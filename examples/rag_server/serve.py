@@ -40,19 +40,16 @@ from pathway_tpu.xpacks.llm.servers import DocumentStoreServer
 from pathway_tpu.xpacks.llm.splitters import TokenCountSplitter
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--docs", default="docs", help="directory to watch")
-    ap.add_argument("--host", default="127.0.0.1")
-    ap.add_argument("--port", type=int, default=8666)
-    ap.add_argument("--max-tokens", type=int, default=256)
-    args = ap.parse_args()
-
+def build_server(
+    docs_dir: str, host: str, port: int, max_tokens: int = 256
+) -> DocumentStoreServer:
+    """The served pipeline: watched directory -> parse -> split -> embed ->
+    index -> REST (``chip_smoke.py`` drives exactly this on the chip)."""
     # a watch directory that does not exist yet is an empty corpus, not an
     # error — create it so `serve.py` works (and lints) out of the box
-    os.makedirs(args.docs, exist_ok=True)
+    os.makedirs(docs_dir, exist_ok=True)
     docs = pw.io.fs.read(
-        args.docs, format="binary", mode="streaming", with_metadata=True,
+        docs_dir, format="binary", mode="streaming", with_metadata=True,
     )
 
     embedder = TpuEmbedder()
@@ -63,9 +60,20 @@ def main() -> None:
             embedder=embedder.embedder,
         ),
         parser=ParseLocal(),
-        splitter=TokenCountSplitter(max_tokens=args.max_tokens),
+        splitter=TokenCountSplitter(max_tokens=max_tokens),
     )
-    server = DocumentStoreServer(args.host, args.port, store)
+    return DocumentStoreServer(host, port, store)
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--docs", default="docs", help="directory to watch")
+    ap.add_argument("--host", default="127.0.0.1")
+    ap.add_argument("--port", type=int, default=8666)
+    ap.add_argument("--max-tokens", type=int, default=256)
+    args = ap.parse_args()
+
+    server = build_server(args.docs, args.host, args.port, args.max_tokens)
     print(f"serving on http://{args.host}:{args.port}/v1/retrieve")
     server.run()
 

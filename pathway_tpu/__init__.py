@@ -11,22 +11,6 @@ Import as ``import pathway_tpu as pw`` — the API surface mirrors
 
 from __future__ import annotations
 
-import os as _os
-
-if _os.environ.get("PATHWAY_PROCESSES", "1") not in ("", "0", "1") and _os.environ.get(
-    "PATHWAY_MESH_EXCHANGE", ""
-).strip().lower() in ("1", "true", "yes", "on"):
-    # multiprocess mesh child: gloo CPU collectives must be armed BEFORE
-    # the first jax backend client exists (XLA's default CPU client
-    # refuses multiprocess computations; the config knob is read at
-    # client creation, so doing it at mesh-establishment time is too
-    # late). Plain TCP-cluster children never run jax collectives and
-    # skip the multi-second jax import at startup.
-    from .internals.jax_compat import enable_cpu_collectives as _ecc
-
-    _ecc()
-    del _ecc
-
 from . import reducers, udfs
 from .internals import dtype as _dt
 from .internals.custom_reducers import BaseCustomAccumulator

@@ -630,13 +630,16 @@ class FusedChain(Node):
 
         t0 = _wall.perf_counter_ns()
         try:
-            import jax
+            from ..utils import jaxcfg
 
-            from ..utils import jaxcfg  # noqa: F401
+            import jax
         except Exception:
             st["broken"] = True
             return None
-        if not jax.config.jax_enable_x64:
+        if not jaxcfg.enable_x64_on_cpu():
+            return None
+        dev = ec._engine_device()
+        if dev is None:
             return None
         if self._jit is None:
             self._jit = ec.fused_chain_kernel(
@@ -644,12 +647,8 @@ class FusedChain(Node):
             )
             FUSION_STATS["jit_chains_total"] += 1
         try:
-            dev = ec._engine_device()
             src = {c: d.data[c] for c in plan["src_cols"]}
-            if dev is not None:
-                with jax.default_device(dev):
-                    outs = self._jit(src, d.keys)
-            else:
+            with jax.default_device(dev):
                 outs = self._jit(src, d.keys)
         except Exception:
             # shape/dtype combination XLA refuses — numpy tier owns it.

@@ -84,6 +84,8 @@ def _pow2(n: int, floor: int = 8) -> int:
 
 @functools.lru_cache(maxsize=128)
 def _cached_kernel(mesh: Any, axis: str, cap_out: int):
+    from ..utils import jaxcfg  # noqa: F401
+
     import jax
 
     from ..parallel.exchange import bucketed_all_to_all
@@ -160,9 +162,11 @@ class MeshExchangeRunner:
         with a single sharded ``device_put`` and run the bucketed
         all-to-all. Returns (kinds, cap_bucket, global vals, global valid)
         or None when the tick moves no rows."""
-        import jax
-
         import time as _time
+
+        from ..utils import jaxcfg  # noqa: F401
+
+        import jax
 
         counts_all = [p[1] for p in payloads]
         total_rows = sum(int(c.sum()) for c in counts_all)

@@ -13,6 +13,7 @@ which dispatch to JAX/XLA for large batches.
 
 from __future__ import annotations
 
+import sys
 from typing import Any, Callable
 
 import numpy as np
@@ -3687,13 +3688,10 @@ def _as_column(arr: Any, n: int) -> np.ndarray:
         and arr.dtype.kind not in ("U", "S")
     ):
         return arr
-    try:
-        import jax
-
-        if isinstance(arr, jax.Array):
-            return np.asarray(arr)
-    except Exception:
-        pass
+    # a process that never imported jax cannot hold a jax.Array
+    jax = sys.modules.get("jax")
+    if jax is not None and isinstance(arr, jax.Array):
+        return np.asarray(arr)
     if not isinstance(arr, (np.ndarray, list)):
         # anything else — scalars, None, tuples, dicts, Json, arbitrary
         # objects — is a row *value* (constant per row), never a column

@@ -13,6 +13,7 @@ XLA kernel per operator per batch").
 
 from __future__ import annotations
 
+import logging
 import os
 from dataclasses import dataclass
 from typing import Any, Callable
@@ -49,6 +50,8 @@ from .expression import (
     RequireExpression,
     UnwrapExpression,
 )
+
+_log = logging.getLogger(__name__)
 
 JIT_THRESHOLD = int(os.environ.get("PATHWAY_TPU_JIT_THRESHOLD", "4096"))
 JIT_WARMUP_BATCHES = int(os.environ.get("PATHWAY_TPU_JIT_WARMUP_BATCHES", "16"))
@@ -201,13 +204,17 @@ def _compile_expr_uncached(expr: ColumnExpression, env: ColumnEnv) -> Compiled:
                 if hot[0] <= JIT_WARMUP_BATCHES:
                     return np_fn(cols, keys)
                 try:
-                    import jax
+                    from ..utils import jaxcfg
 
-                    from ..utils import jaxcfg  # noqa: F401  (configures x64)
+                    import jax
                 except Exception:
-                    # present-but-broken jax (e.g. jaxlib mismatch): degrade
-                    # to the numpy kernels forever, as the old import-time
-                    # probe did — never crash a running stream
+                    # present-but-broken jax (e.g. jaxlib mismatch): the
+                    # numpy kernels compute the same values, so a running
+                    # stream keeps them for good instead of crashing
+                    _log.warning(
+                        "jax failed to import; host expressions stay on "
+                        "the numpy kernels", exc_info=True,
+                    )
                     _jax_checked[:] = [False]
                     jax_broken[0] = True
                     return np_fn(cols, keys)
@@ -215,22 +222,23 @@ def _compile_expr_uncached(expr: ColumnExpression, env: ColumnEnv) -> Compiled:
                 # x64 gate: without it the traced kernel silently truncates
                 # INT/FLOAT columns to 32 bits — wrong values, and 32-bit
                 # outputs knock every downstream key hash off the fast path.
-                if not jax.config.jax_enable_x64:
+                # x64 is on only in a CPU-only process (utils/jaxcfg.py), so
+                # on an accelerator host this tier is the numpy kernels.
+                if not jaxcfg.enable_x64_on_cpu():
+                    return np_fn(cols, keys)
+                # pin to the host CPU backend: streaming tick batches are
+                # latency-bound host work; shipping them to an accelerator
+                # per tick costs more than the fused kernel saves. The TPU
+                # is for the dense kernels (knn, embedder, window
+                # aggregation) that amortize the transfer.
+                # Override with PATHWAY_TPU_EXPR_BACKEND=tpu.
+                dev = _engine_device()
+                if dev is None:
                     return np_fn(cols, keys)
                 if not jitted_box:
                     jitted_box.append(_jitted_kernel(expr, env))
-                jitted = jitted_box[0]
-                # pin to the host CPU backend: streaming tick batches are
-                # latency-bound host work; shipping them to an accelerator
-                # (worse, a tunneled one) per tick costs more than the fused
-                # kernel saves. The TPU is for the dense kernels (knn,
-                # embedder, window aggregation) that amortize the transfer.
-                # Override with PATHWAY_TPU_EXPR_BACKEND=tpu.
-                dev = _engine_device()
-                if dev is not None:
-                    with jax.default_device(dev):
-                        return np.asarray(jitted(cols, keys))
-                return np.asarray(jitted(cols, keys))
+                with jax.default_device(dev):
+                    return np.asarray(jitted_box[0](cols, keys))
             return np_fn(cols, keys)
 
         return Compiled(fn, dtype, jax_ok=True)
@@ -241,13 +249,21 @@ _engine_dev_cache: list = []
 
 
 def _engine_device():
+    """The device host expression kernels are pinned to, or None when the
+    backend ``PATHWAY_TPU_EXPR_BACKEND`` names (default ``cpu``) is not
+    there — e.g. under ``JAX_PLATFORMS=tpu``. None means the numpy kernels
+    run: a kernel never lands on a device nobody named."""
     if not _engine_dev_cache:
         import jax
 
         backend = os.environ.get("PATHWAY_TPU_EXPR_BACKEND", "cpu")
         try:
             _engine_dev_cache.append(jax.local_devices(backend=backend)[0])
-        except Exception:
+        except RuntimeError as e:
+            _log.warning(
+                "jax backend %r is unavailable (%s); host expressions stay "
+                "on the numpy kernels", backend, e,
+            )
             _engine_dev_cache.append(None)
     return _engine_dev_cache[0]
 

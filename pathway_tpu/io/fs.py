@@ -384,22 +384,27 @@ def read(
 ) -> Table:
     if format == "raw":
         format = "binary"  # reference alias (io/fs raw == whole-file bytes)
-    if (
-        mode == "streaming"
-        and with_metadata
-        and format in ("csv", "dsv", "json", "jsonlines", "plaintext")
+    if mode == "streaming" and (
+        format == "binary"
+        or (
+            with_metadata
+            and format in ("csv", "dsv", "json", "jsonlines", "plaintext")
+        )
     ):
         # object semantics (the reference's posix_like scanner): each file
         # is one object — a modified file retracts its old rows and inserts
-        # the new version's, a deleted file retracts everything, and every
-        # row carries a _metadata column. The default (tail) path below is
-        # the append-log fast lane.
+        # the new version's, a deleted file retracts everything, and with
+        # with_metadata every row carries a _metadata column. A whole-file
+        # (binary) row has no other streaming meaning. The default (tail)
+        # path below is the append-log fast lane.
         from .s3 import object_source_table
 
         spath = os.fspath(path)
         delimiter = getattr(csv_settings, "delimiter", ",") if csv_settings else ","
         if format == "plaintext":
             schema = schema or schema_from_types(data=str)
+        if format == "binary":
+            schema = schema or schema_from_types(data=bytes)
         if schema is None:
             probe = read(spath, format=format, schema=None, mode="static",
                          csv_settings=csv_settings)
@@ -411,7 +416,7 @@ def read(
                 )
         return object_source_table(
             _LocalFsClient(spath), format, schema,
-            mode="streaming", with_metadata=True,
+            mode="streaming", with_metadata=with_metadata,
             refresh_interval_ms=1000,
             autocommit_duration_ms=autocommit_duration_ms,
             name=name, delimiter=delimiter,

@@ -16,6 +16,8 @@ import dataclasses
 import functools
 from typing import Any
 
+from ..utils import jaxcfg  # noqa: F401  (configures jax before first use)
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -283,8 +285,7 @@ class Embedder:
     def embed_texts_device(self, texts: list[str], max_len: int = 128) -> jax.Array:
         """Embeddings as a device-resident array (no host fetch): consumers
         that feed another device computation (the KNN scorer) pipeline the
-        dispatches and pay ONE host roundtrip for the whole chain — the
-        serve-path latency win on remote/tunneled accelerators.
+        dispatches and pay ONE blocking fetch for the whole chain.
 
         The sequence is bucketed to the smallest power of two covering the
         longest REAL token run (min 16): pad columns are masked out of

@@ -19,6 +19,8 @@ from __future__ import annotations
 import functools
 from typing import Any
 
+from ..utils import jaxcfg  # noqa: F401  (configures jax before first use)
+
 import jax
 import jax.numpy as jnp
 import numpy as np

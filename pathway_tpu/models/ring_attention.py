@@ -17,11 +17,12 @@ from __future__ import annotations
 import functools
 from typing import Any
 
+from ..utils import jaxcfg  # noqa: F401  (configures jax before first use)
+
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..internals.jax_compat import shard_map
 
 __all__ = ["ring_attention", "full_attention"]
 

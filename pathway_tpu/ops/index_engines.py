@@ -237,6 +237,8 @@ class BruteForceKnnEngine:
         n = self._slots.high
         if n == 0 or not queries:
             return [[] for _ in queries]
+        from ..utils import jaxcfg  # noqa: F401
+
         import jax.numpy as jnp
 
         from .knn import topk_scores

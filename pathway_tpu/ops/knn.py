@@ -20,9 +20,12 @@ from __future__ import annotations
 import functools
 from typing import Any
 
+from ..utils import jaxcfg  # noqa: F401  (configures jax before first use)
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
@@ -99,8 +102,6 @@ def sharded_knn_search(
             "raise index capacity or lower k"
         )
 
-    from ..internals.jax_compat import shard_map
-
     specs_in = [P(), P(axis, None)]
     args = [queries, index_sharded]
     if valid_sharded is not None:
@@ -151,28 +152,43 @@ class ShardedKnnIndex:
         self.mesh = mesh
         self.axis = axis
         self.size = 0
+        shardings = None
         if mesh is not None:
+            shardings = (
+                NamedSharding(mesh, P(axis, None)), NamedSharding(mesh, P(axis))
+            )
             self._data = jax.device_put(
-                jnp.zeros((capacity, dim), jnp.float32),
-                NamedSharding(mesh, P(axis, None)),
+                jnp.zeros((capacity, dim), jnp.float32), shardings[0]
             )
             self._valid_d = jax.device_put(
-                jnp.zeros((capacity,), jnp.bool_), NamedSharding(mesh, P(axis))
+                jnp.zeros((capacity,), jnp.bool_), shardings[1]
             )
         else:
             self._data = jnp.zeros((capacity, dim), jnp.float32)
             self._valid_d = jnp.zeros((capacity,), jnp.bool_)
+
+        def write(data, valid, rows, start):
+            data = jax.lax.dynamic_update_slice(
+                data, rows, (start, jnp.zeros_like(start))
+            )
+            ones = jnp.ones((rows.shape[0],), jnp.bool_)
+            return data, jax.lax.dynamic_update_slice(valid, ones, (start,))
+
+        # the block is updated in place (donated) and keeps its sharding:
+        # left to itself the compiler replicates the result of an eager
+        # update on a TPU mesh, and every chip then holds the whole index
+        self._write = jax.jit(
+            write, donate_argnums=(0, 1), out_shardings=shardings
+        )
         self._keys: list[Any] = []
 
     def add(self, vectors: np.ndarray, keys: list[Any] | None = None) -> None:
         n = len(vectors)
         if self.size + n > self.capacity:
             raise ValueError("index capacity exceeded")
-        self._data = jax.lax.dynamic_update_slice(
-            self._data, jnp.asarray(vectors, jnp.float32), (self.size, 0)
-        )
-        self._valid_d = jax.lax.dynamic_update_slice(
-            self._valid_d, jnp.ones((n,), jnp.bool_), (self.size,)
+        self._data, self._valid_d = self._write(
+            self._data, self._valid_d,
+            np.asarray(vectors, np.float32), np.int32(self.size),
         )
         self._keys.extend(keys if keys is not None else range(self.size, self.size + n))
         self.size += n

@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import os
 
+from ..utils import jaxcfg  # noqa: F401  (configures jax before first use)
+
 import jax
 
 __all__ = ["init_from_env", "global_mesh"]
@@ -36,12 +38,8 @@ def init_from_env(coordinator_host: str = "127.0.0.1") -> None:
     coordinator = os.environ.get(
         "PATHWAY_COORDINATOR", f"{coordinator_host}:{cfg.first_port + 1000}"
     )
-    from ..internals.jax_compat import enable_cpu_collectives
-
-    # XLA's default CPU client refuses multiprocess computations; jaxlib
-    # ships gloo TCP collectives for exactly this case — arm them before
-    # the distributed client is created (no-op on TPU/GPU)
-    enable_cpu_collectives()
+    # multiprocess computations on the CPU backend ride jaxlib's gloo TCP
+    # collectives, the default jax_cpu_collectives_implementation
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=cfg.processes,

@@ -12,10 +12,12 @@ from __future__ import annotations
 
 import functools
 
+from ..utils import jaxcfg  # noqa: F401  (configures jax before first use)
+
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..internals.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..engine import keys as K

@@ -4,6 +4,8 @@ axes)."""
 
 from __future__ import annotations
 
+from ..utils import jaxcfg  # noqa: F401  (configures jax before first use)
+
 import jax
 import numpy as np
 from jax.sharding import Mesh

@@ -62,6 +62,8 @@ __all__ = ["MeshComm", "MultiHostMeshComm"]
 
 class MeshComm(Comm):
     def __init__(self, inner: Comm, mesh: Any = None):
+        from ..utils import jaxcfg  # noqa: F401
+
         import jax
         from jax.sharding import Mesh
 
@@ -303,6 +305,8 @@ class MultiHostMeshComm(Comm):
 
     def __init__(self, inner: Comm, process_id: int, n_processes: int,
                  threads: int):
+        from ..utils import jaxcfg  # noqa: F401
+
         import jax
         from jax.sharding import Mesh
 
@@ -556,6 +560,8 @@ class MultiHostMeshComm(Comm):
         slice of the global array, run the collective with every other
         process's leader."""
         import time as _time
+
+        from ..utils import jaxcfg  # noqa: F401
 
         import jax
 

@@ -10,6 +10,7 @@ documents change.
 
 from __future__ import annotations
 
+import json
 from typing import Any, Callable, Iterable
 
 import pathway_tpu as pw
@@ -57,7 +58,7 @@ class DocumentStore:
             docs_list = list(docs)
         if not docs_list:
             raise ValueError("DocumentStore needs at least one docs table")
-        self.docs = (
+        self.docs = self._ensure_metadata(
             docs_list[0]
             if len(docs_list) == 1
             else docs_list[0].concat_reindex(*docs_list[1:])
@@ -83,14 +84,21 @@ class DocumentStore:
     # ------------------------------------------------------------------
 
     def _ensure_metadata(self, table: Table) -> Table:
-        if "_metadata" in table.column_names():
-            return table
-        return table.with_columns(_metadata=apply_with_type(
-            lambda d: {}, dt.ANY, this.data
-        ))
+        if "_metadata" not in table.column_names():
+            return table.with_columns(_metadata=apply_with_type(
+                lambda d: {}, dt.ANY, this.data
+            ))
+        mdt = dt.unoptionalize(table.schema.columns()["_metadata"].dtype)
+        if mdt == dt.STR:
+            # connectors (pw.io.fs/s3 with_metadata=True) deliver the
+            # metadata as a JSON string; the pipeline merges dicts
+            return table.with_columns(_metadata=apply_with_type(
+                lambda m: json.loads(m) if m else {}, dt.ANY, this._metadata
+            ))
+        return table
 
     def build_pipeline(self) -> None:
-        docs = self._ensure_metadata(self.docs)
+        docs = self.docs
 
         if self.vector_column is not None:
             # pre-embedded chunks: index straight over the vector column
@@ -193,8 +201,7 @@ class DocumentStore:
     def statistics_query(self, info_queries: Table) -> Table:
         """Global doc-count/last-modified stats per query row
         (reference document_store.py statistics_query)."""
-        docs = self._ensure_metadata(self.docs)
-        counts = docs.reduce(
+        counts = self.docs.reduce(
             count=pw.reducers.count(),
             last_modified=pw.reducers.max(apply_with_type(
                 lambda m: int((m or {}).get("modified_at", 0)), dt.INT,
@@ -218,8 +225,7 @@ class DocumentStore:
         """List indexed input files (path + metadata) per query row."""
         from ...utils.filters import compile_metadata_filter
 
-        docs = self._ensure_metadata(self.docs)
-        files = docs.reduce(
+        files = self.docs.reduce(
             metas=pw.reducers.tuple(this._metadata),
         ).select(__one=0, metas=this.metas)
 

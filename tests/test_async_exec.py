@@ -185,8 +185,6 @@ def test_sink_kill_async_pinned(tmp_path, monkeypatch):
 _CLUSTER_PROG = """
 import json, os, sys
 sys.path.insert(0, {repo!r})
-from pathway_tpu.utils.jaxcfg import guard_cpu_platform
-guard_cpu_platform()
 import pathway_tpu as pw
 
 n_rows, batch = 20_000, 1_000

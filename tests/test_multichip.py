@@ -6,23 +6,11 @@ import jax
 import numpy as np
 import pytest
 
-from pathway_tpu.internals.jax_compat import (
-    shard_map_available,
-    shard_map_unavailable_reason,
-)
 from pathway_tpu.parallel.mesh import data_model_mesh, make_mesh
 
-pytestmark = [
-    pytest.mark.skipif(
-        len(jax.devices()) < 8, reason="needs 8 virtual devices"
-    ),
-    # explicit env-capability skip, not a blind xfail: the shim resolves
-    # jax.shard_map OR jax.experimental.shard_map.shard_map — only a jax
-    # with NEITHER (named in the reason) skips these
-    pytest.mark.skipif(
-        not shard_map_available(), reason=shard_map_unavailable_reason()
-    ),
-]
+pytestmark = pytest.mark.skipif(
+    len(jax.devices()) < 8, reason="needs 8 virtual devices"
+)
 
 
 def test_sharded_knn_matches_single_device():
@@ -36,7 +24,11 @@ def test_sharded_knn_matches_single_device():
     queries /= np.linalg.norm(queries, axis=1, keepdims=True)
 
     idx = ShardedKnnIndex(dim=32, capacity=256, mesh=mesh)
-    idx.add(docs)
+    idx.add(docs[:100])
+    idx.add(docs[100:])
+    # a write keeps the block sharded: an eighth on every device (on four
+    # real chips an eager update came back replicated, PR 21)
+    assert [s.data.shape for s in idx._data.addressable_shards] == [(32, 32)] * 8
     s_sharded, i_sharded = idx.query(queries, k=7)
     s_ref, i_ref = knn_search(queries, docs, k=7)
     # same neighbor sets (scores in bf16 → compare ids)

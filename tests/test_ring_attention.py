@@ -9,18 +9,6 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from pathway_tpu.internals.jax_compat import (  # noqa: E402
-    shard_map_available,
-    shard_map_unavailable_reason,
-)
-
-# env-capability gate with an explicit reason (ISSUE 8 satellite): ring
-# attention needs SOME shard_map implementation; the jax_compat shim
-# accepts both the modern top-level API and the 0.4.x experimental one
-pytestmark = pytest.mark.skipif(
-    not shard_map_available(), reason=shard_map_unavailable_reason()
-)
-
 from pathway_tpu.models.embedder import EmbedderConfig, init_params  # noqa: E402
 from pathway_tpu.models.ring_attention import (  # noqa: E402
     embed_tokens_long,

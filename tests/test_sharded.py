@@ -463,13 +463,6 @@ def test_multihost_mesh_exchange_parity(tmp_path):
     ride the cross-process device collective (MultiHostMeshComm) and the
     output matches the single-worker run (VERDICT r4 item 6 — the engine
     call site + test for parallel/distributed.py)."""
-    from pathway_tpu.internals.jax_compat import multihost_cpu_supported
-
-    ok, reason = multihost_cpu_supported()
-    if not ok:
-        # explicit env-capability skip: without gloo TCP collectives the
-        # default XLA CPU client refuses multiprocess computations
-        pytest.skip(reason)
     prog = tmp_path / "prog.py"
     prog.write_text(textwrap.dedent(_CLUSTER_PROGRAM))
     out_single = tmp_path / "single.json"
