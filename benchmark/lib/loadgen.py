@@ -1,0 +1,92 @@
+"""The load generator: closed-loop reader clients and probe clients, one
+thread and one persistent connection each, in the parent process (so client
+threads do not share the server's interpreter lock). Times are
+``time.monotonic()``, which parent and child share on Linux."""
+
+from __future__ import annotations
+
+import http.client
+import json
+import threading
+import time
+
+from . import check
+
+REQUEST_TIMEOUT_S = 120.0
+
+
+class Client(threading.Thread):
+    """Sends its next request when the last reply is read, no think time,
+    until ``until()`` says stop. ``next_query()`` gives (query id, text)."""
+
+    def __init__(self, name: str, port: int, route: str, k: int, t0: float,
+                 next_query, until):
+        super().__init__(name=name, daemon=True)
+        self.port, self.route, self.k, self.t0 = port, route, k, t0
+        self.next_query, self.until = next_query, until
+        self.records: list[dict] = []
+        self._conn: http.client.HTTPConnection | None = None
+
+    def _post(self, text: str):
+        """(status, body); status 0 is no answer. One reconnect if the server
+        closed a kept connection between requests."""
+        payload = json.dumps({"query": text, "k": self.k})
+        for attempt in (0, 1):
+            try:
+                if self._conn is None:
+                    self._conn = http.client.HTTPConnection(
+                        "127.0.0.1", self.port, timeout=REQUEST_TIMEOUT_S)
+                self._conn.request("POST", self.route, body=payload,
+                                   headers={"Content-Type": "application/json"})
+                resp = self._conn.getresponse()
+                return resp.status, resp.read()
+            except (http.client.RemoteDisconnected, ConnectionResetError,
+                    BrokenPipeError) as e:
+                self._close()
+                if attempt:
+                    return 0, repr(e).encode()
+            except OSError as e:
+                self._close()
+                return 0, repr(e).encode()
+        return 0, b""
+
+    def _close(self) -> None:
+        if self._conn is not None:
+            self._conn.close()
+            self._conn = None
+
+    def run(self) -> None:
+        time.sleep(max(0.0, self.t0 - time.monotonic()))
+        try:
+            while not self.until():
+                qid, text = self.next_query()
+                send = time.monotonic()
+                status, body = self._post(text)
+                recv = time.monotonic()
+                rows = None
+                if status == 200:
+                    try:
+                        rows = check.reply_rows(json.loads(body), self.k)
+                    except ValueError:
+                        rows = None
+                rec = {"client": self.name, "qid": qid, "query": text, "send": send,
+                       "recv": recv, "status": status, "rows": rows}
+                if rows is None:
+                    rec["error"] = body[:1500].decode("utf-8", "replace")
+                self.records.append(rec)
+        finally:
+            self._close()
+
+
+def run_clients(clients: list[Client], deadline_s: float) -> list[dict]:
+    """Start all, wait for all (each ends by its own ``until``); records of
+    every client, by send time. A client still alive at the deadline is left
+    behind as a daemon and its open request is recorded as unanswered."""
+    for c in clients:
+        c.start()
+    t_end = time.monotonic() + deadline_s
+    for c in clients:
+        c.join(timeout=max(0.1, t_end - time.monotonic()))
+    out = [r for c in clients for r in list(c.records)]
+    out.sort(key=lambda r: r["send"])
+    return out

@@ -1,0 +1,25 @@
+"""Published peaks of one chip, keyed by ``jax.devices()[0].device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB of HBM
+at 819 GB/s. A device that is not in the table is an error, not a default.
+"""
+
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16 * 2**30,
+        "source": 'Google Cloud documentation, "TPU v5e"',
+    },
+}
+
+
+def peaks_for(device_kind: str) -> dict:
+    if device_kind not in PEAKS:
+        raise KeyError(
+            f"no peak on record for device kind {device_kind!r}; add it to "
+            "benchmark/lib/peaks.py with its source"
+        )
+    return PEAKS[device_kind]
