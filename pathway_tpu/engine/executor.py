@@ -25,6 +25,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from ..internals import tracing as _tracing
 from .delta import Delta, concat_deltas
 
 __all__ = [
@@ -795,9 +796,9 @@ class Executor:
             # the /attribution label the profiler's op slot publishes
             # while this node executes (fused chains refine to members)
             node._op_label = f"{type(node).__name__}#{node.node_id}"
-        from ..internals.tracing import get_tracer
-
-        self.tracer = get_tracer()
+        #: perf_counter_ns of the first park since the last round (the
+        #: open ``engine.park`` span), None while the loop has work
+        self._park_t0: int | None = None
         # spill-to-disk state budget (engine/spill.py): None unless
         # PATHWAY_STATE_MEMORY_BUDGET_MB is set — one None check per tick
         from . import spill as _spill
@@ -868,25 +869,24 @@ class Executor:
                 n_nodes=len(self.nodes),
             )
         try:
-            if self.tracer is not None:
-                try:
-                    with self.tracer.span(
-                        "engine.run",
-                        n_nodes=len(self.nodes),
-                        worker=self.ctx.worker_id,
-                        n_workers=self.ctx.n_workers,
-                    ):
-                        self._run_inner()
-                finally:
-                    if not self.ctx.is_sharded:
-                        # failed runs are the ones worth a trace; sharded
-                        # runs flush once after every worker joined
-                        # (graph_runner._run_sharded) — a per-worker flush
-                        # here would freeze the file at the first worker's
-                        # finish
-                        self.tracer.flush()
-            else:
-                self._run_inner()
+            try:
+                with _tracing.span(
+                    "engine.run",
+                    n_nodes=len(self.nodes),
+                    worker=self.ctx.worker_id,
+                    n_workers=self.ctx.n_workers,
+                ):
+                    self._run_inner()
+            finally:
+                # failed runs are the ones worth a trace; sharded runs
+                # flush once after every worker joined
+                # (graph_runner._run_sharded) — a per-worker flush here
+                # would freeze the file at the first worker's finish.
+                # Not get_tracer(): a profiler session that recorded
+                # spans in the middle of the run is over by now.
+                tracer = _tracing.run_tracer()
+                if tracer is not None and not self.ctx.is_sharded:
+                    tracer.flush()
             if self.flight is not None:
                 self.flight.record(
                     "run.end",
@@ -1019,9 +1019,12 @@ class Executor:
                 else:
                     # park until data arrives (waker) or the poll interval
                     # lapses (step_or_park's timed wait)
+                    if self._park_t0 is None:
+                        self._park_t0 = _time.perf_counter_ns()
                     wake.wait(0.005)
                     wake.clear()
         finally:
+            self._end_park()
             for src in realtime:
                 src.stop()
         self._finish()
@@ -1111,10 +1114,13 @@ class Executor:
                     # interval lapses; peers' data surfaces via the next
                     # cycle's allgather either way
                     park_t0 = _time.perf_counter_ns()
+                    if self._park_t0 is None:
+                        self._park_t0 = park_t0
                     wake.wait(0.005)
                     wake.clear()
                     self._idle_park_ns += _time.perf_counter_ns() - park_t0
         finally:
+            self._end_park()
             for src in owned:
                 src.stop()
 
@@ -1349,6 +1355,8 @@ class Executor:
                             )
                             stall_logged = True
                     park_t0 = _time.perf_counter_ns()
+                    if self._park_t0 is None:
+                        self._park_t0 = park_t0
                     plane.waker.wait(0.005)
                     plane.waker.clear()
                     self._idle_park_ns += _time.perf_counter_ns() - park_t0
@@ -1372,6 +1380,7 @@ class Executor:
                     frontier=plane.tracker.local(), epochs=epoch,
                 )
         finally:
+            self._end_park()
             for src in owned:
                 src.stop()
             # the END_TIME flush sweep (and any recovery that follows a
@@ -1471,7 +1480,7 @@ class Executor:
         clock = max(clock, T)
         plane.hold_above = T
         t_ready = _time.perf_counter_ns()
-        tracer = self.tracer
+        tracer = _tracing.get_tracer()
         if tracer is not None:
             tracer.complete("wave.frontier_wait", t_entry, {"epoch": epoch})
         if self.flight is not None:
@@ -1772,13 +1781,35 @@ class Executor:
         self.persistence.begin_recording(owned_sources(realtime, self.ctx))
         return clock
 
-    def _tick(self, time: int, source_emissions: list[tuple[SourceNode, Delta]]) -> None:
-        import time as _wall
+    def _end_park(self) -> None:
+        """Close the open ``engine.park`` span: the loop found a round (or
+        is ending). Consecutive 5 ms waits are one span."""
+        t0, self._park_t0 = self._park_t0, None
+        if t0 is not None:
+            tracer = _tracing.get_tracer()
+            if tracer is not None:
+                tracer.complete("engine.park", t0)
 
+    def _tick(self, time: int, source_emissions: list[tuple[SourceNode, Delta]]) -> None:
         if self._tick_fault is not None:
             self._tick_fault.fire(self._tick_seq)
         self._tick_seq += 1
-        tracer = self.tracer
+        if self._park_t0 is not None:
+            self._end_park()
+        # read at every tick, not once at construction: a profiler session
+        # (and with it span recording) may begin in the middle of a run
+        tracer = _tracing.get_tracer()
+        if tracer is None:
+            self._sweep(time, source_emissions, None)
+            return
+        rows_in = sum(len(d) for _, d in source_emissions)
+        with tracer.span("tick", time=time, tick=time, rows_in=rows_in) as sp:
+            self._sweep(time, source_emissions, tracer, sp)
+
+    def _sweep(self, time: int, source_emissions: list[tuple[SourceNode, Delta]],
+               tracer, tick_span=None) -> None:
+        import time as _wall
+
         timed = tracer is not None or self.stats.detailed
         # tick duration is always histogrammed — two clock reads per tick
         # against a full topological sweep is noise, and it is the one
@@ -1918,21 +1949,17 @@ class Executor:
             self.persistence.on_time_end(time)
         if tracer is not None:
             # after the callbacks and the persistence commit: both can
-            # dominate a tick and must show inside its span. Span + counter
-            # go in ONE append (worker id in the counter name: counter
+            # dominate a tick and must show inside its span, which _tick
+            # holds open around this sweep. The row counters ride the
+            # span's own append (worker id in the counter name: counter
             # tracks merge by (pid, name)) so the ring-buffer drop can
             # never orphan the sample from its tick.
-            tracer.complete(
-                "tick",
-                tick_t0,
-                {"time": time},
-                counter=(
-                    f"engine_rows.w{self.ctx.worker_id}",
-                    {
-                        "input": self.stats.input_rows,
-                        "output": self.stats.output_rows,
-                    },
-                ),
+            tick_span.counter = (
+                f"engine_rows.w{self.ctx.worker_id}",
+                {
+                    "input": self.stats.input_rows,
+                    "output": self.stats.output_rows,
+                },
             )
         if self.flight is not None:
             # throttled to one record per 10ms: the ring's job is the

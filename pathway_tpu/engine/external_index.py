@@ -296,30 +296,39 @@ class ExternalIndexNode(Node):
         )
 
     def _apply_data(self, data_d: Delta) -> None:
-        cols = data_d.data
-        filt = cols.get("__filter_data__")
-        datas = cols["__data__"]
-        # removals before insertions so an in-tick update (retract+insert
-        # of the same key) lands in the index as the new value
-        add_keys: list[int] = []
-        add_datas: list[Any] = []
-        add_filts: list[Any] = []
-        order = np.argsort(data_d.diffs, kind="stable")
-        for i in order:
-            k = int(data_d.keys[i])
-            if data_d.diffs[i] < 0:
-                for _ in range(-int(data_d.diffs[i])):
-                    self.engine.remove(k)
-            else:
-                for _ in range(int(data_d.diffs[i])):
-                    add_keys.append(k)
-                    add_datas.append(datas[i])
-                    add_filts.append(filt[i] if filt is not None else None)
-        if add_keys:
-            add_batch = getattr(self.engine, "add_batch", None)
-            if add_batch is not None:
-                # one batched embed + insert per tick, not per document
-                add_batch(add_keys, add_datas, add_filts)
-            else:
-                for k, d, f in zip(add_keys, add_datas, add_filts):
-                    self.engine.add(k, d, f)
+        from ..internals.tracing import span
+        from ..serve.stats import bump
+
+        diffs = data_d.diffs
+        added = int(diffs[diffs > 0].sum())
+        removed = int(-diffs[diffs < 0].sum())
+        bump("index_rows_added_total", added)
+        bump("index_rows_removed_total", removed)
+        with span("index.apply", added=added, removed=removed):
+            cols = data_d.data
+            filt = cols.get("__filter_data__")
+            datas = cols["__data__"]
+            # removals before insertions so an in-tick update (retract+insert
+            # of the same key) lands in the index as the new value
+            add_keys: list[int] = []
+            add_datas: list[Any] = []
+            add_filts: list[Any] = []
+            order = np.argsort(data_d.diffs, kind="stable")
+            for i in order:
+                k = int(data_d.keys[i])
+                if data_d.diffs[i] < 0:
+                    for _ in range(-int(data_d.diffs[i])):
+                        self.engine.remove(k)
+                else:
+                    for _ in range(int(data_d.diffs[i])):
+                        add_keys.append(k)
+                        add_datas.append(datas[i])
+                        add_filts.append(filt[i] if filt is not None else None)
+            if add_keys:
+                add_batch = getattr(self.engine, "add_batch", None)
+                if add_batch is not None:
+                    # one batched embed + insert per tick, not per document
+                    add_batch(add_keys, add_datas, add_filts)
+                else:
+                    for k, d, f in zip(add_keys, add_datas, add_filts):
+                        self.engine.add(k, d, f)

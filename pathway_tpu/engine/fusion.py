@@ -57,6 +57,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from ..internals.tracing import get_tracer
 from .delta import Delta
 from .executor import Node
 
@@ -348,7 +349,6 @@ class FusedChain(Node):
         self._jit = None  # lazily-built whole-chain kernel wrapper
         self._jit_state: dict[str, Any] = {"hot": 0, "broken": False}
         self._jit_plan = self._build_jit_plan()
-        self._tracer_box: list = []  # lazily resolved process tracer
 
     # -- planning helpers ------------------------------------------------
 
@@ -429,7 +429,9 @@ class FusedChain(Node):
             return None
         stats = getattr(self, "_engine_stats", None)
         detailed = stats is not None and stats.detailed
-        tracer = self._tracer()
+        # read per call, never cached: recording may begin (a profiler
+        # session) and end in the middle of a run
+        tracer = get_tracer()
         t0 = _wall.perf_counter_ns() if tracer is not None else 0
         fell_back = False
         # progress record for the fallback: [next member index, cols,
@@ -719,13 +721,6 @@ class FusedChain(Node):
             share = int(total_ns * (wi / tot))
             if share > 0:
                 stats.note_op_time(label, share)
-
-    def _tracer(self):
-        if not self._tracer_box:
-            from ..internals.tracing import get_tracer
-
-            self._tracer_box.append(get_tracer())
-        return self._tracer_box[0]
 
     def __repr__(self) -> str:
         inner = "→".join(self._labels)

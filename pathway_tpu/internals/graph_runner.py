@@ -131,9 +131,9 @@ class GraphRunner:
                 stop_dashboard()
             self._stop_observability(http_server, flusher, _hub)
             from .telemetry import export_from_env
-            from .tracing import get_tracer
+            from .tracing import run_tracer
 
-            export_from_env(get_tracer())
+            export_from_env(run_tracer())
 
     def run_tables(self, *tables: Table, include_sinks: bool = False):
         """Build + execute; return one Capture per requested table."""
@@ -146,7 +146,7 @@ class GraphRunner:
 
     def run(self) -> None:
         from .config import get_pathway_config
-        from .tracing import get_tracer, span
+        from .tracing import run_tracer, span
 
         cfg = get_pathway_config()
         if cfg.total_workers > 1:
@@ -160,7 +160,7 @@ class GraphRunner:
         finally:
             # a failed lowering still deserves its partial trace (executor
             # flushes are no-ops when nothing new happened since)
-            tracer = get_tracer()
+            tracer = run_tracer()
             if tracer is not None:
                 tracer.flush()
                 from .telemetry import export_from_env
@@ -290,10 +290,10 @@ class GraphRunner:
             comm.close()
             for manager in managers:
                 manager.close()
-            from .tracing import get_tracer
+            from .tracing import run_tracer
             from .telemetry import export_from_env
 
-            tracer = get_tracer()
+            tracer = run_tracer()
             if tracer is not None:
                 tracer.flush()
                 export_from_env(tracer)

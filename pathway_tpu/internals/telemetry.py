@@ -205,21 +205,6 @@ class OtlpExporter:
         except Exception:
             return False
 
-    def export(self, tracer: Any) -> dict[str, bool]:
-        """Push the tracer's current buffer; returns per-signal success.
-
-        One-shot/diagnostic surface only: it ignores the ``_otlp_mark``
-        cursor, so mixing it with the periodic flusher would double-export
-        — incremental callers go through ``export_events`` +
-        ``Tracer.events_since`` instead."""
-        with tracer._lock:
-            events = list(tracer._events)
-            origin = tracer._origin
-        # anchor relative timestamps to the wall clock NOW minus the
-        # monotonic distance to each event (close enough for telemetry)
-        origin_unix_ns = time.time_ns() - (time.perf_counter_ns() - origin)
-        return self.export_events(events, origin_unix_ns)
-
     def export_events(
         self, events: list[dict], origin_unix_ns: int
     ) -> dict[str, bool]:

@@ -11,8 +11,26 @@ when the run finishes.
 
 Activation is env-first like every other engine knob
 (``internals/config.py``): set ``PATHWAY_TRACE_FILE=/path/run.json``.
-When unset, ``get_tracer()`` returns ``None`` and every instrumentation
-site is a single ``is None`` check — no timestamps are taken.
+Spans are also recorded while a ``jax.profiler`` session is live
+(``TraceAnnotation.is_enabled()``): start a profile, get the engine's
+spans of that stretch, as ``<tempdir>/pathway-tpu/spans/<pid>.json``
+written by the end-of-run flush (the newest 8 files are kept). Otherwise
+``get_tracer()`` returns ``None`` and every instrumentation site is that
+one check — no timestamps are taken, and a run nobody traced writes no
+file.
+
+Two clocks, one call: a ``with span(...)`` entered while a profiler
+session is live also enters ``jax.profiler.TraceAnnotation(name, **args)``,
+so the span lies in the profiler's host plane on the device trace's clock;
+the trace file's ``trace.clock_sync`` metadata carries
+``origin_monotonic_ns``, so ``origin_monotonic_ns + ts`` is
+``time.monotonic_ns()``. (A ``complete(name, t0_ns)`` event — one that
+began on another thread or in the past — is in the file only: an annotation
+cannot be entered after the fact.) A span entered inside another one, on
+the same thread or in the same asyncio task, carries ``parent`` (the
+enclosing span's name) and inherits its ``req`` / ``tick`` identifier, so
+the spans of one request or one tick share an id and a layer's self time
+is its span less its children.
 
 Span taxonomy (mirrors the reference's span names where it has them):
 
@@ -24,7 +42,16 @@ Span taxonomy (mirrors the reference's span names where it has them):
   emitted row count — the analog of timely's event logging stream
   (``DIFFERENTIAL_LOG_ADDR``, reference ``dataflow.rs:5540-5548``);
 - counter samples of ``EngineStats`` totals per tick, rendered by the
-  trace viewers as time series.
+  trace viewers as time series, and one ``serve_stats`` sample of the
+  ``serve/stats.py`` counters at each flush;
+- the serving path: ``rest.request`` / ``rest.admit`` / ``rest.in_engine``
+  / ``rest.reply`` (``io/http/_server.py``), ``connector.window``
+  (``io/python.py``), ``engine.park`` (the streaming loops),
+  ``index.apply`` (``engine/external_index.py``), ``index.search`` with
+  ``index.embed`` / ``index.upload`` / ``index.score`` / ``index.fetch`` /
+  ``index.pack`` (``ops/index_engines.py``), ``embed.tokenize`` /
+  ``embed.dispatch`` (``models/embedder.py``) — ``docs/observability.md``
+  has the table.
 
 Multi-process runs write one file per process (``<path>.p<process_id>``,
 like the per-process metrics ports of ``engine/http_server.rs:21``);
@@ -41,9 +68,13 @@ cluster handshake estimates (``parallel/cluster.py``) and records here via
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import json
 import os
 import secrets
+import sys
+import tempfile
 import threading
 import time
 from typing import Any
@@ -55,8 +86,27 @@ __all__ = [
     "get_tracer",
     "init_from_env",
     "mint_flow_tag",
+    "run_tracer",
     "span",
+    "spans_dir",
 ]
+
+#: the span this thread (or asyncio task) is inside of, for ``parent`` and
+#: the inherited ``req`` / ``tick`` identifier
+_current: "contextvars.ContextVar[_Span | None]" = contextvars.ContextVar(
+    "pathway_span", default=None
+)
+_ID_KEYS = ("req", "tick")
+#: span files of profiler sessions kept under :func:`spans_dir`
+_KEEP_SESSION_FILES = 8
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` if jax's profiler is loaded, else
+    None. Never imports jax: a process that has not cannot hold a session
+    (and ``import pathway_tpu`` stays off JAX)."""
+    mod = sys.modules.get("jax.profiler")
+    return getattr(mod, "TraceAnnotation", None)
 
 
 def mint_flow_tag() -> str:
@@ -79,20 +129,48 @@ def make_flow_id(tracer: "Tracer", tag: str, *coords: Any) -> str:
     return "/".join([tracer.run_id, tag, *map(str, coords)])
 
 
+def _ids_of(outer: "_Span | None") -> dict[str, Any]:
+    """The ``req`` / ``tick`` a span hands down to what runs inside it."""
+    if outer is None:
+        return {}
+    return {k: outer.args[k] for k in _ID_KEYS if k in outer.args}
+
+
 class _Span:
-    __slots__ = ("tracer", "name", "args", "t0")
+    __slots__ = ("tracer", "name", "args", "counter", "t0", "_ann", "_token")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict[str, Any]):
         self.tracer = tracer
         self.name = name
         self.args = args
+        #: ``(name, values)`` set before the span ends: a counter sample
+        #: appended with it (see :meth:`Tracer.complete`)
+        self.counter = None
 
     def __enter__(self) -> "_Span":
+        args = self.args
+        outer = _current.get()
+        if outer is not None:
+            args.setdefault("parent", outer.name)
+            for k, v in _ids_of(outer).items():
+                args.setdefault(k, v)
+        ann = _annotation()
+        if ann is not None and ann.is_enabled():
+            self._ann = ann(self.name, **args)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._token = _current.set(self)
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
-        self.tracer.complete(self.name, self.t0, self.args or None)
+        self.tracer.complete(
+            self.name, self.t0, self.args or None, self.counter
+        )
+        _current.reset(self._token)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
 
 
 class Tracer:
@@ -104,6 +182,10 @@ class Tracer:
 
     def __init__(self, path: str | None, max_events: int | None = None):
         self.path = path
+        #: True for the tracer of profiler sessions (:func:`get_tracer`):
+        #: its file goes under :func:`spans_dir`, which flush creates and
+        #: prunes
+        self.session = False
         self._events: list[dict[str, Any]] = []
         self._lock = threading.Lock()
         self._pid = os.getpid()
@@ -118,6 +200,9 @@ class Tracer:
         #: perf_counter origin so timestamps start near zero in the viewer
         self._origin = time.perf_counter_ns()
         self.origin_unix_ns = unix_now
+        #: the same instant on ``time.monotonic_ns()``, the clock load
+        #: generators and harnesses time requests on
+        self.origin_monotonic_ns = time.monotonic_ns()
         #: peer process id -> (unix-clock offset ns, rtt ns), estimated by
         #: the cluster handshake ping (ClusterComm); written to the trace
         #: file so `trace merge` can align per-host clocks
@@ -339,6 +424,7 @@ class Tracer:
                     "run_id": self.run_id,
                     "process_id": process_id,
                     "origin_unix_ns": self.origin_unix_ns,
+                    "origin_monotonic_ns": self.origin_monotonic_ns,
                     "clock_offsets": {
                         str(p): [off, rtt]
                         for p, (off, rtt) in sorted(
@@ -360,7 +446,23 @@ class Tracer:
                     "args": {"count": self._dropped},
                 }
             )
+        # the serve-plane counters as they stand now (index, embedder and
+        # connector counts among them): one sample per written file, not
+        # kept in the buffer
+        from ..serve.stats import SERVE_STATS
+
+        events.append(
+            {
+                "name": "serve_stats",
+                "ph": "C",
+                "ts": self._ts(time.perf_counter_ns()),
+                "pid": self._pid,
+                "args": dict(SERVE_STATS),
+            }
+        )
         try:
+            if self.session:
+                os.makedirs(os.path.dirname(path), exist_ok=True)
             # atomic rewrite: the periodic flusher rewrites this file every
             # interval, and a SIGKILL mid-write must leave the PREVIOUS
             # complete flush on disk, not a torn JSON — crashed runs are
@@ -371,6 +473,8 @@ class Tracer:
                     {"traceEvents": meta + events, "displayTimeUnit": "ms"}, f
                 )
             os.replace(tmp, path)
+            if self.session:
+                _prune_session_files(os.path.dirname(path))
         except (OSError, TypeError, ValueError) as e:
             import warnings
 
@@ -384,6 +488,30 @@ class Tracer:
 _active: Tracer | None = None
 _env_checked = False
 _programmatic = False
+#: the tracer of this process's profiler sessions, made when the first
+#: one is seen live; None in a process nobody profiled
+_session: Tracer | None = None
+_session_lock = threading.Lock()
+_NO_SPAN = contextlib.nullcontext()
+
+
+def spans_dir() -> str:
+    """Where the spans of profiler sessions go, one file per process."""
+    return os.path.join(tempfile.gettempdir(), "pathway-tpu", "spans")
+
+
+def _prune_session_files(directory: str) -> None:
+    try:
+        files = [
+            os.path.join(directory, n)
+            for n in os.listdir(directory)
+            if n.endswith(".json")
+        ]
+        files.sort(key=os.path.getmtime, reverse=True)
+        for old in files[_KEEP_SESSION_FILES:]:
+            os.remove(old)
+    except OSError:
+        pass  # another process pruning the same directory
 
 
 def activate(path: str) -> Tracer:
@@ -434,18 +562,50 @@ def init_from_env() -> Tracer | None:
 
 
 def get_tracer() -> Tracer | None:
-    global _env_checked
+    """The tracer spans go to NOW: the one an operator asked for, else the
+    session tracer while a ``jax.profiler`` session is live, else None.
+    Call it at the site, not once at construction: a profile may start in
+    the middle of a run."""
+    global _session
     if not _env_checked:
         init_from_env()
-    return _active
+    if _active is not None:
+        return _active
+    ann = _annotation()
+    if ann is None or not ann.is_enabled():
+        return None
+    if _session is None:
+        with _session_lock:
+            if _session is None:
+                tracer = Tracer(
+                    os.path.join(spans_dir(), f"{os.getpid()}.json")
+                )
+                tracer.session = True
+                _session = tracer
+    return _session
+
+
+def run_tracer() -> Tracer | None:
+    """The tracer a run flushes when it ends: the operator's, else the
+    session tracer if a profiler session was live at any point (its flush
+    is a no-op when nothing was recorded since the last one)."""
+    if not _env_checked:
+        init_from_env()
+    return _active if _active is not None else _session
 
 
 def span(name: str, **args: Any):
-    """Span on the active tracer, or a no-op context when tracing is off —
-    lets instrumentation sites keep a single code path."""
-    import contextlib
-
+    """Span on the tracer of the moment, or a no-op context when nothing
+    records — lets instrumentation sites keep a single code path. ``with
+    span(...) as sp`` gives the span (``sp.args`` may be added to until it
+    ends) or None."""
     tracer = get_tracer()
     if tracer is None:
-        return contextlib.nullcontext()
+        return _NO_SPAN
     return tracer.span(name, **args)
+
+
+def current_ids() -> dict[str, Any]:
+    """``req`` / ``tick`` of the span this thread is inside of — for a
+    ``complete()`` event that ends here but began elsewhere."""
+    return _ids_of(_current.get())

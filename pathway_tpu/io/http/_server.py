@@ -210,6 +210,11 @@ class _RestSubject(ConnectorSubject):
         self.delete_completed_queries = delete_completed_queries
         self.request_validator = request_validator
         self._futures: dict[int, asyncio.Future] = {}
+        #: request key -> perf_counter_ns at which its row went to the
+        #: engine: the open ``rest.in_engine`` span, closed from
+        #: ``_complete`` on the engine thread (kept only while spans are
+        #: recorded)
+        self._engine_t0: dict[int, int] = {}
         self._rows: dict[int, dict[str, Any]] = {}
         self._names = schema.column_names()
         webserver._add_route(route, methods, self._handle)
@@ -232,6 +237,17 @@ class _RestSubject(ConnectorSubject):
             )
 
     async def _handle_inner(self, request, web):
+        from ...internals.tracing import span
+
+        key = int(K.ref_scalar(next(_request_counter), salt=0x9E57))
+        with span("rest.request", req=key, route=request.path) as sp:
+            response = await self._serve_request(request, web, key)
+            if sp is not None:
+                sp.args["status"] = response.status
+            return response
+
+    async def _serve_request(self, request, web, key: int):
+        from ...internals.tracing import get_tracer, span
         from ...serve import status as serve_status
         from ...serve.admission import shared_controller
         from ...serve.merge import default_deadline_ms
@@ -279,26 +295,27 @@ class _RestSubject(ConnectorSubject):
         ctrl = shared_controller()
         loop = asyncio.get_event_loop()
         t0 = loop.time()
-        admit = loop.run_in_executor(
-            None,
-            ctrl.try_admit,
-            min(self._ADMIT_WAIT_S, deadline_ms / 1e3),
-        )
-        try:
-            slot = await admit
-        except asyncio.CancelledError:
-            # client gone while waiting at the door: a slot granted after
-            # this point must go straight back
-            admit.add_done_callback(
-                lambda f: (
-                    ctrl.cancel(f.result())
-                    if not f.cancelled()
-                    and f.exception() is None
-                    and f.result() is not None
-                    else None
-                )
+        with span("rest.admit"):
+            admit = loop.run_in_executor(
+                None,
+                ctrl.try_admit,
+                min(self._ADMIT_WAIT_S, deadline_ms / 1e3),
             )
-            raise
+            try:
+                slot = await admit
+            except asyncio.CancelledError:
+                # client gone while waiting at the door: a slot granted
+                # after this point must go straight back
+                admit.add_done_callback(
+                    lambda f: (
+                        ctrl.cancel(f.result())
+                        if not f.cancelled()
+                        and f.exception() is None
+                        and f.result() is not None
+                        else None
+                    )
+                )
+                raise
         if slot is None:
             # saturated: shed at the door with back-off advice so the
             # accepted-query tail stays bounded
@@ -309,7 +326,6 @@ class _RestSubject(ConnectorSubject):
                 headers={"Retry-After": str(max(1, int(retry_s + 0.999)))},
             )
 
-        key = int(K.ref_scalar(next(_request_counter), salt=0x9E57))
         fut = asyncio.get_event_loop().create_future()
         self._futures[key] = fut
         if self.delete_completed_queries:
@@ -320,6 +336,8 @@ class _RestSubject(ConnectorSubject):
             serve_status.note_deadline(
                 key, _time.time_ns() + int(deadline_ms * 1e6)
             )
+            if get_tracer() is not None:
+                self._engine_t0[key] = _time.perf_counter_ns()
             self._next_with_key(key, **row)
             self.commit()
             remaining_s = max(0.001, deadline_ms / 1e3 - (loop.time() - t0))
@@ -327,27 +345,32 @@ class _RestSubject(ConnectorSubject):
                 result = await asyncio.wait_for(fut, timeout=remaining_s)
             except asyncio.TimeoutError:
                 self._futures.pop(key, None)
+                self._engine_t0.pop(key, None)
                 serve_bump("deadline_dropped_total")
                 return web.json_response({"error": "timeout"}, status=504)
-            if isinstance(result, Json):
-                result = result.value
-            headers = {}
-            st = serve_status.take_status(key)
-            if st is not None and (
-                st.get("degraded") or st.get("deadline_exceeded")
-            ):
-                headers["X-Pathway-Degraded"] = "1"
-                if isinstance(result, dict):
-                    result = dict(result)
-                    result["degraded"] = True
-                    result["missing_shards"] = list(
-                        st.get("missing_shards", ())
-                    )
-            return web.json_response(result, dumps=_dumps, headers=headers)
+            with span("rest.reply"):
+                if isinstance(result, Json):
+                    result = result.value
+                headers = {}
+                st = serve_status.take_status(key)
+                if st is not None and (
+                    st.get("degraded") or st.get("deadline_exceeded")
+                ):
+                    headers["X-Pathway-Degraded"] = "1"
+                    if isinstance(result, dict):
+                        result = dict(result)
+                        result["degraded"] = True
+                        result["missing_shards"] = list(
+                            st.get("missing_shards", ())
+                        )
+                return web.json_response(
+                    result, dumps=_dumps, headers=headers
+                )
         except asyncio.CancelledError:
             # client disconnected mid-flight: free the slot now, drop the
             # pending future (the engine's late answer finds nobody)
             self._futures.pop(key, None)
+            self._engine_t0.pop(key, None)
             ctrl.cancel(slot)
             slot = None
             raise
@@ -357,6 +380,19 @@ class _RestSubject(ConnectorSubject):
 
     def _complete(self, key: int, value: Any) -> None:
         """Called from the engine thread by the response writer sink."""
+        t0_ns = self._engine_t0.pop(key, None)
+        if t0_ns is not None:
+            from ...internals import tracing
+
+            tracer = tracing.get_tracer()
+            if tracer is not None:
+                # began on the asyncio thread, ends here inside the tick
+                # that answered it (whose id it takes): no ``with``
+                tracer.complete(
+                    "rest.in_engine", t0_ns,
+                    {"req": key, "parent": "rest.request",
+                     **tracing.current_ids()},
+                )
         fut = self._futures.pop(key, None)
         if fut is not None and not fut.done():
             loop = self.webserver._loop

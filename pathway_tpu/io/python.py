@@ -727,10 +727,15 @@ class PythonSubjectSource(RealtimeSource):
             ):
                 self._window_ingest_ns = t0_ns
 
-    def _close_commit(self, out: list[Delta]) -> None:
+    def _close_commit(self, out: list[Delta], reason: str) -> None:
+        """Close the open commit window into one delta. ``reason``: the
+        subject's ``commit`` marker, the autocommit timer falling ``due``,
+        or the subject being ``done``."""
         self._flush_partial()
         if self._pending:
             from ..engine.delta import concat_deltas
+            from ..internals.tracing import get_tracer
+            from ..serve.stats import bump
 
             stage = _stage_sinks(self._conn_name)
             t0 = _time.perf_counter_ns()
@@ -750,6 +755,18 @@ class PythonSubjectSource(RealtimeSource):
             out.append(d)
             self._pending = []
             self._out_ingest.append(self._window_ingest_ns)
+            bump("connector_windows_total")
+            tracer = get_tracer()
+            if tracer is not None:
+                # from the arrival of the window's oldest row (a unix
+                # stamp, carried onto the span clock) to this close
+                now = _time.perf_counter_ns()
+                stamp = self._window_ingest_ns
+                waited = max(0, _time.time_ns() - stamp) if stamp else 0
+                tracer.complete(
+                    "connector.window", now - waited,
+                    {"rows": len(d), "reason": reason},
+                )
         self._window_ingest_ns = None
 
     def take_ingest_stamps(self) -> list[int | None]:
@@ -777,7 +794,7 @@ class PythonSubjectSource(RealtimeSource):
                     f"connector source {type(self.subject).__name__} failed"
                 ) from item.exc
             if item is _COMMIT:
-                self._close_commit(out)
+                self._close_commit(out, "commit")
                 self._last_flush = _time.monotonic()
                 continue
             if isinstance(item, _Batch):
@@ -812,7 +829,7 @@ class PythonSubjectSource(RealtimeSource):
             and (now - self._last_flush) * 1000.0 >= self.autocommit_ms
         )
         if (self._partial or self._pending) and (self._done or flush_due):
-            self._close_commit(out)
+            self._close_commit(out, "done" if self._done else "due")
             self._last_flush = now
         c = self._coalesce_windows
         if c and len(out) > c:

@@ -13,7 +13,6 @@ pretrained MiniLM-class weights is a straight param-tree mapping.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 from ..utils import jaxcfg  # noqa: F401  (configures jax before first use)
@@ -238,7 +237,15 @@ class Embedder:
         self.cfg = cfg or EmbedderConfig()
         self.params = params if params is not None else init_params(self.cfg, seed)
         self.tokenizer = tokenizer
-        self._fwd = jax.jit(functools.partial(embed_tokens, cfg=self.cfg))
+        cfg = self.cfg
+
+        def forward(params, token_ids):
+            return embed_tokens(params, token_ids, cfg=cfg)
+
+        # a named function, not a bare functools.partial: a device trace
+        # then shows the program as ``jit_embed_tokens``, not ``jit__unknown``
+        forward.__name__ = forward.__qualname__ = "embed_tokens"
+        self._fwd = jax.jit(forward)
 
     @classmethod
     def from_pretrained(
@@ -294,19 +301,23 @@ class Embedder:
         vs absent columns), and a 4-token serve query pays a 16-token
         forward instead of a ``max_len`` one (the dominant slice of REST
         p50 off-TPU). One jit cache entry per bucket."""
+        from ..internals.tracing import span
+        from ..serve.stats import bump
+
         max_len = min(max_len, self.cfg.max_len)  # position-table bound
-        if self.tokenizer is not None:
-            toks = self.tokenizer.encode_batch(texts, max_len)
-        else:
-            if self.cfg.arch == "bert":
-                raise RuntimeError(
-                    "pretrained (arch='bert') embedder has no tokenizer: the "
-                    "hashing stand-in would feed token ids the checkpoint was "
-                    "never trained on — load with a vocab.txt (WordPiece) or "
-                    "pass tokenizer="
-                )
-            toks = tokenize_batch(texts, self.cfg.vocab_size, max_len)
-        toks = np.asarray(toks, dtype=np.int32)
+        with span("embed.tokenize", q=len(texts)):
+            if self.tokenizer is not None:
+                toks = self.tokenizer.encode_batch(texts, max_len)
+            else:
+                if self.cfg.arch == "bert":
+                    raise RuntimeError(
+                        "pretrained (arch='bert') embedder has no tokenizer: "
+                        "the hashing stand-in would feed token ids the "
+                        "checkpoint was never trained on — load with a "
+                        "vocab.txt (WordPiece) or pass tokenizer="
+                    )
+                toks = tokenize_batch(texts, self.cfg.vocab_size, max_len)
+            toks = np.asarray(toks, dtype=np.int32)
         n, width = toks.shape
         if n == 0:
             return self._fwd(self.params, jnp.asarray(toks))
@@ -321,14 +332,19 @@ class Embedder:
             16, 2 ** np.ceil(np.log2(np.maximum(lengths, 1))).astype(np.int64)
         )
         buckets = np.minimum(buckets, width)
+        # useful work over attempted work in the forward
+        bump("embed_real_tokens_total", int(lengths.sum()))
+        bump("embed_padded_tokens_total", int(buckets.sum()))
         uniq = np.unique(buckets)
         if len(uniq) == 1:
             b = int(uniq[0])
-            return self._fwd(self.params, jnp.asarray(toks[:, :b]))
+            with span("embed.dispatch", bucket=b, rows=n):
+                return self._fwd(self.params, jnp.asarray(toks[:, :b]))
         out = None
         for b in uniq.tolist():
             ix = np.flatnonzero(buckets == b)
-            part = self._fwd(self.params, jnp.asarray(toks[ix, :b]))
+            with span("embed.dispatch", bucket=b, rows=len(ix)):
+                part = self._fwd(self.params, jnp.asarray(toks[ix, :b]))
             if out is None:
                 out = jnp.zeros((n, part.shape[1]), part.dtype)
             out = out.at[jnp.asarray(ix)].set(part)

@@ -234,61 +234,78 @@ class BruteForceKnnEngine:
 
     # -- search ------------------------------------------------------------
     def search(self, queries: list[Any], limits: list[int], filters: list[Any]):
-        n = self._slots.high
-        if n == 0 or not queries:
-            return [[] for _ in queries]
-        from ..utils import jaxcfg  # noqa: F401
+        from ..internals.tracing import span
+        from ..serve.stats import bump
 
-        import jax.numpy as jnp
+        bump("index_searches_total")
+        bump("index_search_queries_total", len(queries))
+        with span(
+            "index.search", q=len(queries),
+            dirty=bool(self._dirty or self._device is None),
+            k=max(limits, default=0),
+        ):
+            n = self._slots.high
+            if n == 0 or not queries:
+                return [[] for _ in queries]
+            from ..utils import jaxcfg  # noqa: F401
 
-        from .knn import topk_scores
+            import jax.numpy as jnp
 
-        dev_embed = getattr(self.embedder, "embed_texts_device", None)
-        if dev_embed is not None and all(isinstance(x, str) for x in queries):
-            # device-resident query embeddings (already L2-normalized by the
-            # model head) flow straight into the scorer: embed -> score ->
-            # top_k pipelines as queued device work with a single blocking
-            # fetch at _pack time
-            q = dev_embed(list(queries))
-        else:
-            q = np.stack([self._vec(x) for x in queries])
-        if self._dirty or self._device is None:
-            self._device = jnp.asarray(self._host)
-            self._device_valid = jnp.asarray(self._valid)
-            self._dirty = False
+            from .knn import topk_scores
 
-        kmax = min(max(limits), int(self._valid.sum()))
-        if kmax <= 0:
-            return [[] for _ in queries]
+            dev_embed = getattr(self.embedder, "embed_texts_device", None)
+            if dev_embed is not None and all(isinstance(x, str) for x in queries):
+                # device-resident query embeddings (already L2-normalized by the
+                # model head) flow straight into the scorer: embed -> score ->
+                # top_k pipelines as queued device work with a single blocking
+                # fetch at _pack time
+                with span("index.embed", q=len(queries)):
+                    q = dev_embed(list(queries))
+            else:
+                q = np.stack([self._vec(x) for x in queries])
+            if self._dirty or self._device is None:
+                with span("index.upload", bytes=self._host.nbytes):
+                    self._device = jnp.asarray(self._host)
+                    self._device_valid = jnp.asarray(self._valid)
+                    self._dirty = False
+                bump("index_uploads_total")
+                bump("index_upload_bytes_total", self._host.nbytes)
 
-        filt_fns = [compile_metadata_filter(f) for f in filters]
-        if any(f is not None for f in filt_fns):
-            # per-query validity: metadata filter evaluated on the host
-            # directory, applied as a -inf mask before device top-k
-            out = []
-            for qi, (fv, lim) in enumerate(zip(filt_fns, limits)):
-                mask = self._valid.copy()
-                if fv is not None:
-                    for slot in range(n):
-                        if mask[slot] and not fv(self._slots.meta.get(slot)):
-                            mask[slot] = False
-                k_eff = min(lim, int(mask.sum()))
-                if k_eff <= 0:
-                    out.append([])
-                    continue
-                s, ids = topk_scores(
-                    jnp.asarray(q[qi : qi + 1]), self._device, k_eff,
-                    self.metric, valid=jnp.asarray(mask),
-                )
-                out.append(self._pack(np.asarray(s)[0], np.asarray(ids)[0], lim))
-            return out
+            kmax = min(max(limits), int(self._valid.sum()))
+            if kmax <= 0:
+                return [[] for _ in queries]
 
-        s, ids = topk_scores(jnp.asarray(q), self._device, kmax, self.metric,
-                             valid=self._device_valid)
-        s, ids = np.asarray(s), np.asarray(ids)
-        return [
-            self._pack(s[i], ids[i], limits[i]) for i in range(len(queries))
-        ]
+            filt_fns = [compile_metadata_filter(f) for f in filters]
+            if any(f is not None for f in filt_fns):
+                # per-query validity: metadata filter evaluated on the host
+                # directory, applied as a -inf mask before device top-k
+                out = []
+                for qi, (fv, lim) in enumerate(zip(filt_fns, limits)):
+                    mask = self._valid.copy()
+                    if fv is not None:
+                        for slot in range(n):
+                            if mask[slot] and not fv(self._slots.meta.get(slot)):
+                                mask[slot] = False
+                    k_eff = min(lim, int(mask.sum()))
+                    if k_eff <= 0:
+                        out.append([])
+                        continue
+                    s, ids = topk_scores(
+                        jnp.asarray(q[qi : qi + 1]), self._device, k_eff,
+                        self.metric, valid=jnp.asarray(mask),
+                    )
+                    out.append(self._pack(np.asarray(s)[0], np.asarray(ids)[0], lim))
+                return out
+
+            with span("index.score", q=len(queries), rows=n):
+                s, ids = topk_scores(jnp.asarray(q), self._device, kmax,
+                                     self.metric, valid=self._device_valid)
+            with span("index.fetch"):  # where the host waits for the device
+                s, ids = np.asarray(s), np.asarray(ids)
+            with span("index.pack", replies=len(queries)):
+                return [
+                    self._pack(s[i], ids[i], limits[i]) for i in range(len(queries))
+                ]
 
     def _pack(self, scores: np.ndarray, slots: np.ndarray, limit: int):
         out = []

@@ -23,7 +23,6 @@ __all__ = [
     "bump",
     "serve_stats_snapshot",
     "register_gauge_provider",
-    "unregister_gauge_provider",
     "reset_serve_stats",
 ]
 
@@ -52,6 +51,23 @@ SERVE_STATS: dict[str, int] = {
     "cancelled_total": 0,
     #: shard responder errors surfaced as failed shards
     "errors_total": 0,
+    # -- the layers under the edge, counted at the boundaries the spans of
+    # -- internals/tracing.py time (docs/observability.md has the table)
+    #: BruteForceKnnEngine.search calls / the queries they carried
+    "index_searches_total": 0,
+    "index_search_queries_total": 0,
+    #: whole-block host-to-device uploads a search made (index was dirty)
+    "index_uploads_total": 0,
+    "index_upload_bytes_total": 0,
+    #: rows the dataflow added to / removed from an external index
+    "index_rows_added_total": 0,
+    "index_rows_removed_total": 0,
+    #: commit windows a python connector closed into a delta
+    "connector_windows_total": 0,
+    #: tokens the embed forward was asked for: real ones / with the
+    #: padding of their length bucket (useful over attempted work)
+    "embed_real_tokens_total": 0,
+    "embed_padded_tokens_total": 0,
 }
 
 _lock = threading.Lock()
@@ -71,14 +87,6 @@ def register_gauge_provider(fn: Callable[[], dict[str, float]]) -> None:
     with _lock:
         if fn not in _gauge_providers:
             _gauge_providers.append(fn)
-
-
-def unregister_gauge_provider(fn: Callable[[], dict[str, float]]) -> None:
-    with _lock:
-        try:
-            _gauge_providers.remove(fn)
-        except ValueError:
-            pass
 
 
 def serve_stats_snapshot() -> dict[str, float]:
