@@ -17,7 +17,10 @@ import numpy as np
 
 from . import keys as K
 
-__all__ = ["Delta", "concat_deltas", "rows_to_columns", "column_of_values", "rows_equal"]
+__all__ = [
+    "Delta", "concat_deltas", "consolidation_plan", "rows_to_columns",
+    "column_of_values", "rows_equal",
+]
 
 
 def rows_equal(a: tuple | None, b: tuple | None) -> bool:
@@ -197,13 +200,18 @@ class Delta:
         The analog of differential's ``consolidate``; output ops use it so a
         retract+insert of an unchanged row cancels out within a tick.
 
+        Two entries can only merge or cancel when their keys are equal,
+        so row content is hashed only inside groups of entries that
+        share a key (:func:`consolidation_plan`); an entry whose key is
+        alone in the batch passes through untouched. Surviving entries
+        keep their input order.
+
         Fast paths (fusion subsystem, ``PATHWAY_FUSION=0`` disables):
         an all-insertions batch can neither cancel nor go negative, so
 
         - with unique keys it is PROVABLY already consolidated — the
-          batch returns as-is, skipping the row-signature hash + sort of
-          every column (the chain-exit/sink-side cost the fusion work
-          targets);
+          batch returns as-is (the chain-exit/sink-side cost the fusion
+          work targets);
         - ``multiset_ok=True`` (engine-internal edges: the join output
           feeding downstream operators) returns it as-is even with
           duplicate keys — duplicate (key, row) entries at +1/+1 are the
@@ -220,22 +228,56 @@ class Delta:
             if multiset_ok or K.all_unique(self.keys):
                 FUSION_STATS["consolidation_skips_total"] += 1
                 return self
-        # asymmetric combine — a plain xor would zero out whenever row keys
-        # are themselves content-derived (same mix as the row hash)
-        row_sig = K.derive_pair(
-            self.keys,
-            K.mix_columns(list(self.data.values()), len(self), register=False),
+        plan = consolidation_plan(
+            self.keys, list(self.data.values()), self.diffs
         )
-        order = np.argsort(row_sig, kind="stable")
-        sig_sorted = row_sig[order]
-        boundaries = np.flatnonzero(np.diff(sig_sorted) != 0) + 1
-        starts = np.concatenate([[0], boundaries])
-        sums = np.add.reduceat(self.diffs[order], starts)
-        keep = sums != 0
-        reps = order[starts[keep]]
-        out = self.take(reps)
-        out.diffs = sums[keep]
+        if plan is None:
+            return self
+        keep, sums = plan
+        out = self.take(keep)
+        out.diffs = sums
         return out
+
+
+def consolidation_plan(
+    ids: np.ndarray, cols: list[np.ndarray], diffs: np.ndarray
+) -> tuple[np.ndarray, np.ndarray] | None:
+    """Differential consolidation of ``(ids[i], row i of cols, diffs[i])``
+    entries: ``(keep, sums)`` — the positions of the surviving entries in
+    input order (the first of each group of identical entries) and their
+    summed diffs — or None when the batch is already consolidated.
+
+    Identical entries have equal ids, so content is hashed only for the
+    entries whose id recurs in the batch; an id seen once keeps its entry
+    (unless its diff is 0). ``FUSION_STATS`` counts both populations."""
+    from .fusion import FUSION_STATS
+
+    n = len(ids)
+    FUSION_STATS["consolidation_rows_total"] += n
+    recurs = K.recurring(ids)
+    if recurs is None:
+        if diffs.all():
+            return None
+        keep = np.flatnonzero(diffs)
+        return keep, diffs[keep]
+    group = np.flatnonzero(recurs)
+    FUSION_STATS["consolidation_rows_hashed_total"] += len(group)
+    if len(group) < n:
+        ids, cols = ids[group], [c[group] for c in cols]
+    # asymmetric combine — a plain xor would zero out whenever row keys
+    # are themselves content-derived (same mix as the row hash)
+    sig = K.derive_pair(
+        ids, K.mix_columns(cols, len(group), register=False)
+    )
+    by_sig = np.argsort(sig, kind="stable")
+    sig_sorted = sig[by_sig]
+    starts = np.concatenate([[0], np.flatnonzero(np.diff(sig_sorted) != 0) + 1])
+    sums = diffs.copy()
+    sums[group] = 0
+    # stable sort over ascending positions: a group's first is its earliest
+    sums[group[by_sig[starts]]] = np.add.reduceat(diffs[group][by_sig], starts)
+    keep = np.flatnonzero(sums)
+    return keep, sums[keep]
 
 
 def concat_deltas(deltas: list[Delta], columns: list[str] | None = None) -> Delta:

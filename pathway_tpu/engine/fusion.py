@@ -96,6 +96,11 @@ FUSION_STATS: dict[str, int] = {
     "preambles_total": 0,     # Rowwise preambles absorbed into groupby/join
     "key_reuse_total": 0,     # batches whose group/join keys reused row keys
     "consolidation_skips_total": 0,  # provably-identity consolidations skipped
+    # consolidation by key first (engine/delta.py consolidation_plan, also
+    # behind _SortedSide merges and Join._check_unique_ids): entries seen,
+    # and the ones whose key recurred so their row content was hashed
+    "consolidation_rows_total": 0,
+    "consolidation_rows_hashed_total": 0,
 }
 
 

@@ -912,6 +912,18 @@ def all_unique(keys: KeyArray) -> bool:
     return len(np.unique(keys)) == n
 
 
+def recurring(keys: KeyArray) -> np.ndarray | None:
+    """Mask of the entries whose key occurs more than once in ``keys``;
+    None when no key repeats. Consolidation looks at row content only
+    inside these groups (engine/delta.py ``consolidation_plan``)."""
+    if all_unique(keys):
+        return None
+    from .slotmap import SlotMap
+
+    slots, _ = SlotMap().lookup_or_insert(keys)
+    return np.bincount(slots)[slots] > 1
+
+
 def derive(keys: KeyArray, salt: int) -> KeyArray:
     """Derive child keys from parent keys (concat_reindex, flatten branches)."""
     return _splitmix(keys ^ _splitmix(np.full(len(keys), np.uint64(salt), dtype=np.uint64)))

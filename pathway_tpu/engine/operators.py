@@ -19,7 +19,13 @@ from typing import Any, Callable
 import numpy as np
 
 from . import keys as K
-from .delta import Delta, column_of_values, concat_deltas, rows_to_columns
+from .delta import (
+    Delta,
+    column_of_values,
+    concat_deltas,
+    consolidation_plan,
+    rows_to_columns,
+)
 from .error import ERROR_LOG, Error as EngineError, errors_seen, is_error
 from .executor import END_TIME, Node, SourceNode
 from .reducers import ReducerImpl
@@ -1887,18 +1893,14 @@ class _SortedSide:
     @staticmethod
     def _consolidate(jks, keys, cols, counts):
         """Sum multiplicities of identical (jk, row_key, values) rows and
-        drop the zeros — differential consolidation over a row batch."""
-        sig = K.derive_pair(
-            K.derive_pair(jks, keys),
-            K.mix_columns(cols, len(jks), register=False),
-        )
-        order = np.argsort(sig, kind="stable")
-        ss = sig[order]
-        starts = np.concatenate([[0], np.flatnonzero(np.diff(ss) != 0) + 1])
-        sums = np.add.reduceat(counts[order], starts)
-        keep = sums != 0
-        reps = order[starts[keep]]
-        return jks[reps], keys[reps], [c[reps] for c in cols], sums[keep]
+        drop the zeros — differential consolidation over a row batch.
+        Values are hashed only for rows whose (jk, row_key) pair recurs
+        (a retraction meeting its insert); the rest keep their count."""
+        plan = consolidation_plan(K.derive_pair(jks, keys), cols, counts)
+        if plan is None:
+            return jks, keys, cols, counts
+        keep, sums = plan
+        return jks[keep], keys[keep], [c[keep] for c in cols], sums
 
     def _compact(self) -> None:
         from .delta import _concat_cols
@@ -2671,25 +2673,82 @@ class Join(Node):
             ) + tuple(base[n_l:])
         return [(self._DUP_SIG, err_row, 1)]
 
+    #: ``_idstate`` sig of a row stored without hashing: real sigs are
+    #: uint64, so -1 never collides (and pickles with the state)
+    _UNHASHED = -1
+
+    def _hash_id_entries(self, ids) -> None:
+        """Give the rows of ``ids`` that were stored unhashed their
+        content sig — a second candidate row showed up for the id, so
+        the entries have to be told apart."""
+        state = self._idstate
+        lazy = [k for k in ids if self._UNHASHED in state.get(k, ())]
+        if not lazy:
+            return
+        rows = [state[k][self._UNHASHED][0] for k in lazy]
+        sigs = K.mix_columns(
+            list(rows_to_columns(rows, self.column_names).values()),
+            len(rows), register=False,
+        ).tolist()
+        for k, sg in zip(lazy, sigs):
+            ent = state[k]
+            ent[sg] = ent.pop(self._UNHASHED)
+
     def _check_unique_ids(self, delta: Delta | None) -> Delta | None:
         """key_mode left/right: every output key is an id-side row id.
         Multiplicity ≤ 1 passes through untouched; an id matched by
         several rows degrades to ONE row with Error values in the other
         side's columns and a "duplicate key" log entry, recovering when
         matches drop back to one (reference id-preserving join contract,
-        test_errors.py:483)."""
+        test_errors.py:483).
+
+        Row content is hashed only when an id has more than one
+        candidate row to tell apart: an id that holds nothing and gains
+        one row, or holds one row and loses it, keeps the row unhashed
+        and its entry passes through as it came."""
         if self._key_mode == "pair" or delta is None or not len(delta):
             return delta
+        from .fusion import FUSION_STATS
+
         n = len(delta)
-        sigs = K.mix_columns(
-            list(delta.data.values()), n, register=False
-        ).tolist()
+        FUSION_STATS["consolidation_rows_total"] += n
         keys_l = delta.keys.tolist()
         diffs_l = delta.diffs.tolist()
         cols = [np.asarray(delta.data[c]) for c in self.column_names]
         state = self._idstate
-        old_proj = {k: self._project_id_key(k) for k in set(keys_l)}
-        for i, (k, sg, df) in enumerate(zip(keys_l, sigs, diffs_l)):
+        recurs = K.recurring(delta.keys)
+        alone = [True] * n if recurs is None else (~recurs).tolist()
+        through: list[int] = []  # entries that pass as they came
+        hashed: list[int] = []
+        for i, (k, df) in enumerate(zip(keys_l, diffs_l)):
+            if alone[i]:
+                ent = state.get(k)
+                if ent is None:
+                    if df == 1:
+                        state[k] = {
+                            self._UNHASHED: [tuple(c[i] for c in cols), 1]
+                        }
+                        through.append(i)
+                        continue
+                elif df == -1 and len(ent) == 1:
+                    (_row, cnt), = ent.values()
+                    if cnt == 1:
+                        del state[k]
+                        through.append(i)
+                        continue
+            hashed.append(i)
+        if not hashed:
+            return delta
+        FUSION_STATS["consolidation_rows_hashed_total"] += len(hashed)
+        idx = np.asarray(hashed, dtype=np.int64)
+        sigs = K.mix_columns(
+            [c[idx] for c in cols], len(idx), register=False
+        ).tolist()
+        touched = dict.fromkeys(keys_l[i] for i in hashed)
+        self._hash_id_entries(touched)
+        old_proj = {k: self._project_id_key(k) for k in touched}
+        for i, sg in zip(hashed, sigs):
+            k, df = keys_l[i], diffs_l[i]
             ent = state.setdefault(k, {})
             cur = ent.get(sg)
             if cur is None:
@@ -2719,13 +2778,15 @@ class Join(Node):
                 out_keys.append(k)
                 out_rows.append(row)
                 out_diffs.append(cnt)
-        if not out_keys:
-            return None
-        return Delta(
-            keys=np.array(out_keys, dtype=np.uint64),
-            data=rows_to_columns(out_rows, self.column_names),
-            diffs=np.array(out_diffs, dtype=np.int64),
-        ).consolidated()
+        parts = [delta.take(np.asarray(through, dtype=np.int64))]
+        if out_keys:
+            parts.append(Delta(
+                keys=np.array(out_keys, dtype=np.uint64),
+                data=rows_to_columns(out_rows, self.column_names),
+                diffs=np.array(out_diffs, dtype=np.int64),
+            ))
+        out = concat_deltas(parts, self.column_names)
+        return out.consolidated() if len(out) else None
 
     def _repad(self, out, d_this, d_other, this_idx: MultiIndex, other_idx: MultiIndex, pad_state: dict[int, int], pad_fn) -> None:
         affected_jks = {jk for jk, _, _, _ in d_this} | {jk for jk, _, _, _ in d_other}

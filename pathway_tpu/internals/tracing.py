@@ -42,8 +42,10 @@ Span taxonomy (mirrors the reference's span names where it has them):
   emitted row count — the analog of timely's event logging stream
   (``DIFFERENTIAL_LOG_ADDR``, reference ``dataflow.rs:5540-5548``);
 - counter samples of ``EngineStats`` totals per tick, rendered by the
-  trace viewers as time series, and one ``serve_stats`` sample of the
-  ``serve/stats.py`` counters at each flush;
+  trace viewers as time series, one ``serve_stats`` sample of the
+  ``serve/stats.py`` counters at each flush, and a ``fusion_stats`` sample
+  of ``engine/fusion.py``'s at a profiler session's first span and at
+  each flush;
 - the serving path: ``rest.request`` / ``rest.admit`` / ``rest.in_engine``
   / ``rest.reply`` (``io/http/_server.py``), ``connector.window``
   (``io/python.py``), ``engine.park`` (the streaming loops),
@@ -297,20 +299,30 @@ class Tracer:
             ev["args"] = args
         self._append(ev)
 
+    def _counter_event(self, name: str, values: dict[str, float]) -> dict:
+        return {
+            "name": name,
+            "ph": "C",
+            "ts": self._ts(time.perf_counter_ns()),
+            "pid": self._pid,
+            "args": values,
+        }
+
+    def _fusion_sample(self) -> dict:
+        """The engine's fusion and consolidation counters as they stand
+        now (``engine/fusion.py`` FUSION_STATS): a session's first span and
+        every written file carry one, so their difference is the session's
+        own share (rows consolidated, rows whose content was hashed)."""
+        from ..engine.fusion import FUSION_STATS
+
+        return self._counter_event("fusion_stats", dict(FUSION_STATS))
+
     def counter(self, name: str, values: dict[str, float]) -> None:
         """A counter sample (rendered as stacked time series). Callers with
         per-worker counters must put the worker id in ``name`` — trace
         viewers key counter tracks by (pid, name), so same-named samples
         from different workers would interleave into one garbled series."""
-        self._append(
-            {
-                "name": name,
-                "ph": "C",
-                "ts": self._ts(time.perf_counter_ns()),
-                "pid": self._pid,
-                "args": values,
-            }
-        )
+        self._append(self._counter_event(name, values))
 
     # -- cross-worker flow linkage ------------------------------------
 
@@ -451,15 +463,8 @@ class Tracer:
         # kept in the buffer
         from ..serve.stats import SERVE_STATS
 
-        events.append(
-            {
-                "name": "serve_stats",
-                "ph": "C",
-                "ts": self._ts(time.perf_counter_ns()),
-                "pid": self._pid,
-                "args": dict(SERVE_STATS),
-            }
-        )
+        events.append(self._counter_event("serve_stats", dict(SERVE_STATS)))
+        events.append(self._fusion_sample())
         try:
             if self.session:
                 os.makedirs(os.path.dirname(path), exist_ok=True)
@@ -581,6 +586,7 @@ def get_tracer() -> Tracer | None:
                     os.path.join(spans_dir(), f"{os.getpid()}.json")
                 )
                 tracer.session = True
+                tracer._append(tracer._fusion_sample())
                 _session = tracer
     return _session
 

@@ -241,6 +241,12 @@ def test_a_profiler_session_alone_gets_the_spans_on_both_clocks(tmp_path):
     names = {e["name"] for e in events}
     assert {*REQUEST_SPANS, "tick", "index.search", *SEARCH_SPANS, *EMBED_SPANS} <= names
     assert sum(e["name"] == "index.search" for e in events) == 3
+    # the engine's consolidation counters at the session's first span and at
+    # the flush: what lies between them was consolidated from the session on
+    first, *_, last = [e["args"] for e in doc["traceEvents"] if e["name"] == "fusion_stats"]
+    assert last["consolidation_rows_total"] > first["consolidation_rows_total"]
+    assert (last["consolidation_rows_hashed_total"] - first["consolidation_rows_hashed_total"]
+            < last["consolidation_rows_total"] - first["consolidation_rows_total"])
     # the same spans lie in the profiler's host plane, on the trace's clock
     # (rest.in_engine ends on another thread than it began: file only)
     xplane = glob.glob(str(tmp_path / "profile" / "**" / "*.xplane.pb"), recursive=True)[0]
