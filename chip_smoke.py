@@ -431,6 +431,7 @@ def phase_index(sz: dict, log: PhaseLog, workdir: str) -> None:
 
     import pathway_tpu as pw
     from pathway_tpu.ops.knn import topk_scores
+    from pathway_tpu.serve.stats import SERVE_STATS
     from pathway_tpu.stdlib.indexing.nearest_neighbors import (
         BruteForceKnnFactory,
     )
@@ -446,6 +447,10 @@ def phase_index(sz: dict, log: PhaseLog, workdir: str) -> None:
     rng = np.random.default_rng(SEED)
     feed_rows = 65_536
 
+    written = "which row was written after the first search"
+    written_vec = embedder.embedder.embed_texts([written])[0]
+    write_now = threading.Event()
+
     class Feed(pw.io.python.ConnectorSubject):
         def run(self) -> None:
             for start in range(0, n, feed_rows):
@@ -454,6 +459,8 @@ def phase_index(sz: dict, log: PhaseLog, workdir: str) -> None:
                 vecs /= np.linalg.norm(vecs, axis=1, keepdims=True)
                 if start <= needle_row < stop:
                     vecs[needle_row - start] = needle_vec
+                if start == 0:
+                    first_vec = vecs[0].copy()
                 self.next_batch({
                     "data": [f"doc {i}" for i in range(start, stop)],
                     "_metadata": [
@@ -462,6 +469,15 @@ def phase_index(sz: dict, log: PhaseLog, workdir: str) -> None:
                     "vec": list(vecs),
                 })
                 self.commit()
+            # one write once the index has been searched: row 0 replaced
+            # (its slot goes through the free list), the count unchanged
+            write_now.wait()
+            self.next_batch({
+                "data": ["doc 0", "doc written"],
+                "_metadata": [{"path": "d0.txt"}, {"path": "written.txt"}],
+                "vec": [first_vec, written_vec],
+            }, np.array([-1, 1], np.int64))
+            self.commit()
 
     docs = pw.io.python.read(
         Feed(),
@@ -484,12 +500,29 @@ def phase_index(sz: dict, log: PhaseLog, workdir: str) -> None:
         for q, expect in words.queries(sz["index_sequential"], 2, planted):
             _check_retrieve(port, q, expect)
         _burst(port, words.queries(sz["burst"], 8, planted))
+        # the write path: the row lands in the device block in place, so
+        # the block is not placed again and the written row is found
+        before = dict(SERVE_STATS)
+        write_now.set()
+
+        def written_found() -> bool:
+            try:
+                _check_retrieve(port, written, "doc written")
+            except RuntimeError:
+                return False
+            return True
+
+        _await("the written row", 60.0, written_found)
+        grew = {k: SERVE_STATS[k] - before[k] for k in (
+            "index_uploads_total", "index_writes_total", "index_write_rows_total")}
+        if grew["index_uploads_total"] or grew["index_writes_total"] != 1:
+            raise RuntimeError(f"a write re-placed the index block: {grew}")
         (engine,) = _engines()
         log.emit(
             "index", rows=int(engine._valid.sum()), dim=DIM,
             index_capacity=engine.capacity,
             sequential=sz["index_sequential"], burst=sz["burst"], k=K,
-            topk_shapes=topk_scores._cache_size(),
+            topk_shapes=topk_scores._cache_size(), **grew,
         )
 
 

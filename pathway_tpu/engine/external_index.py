@@ -305,26 +305,22 @@ class ExternalIndexNode(Node):
         bump("index_rows_added_total", added)
         bump("index_rows_removed_total", removed)
         with span("index.apply", added=added, removed=removed):
-            cols = data_d.data
+            # several commits may arrive as one delta (a connector merges
+            # the windows it is behind by): a retraction then stands beside
+            # its own insert, and only the net change is this tick's. What
+            # is left goes removals first, so an update (retract + insert of
+            # one key, in either order) lands as the new value.
+            data_d = data_d.consolidated()
+            cols, keys, diffs = data_d.data, data_d.keys, data_d.diffs
             filt = cols.get("__filter_data__")
             datas = cols["__data__"]
-            # removals before insertions so an in-tick update (retract+insert
-            # of the same key) lands in the index as the new value
-            add_keys: list[int] = []
-            add_datas: list[Any] = []
-            add_filts: list[Any] = []
-            order = np.argsort(data_d.diffs, kind="stable")
-            for i in order:
-                k = int(data_d.keys[i])
-                if data_d.diffs[i] < 0:
-                    for _ in range(-int(data_d.diffs[i])):
-                        self.engine.remove(k)
-                else:
-                    for _ in range(int(data_d.diffs[i])):
-                        add_keys.append(k)
-                        add_datas.append(datas[i])
-                        add_filts.append(filt[i] if filt is not None else None)
-            if add_keys:
+            for k in keys[diffs < 0].tolist():
+                self.engine.remove(k)
+            pos = np.flatnonzero(diffs > 0)
+            if len(pos):
+                add_keys = keys[pos].tolist()
+                add_datas = [datas[i] for i in pos]
+                add_filts = [filt[i] for i in pos] if filt is not None else [None] * len(pos)
                 add_batch = getattr(self.engine, "add_batch", None)
                 if add_batch is not None:
                     # one batched embed + insert per tick, not per document

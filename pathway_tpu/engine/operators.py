@@ -1810,17 +1810,15 @@ class _SortedSide:
             and self._budget is None
             # only batches big enough that the deferred sort pays (tiny
             # batches keep the original eager layout, which unit tests
-            # of the physical run structure observe) — but once a
-            # pending list exists, EVERYTHING defers behind it: runs
-            # must arrange in arrival order or retractions would
-            # consolidate against the wrong prefix
-            and (len(jks) >= 256 or self._pending)
+            # of the physical run structure observe)
+            and len(jks) >= 256
         ):
             # fusion lane: defer sort + tiered merging until something
-            # actually reads the arrangement (probe/totals/snapshot). A
-            # side that is never probed again — the FACT side of a
-            # stream⋈static-dimension join — never pays the maintenance
-            # at all; an always-probed side flushes one batch per tick,
+            # actually reads the arrangement (probe/totals/snapshot) or
+            # the join's input pauses (Join.advance_to). A side that is
+            # never probed again — the FACT side of a stream⋈static-
+            # dimension join — pays no maintenance while its stream
+            # flows; an always-probed side flushes one batch per tick,
             # exactly the eager schedule. Bounded so a never-read side
             # cannot defer an unbounded compaction to snapshot time.
             # Eager under a state memory budget: pending raw batches
@@ -1834,6 +1832,12 @@ class _SortedSide:
             if self._pending_rows >= 262_144:
                 self._flush_pending()
             return
+        # a small batch behind a deferred backlog ends the deferral: the
+        # backlog is arranged now, in arrival order (or retractions would
+        # consolidate against the wrong prefix), at the end of the bulk
+        # load that made it and not under whichever later 16-row write
+        # carries the row counter over its bound
+        self._flush_pending()
         self._apply_now(jks, keys, cols, diffs)
 
     def _flush_pending(self) -> None:
@@ -1855,16 +1859,20 @@ class _SortedSide:
         # size-tiered maintenance: merge the tail while neighbors are
         # within 2x, keeping the run count logarithmic in total rows with
         # amortized O(n log n) total merge work — no periodic full-sort
-        # spike, and probes touch far fewer runs
+        # spike, and probes touch far fewer runs. Over the bound, the two
+        # newest (smallest) runs merge whatever their ratio, and the tiers
+        # settle again: the cost is the size of the last tiers, never that
+        # of the whole arrangement (a million-row base run re-sorted for a
+        # 16-row delta is a wall of a second).
         runs = self._runs
-        while len(runs) > 1 and 2 * len(runs[-1][0]) >= len(runs[-2][0]):
+        while len(runs) > 1 and (
+            2 * len(runs[-1][0]) >= len(runs[-2][0]) or len(runs) > self.MAX_RUNS
+        ):
             b = runs.pop()
             a = runs.pop()
             merged = self._merge_runs(a, b)
             if merged is not None:
                 runs.append(merged)
-        if len(runs) > self.MAX_RUNS:
-            self._compact()
 
     def _merge_runs(self, a: list, b: list) -> list | None:
         """Merge two sorted runs into one (stable: a's rows precede b's
@@ -2574,7 +2582,19 @@ class Join(Node):
                 diffs=np.asarray(counts, dtype=np.int64) * sign,
             ))
 
+    def advance_to(self, time: int) -> Delta | None:
+        # the second tick running that brings this join nothing: a bulk
+        # load has paused, so whatever it left deferred is arranged now,
+        # while nobody waits, and not under the next delta or probe
+        if getattr(self, "_quiet", False):
+            for side in (getattr(self, "_cleft", None), getattr(self, "_cright", None)):
+                if side is not None:
+                    side._flush_pending()
+        self._quiet = True
+        return None
+
     def process(self, time: int, ins: list[Delta | None]) -> Delta | None:
+        self._quiet = False
         if self._preambles[0] is not None or self._preambles[1] is not None:
             ins = [
                 self._apply_preamble(side, d) if self._preambles[side] else d
@@ -3749,9 +3769,12 @@ def _as_column(arr: Any, n: int) -> np.ndarray:
         and arr.dtype.kind not in ("U", "S")
     ):
         return arr
-    # a process that never imported jax cannot hold a jax.Array
-    jax = sys.modules.get("jax")
-    if jax is not None and isinstance(arr, jax.Array):
+    # a process that never imported jax cannot hold a jax.Array, and neither
+    # can one in which another thread is importing it this moment (a shard
+    # responder's first search): the module is in sys.modules before it has
+    # its names
+    jax_array = getattr(sys.modules.get("jax"), "Array", None)
+    if jax_array is not None and isinstance(arr, jax_array):
         return np.asarray(arr)
     if not isinstance(arr, (np.ndarray, list)):
         # anything else — scalars, None, tuples, dicts, Json, arbitrary

@@ -191,6 +191,9 @@ def _compile_expr_uncached(expr: ColumnExpression, env: ColumnEnv) -> Compiled:
             if (
                 not jax_broken[0]
                 and n >= JIT_THRESHOLD
+                # something to compute on: a constant stays the row value
+                # the numpy kernel returns, not a 0-d array off the device
+                and refs
                 and all(cols[c].dtype != object for c in ref_cols)
             ):
                 # warm-up gate: XLA compilation (~100ms) only pays for
@@ -237,8 +240,12 @@ def _compile_expr_uncached(expr: ColumnExpression, env: ColumnEnv) -> Compiled:
                     return np_fn(cols, keys)
                 if not jitted_box:
                     jitted_box.append(_jitted_kernel(expr, env))
+                # the kernel gets the columns it reads, which the gate above
+                # checked, and not whatever else the delta carries (an
+                # object column beside them is no array to trace)
                 with jax.default_device(dev):
-                    return np.asarray(jitted_box[0](cols, keys))
+                    return np.asarray(
+                        jitted_box[0]({c: cols[c] for c in ref_cols}, keys))
             return np_fn(cols, keys)
 
         return Compiled(fn, dtype, jax_ok=True)

@@ -78,8 +78,16 @@ class BruteForceKnnEngine:
     """Exact KNN on TPU: the index block is one [capacity, dim] device array.
 
     ``metric``: "cos" (inputs L2-normalized at insert/query time) or "l2"
-    (negative squared distance). Capacity doubles on overflow — one recompile
-    per tier, amortized.
+    (negative squared distance). Capacity doubles on overflow.
+
+    Writes land in the host block and the host mask and stage the slots they
+    touched; the next search brings the device copy up to date by writing
+    only those slots in place (``ops/knn.py::index_write``, block and mask
+    donated, the slot count padded to ``WRITE_BUCKETS``). The whole block is
+    placed only where there is no device copy to write into: the first
+    search, after ``_grow`` (a new capacity tier, so the scan and the write
+    programs compile once more — all of them at that placement, none later),
+    after unpickling, and when ``_dirty`` was set with nothing staged.
     """
 
     def __init__(self, dimensions: int, *, metric: str = "cos",
@@ -92,20 +100,36 @@ class BruteForceKnnEngine:
         self._host = np.zeros((self.capacity, self.dim), dtype=np.float32)
         self._valid = np.zeros(self.capacity, dtype=bool)
         self._slots = _SlotArena()
-        self._device = None  # lazily synced jax copy
+        self._device = self._device_valid = None  # lazily synced jax copy
+        #: true when the device copy is behind the host block
         self._dirty = True
+        #: slots written on the host since the device copy was last current
+        #: (ints and arrays); empty while there is no device copy
+        self._staged: list = []
 
     # operator snapshots pickle the whole engine; the device mirror is a
     # cache rebuilt on first search after restore
     def __getstate__(self):
         st = dict(self.__dict__)
-        st["_device"] = None
-        st.pop("_device_valid", None)
-        st["_dirty"] = True
+        for name in ("_device", "_device_valid", "_staged"):
+            st.pop(name, None)
         # the embedder may be an arbitrary closure (not picklable); the
         # restoring node grafts the freshly-constructed engine's embedder back
         st["embedder"] = None
         return st
+
+    def __setstate__(self, st):
+        self.__dict__.update(st)
+        self._device = self._device_valid = None
+        self._dirty = True
+        self._staged = []
+
+    def _stage(self, slots) -> None:
+        """The host block changed at ``slots``: the device copy, if there is
+        one, is behind by exactly these."""
+        self._dirty = True
+        if self._device is not None:
+            self._staged.append(slots)
 
     # -- mutation ----------------------------------------------------------
     def _vec(self, data: Any) -> np.ndarray:
@@ -135,7 +159,7 @@ class BruteForceKnnEngine:
         self._host[slot] = v
         self._valid[slot] = True
         self._slots.meta[slot] = _as_json(filter_data)
-        self._dirty = True
+        self._stage(slot)
 
     def add_batch(self, keys: list[int], datas: list[Any], filters: list[Any]) -> None:
         """Bulk insertion: all string payloads of one tick are embedded in a
@@ -214,13 +238,13 @@ class BruteForceKnnEngine:
         for slot, f in zip(slots.tolist(), filters):
             if f is not None:
                 self._slots.meta[slot] = _as_json(f)
-        self._dirty = True
+        self._stage(slots)
 
     def remove(self, key: int) -> None:
         slot = self._slots.release(key)
         if slot is not None:
             self._valid[slot] = False
-            self._dirty = True
+            self._stage(slot)
 
     def _grow(self, needed: int | None = None) -> None:
         new_cap = self.capacity * 2
@@ -231,6 +255,60 @@ class BruteForceKnnEngine:
         valid = np.zeros(new_cap, dtype=bool)
         valid[: self.capacity] = self._valid
         self._host, self._valid, self.capacity = host, valid, new_cap
+        # a new tier is a new block: the old device copy goes now, so that
+        # the two never coexist, and the next search places the new one
+        self._device = self._device_valid = None
+        self._staged = []
+
+    def _sync_device(self) -> None:
+        """Bring the device copy up to date with the host block (span
+        ``index.upload``): the staged slots written in place (``index.write``
+        under it), or the whole block placed where there is no copy to
+        write into."""
+        from ..internals.tracing import span
+        from ..serve.stats import bump
+
+        import jax.numpy as jnp
+
+        from .knn import WRITE_BUCKETS
+
+        if self._device is None or not self._staged:
+            with span("index.upload", bytes=self._host.nbytes, whole=True):
+                self._device = self._device_valid = None  # never two blocks
+                self._device = jnp.asarray(self._host)
+                self._device_valid = jnp.asarray(self._valid)
+            bump("index_uploads_total")
+            bump("index_upload_bytes_total", self._host.nbytes)
+            # every write program of this tier compiles now, on writes that
+            # change nothing (slot 0 with its own row), and none while serving
+            self._write([np.zeros(b, np.int32) for b in WRITE_BUCKETS])
+        else:
+            slots = np.unique(np.hstack(self._staged)).astype(np.int32)
+            cap = WRITE_BUCKETS[-1]
+            # a piece is padded to its bucket with its own slots over again,
+            # each with its own row, so the padding writes nothing new
+            pieces = [
+                np.resize(p, next(b for b in WRITE_BUCKETS if b >= len(p)))
+                for p in (slots[i:i + cap] for i in range(0, len(slots), cap))
+            ]
+            padded = sum(len(p) for p in pieces)
+            nbytes = padded * self.dim * 4
+            with span("index.upload", bytes=nbytes, whole=False), \
+                    span("index.write", rows=len(slots), padded=padded, bytes=nbytes):
+                self._write(pieces)
+            bump("index_writes_total")
+            bump("index_write_rows_total", len(slots))
+            bump("index_write_bytes_total", nbytes)
+        self._dirty = False
+        self._staged = []
+
+    def _write(self, pieces: list[np.ndarray]) -> None:
+        from .knn import index_writer
+
+        write = index_writer()
+        for p in pieces:
+            self._device, self._device_valid = write(
+                self._device, self._device_valid, p, self._host[p], self._valid[p])
 
     # -- search ------------------------------------------------------------
     def search(self, queries: list[Any], limits: list[int], filters: list[Any]):
@@ -264,12 +342,7 @@ class BruteForceKnnEngine:
             else:
                 q = np.stack([self._vec(x) for x in queries])
             if self._dirty or self._device is None:
-                with span("index.upload", bytes=self._host.nbytes):
-                    self._device = jnp.asarray(self._host)
-                    self._device_valid = jnp.asarray(self._valid)
-                    self._dirty = False
-                bump("index_uploads_total")
-                bump("index_upload_bytes_total", self._host.nbytes)
+                self._sync_device()
 
             kmax = min(max(limits), int(self._valid.sum()))
             if kmax <= 0:

@@ -30,8 +30,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "topk_scores", "knn_search", "ShardedKnnIndex", "sharded_knn_search",
-    "merge_shard_topk",
+    "merge_shard_topk", "index_write", "index_writer", "WRITE_BUCKETS",
 ]
+
+#: row counts an in-place write is padded to (a larger batch goes in pieces
+#: of the last): a fixed set of shapes, so every write program of a block can
+#: be compiled when the block is placed and none compiles while serving
+WRITE_BUCKETS = (8, 64, 512)
 
 
 def merge_shard_topk(
@@ -74,6 +79,25 @@ def topk_scores(
     if valid is not None:
         scores = jnp.where(valid[None, :], scores, -jnp.inf)
     return jax.lax.top_k(scores, k)
+
+
+def index_write(block: jax.Array, valid: jax.Array, slots: jax.Array,
+                rows: jax.Array, live: jax.Array):
+    """block [n, d], valid [n] with ``rows`` [m, d] and ``live`` [m] written at
+    ``slots`` [m]. A slot may repeat as long as it repeats with the same row
+    (padding to a bucket does): the write is then the same whichever lands."""
+    return block.at[slots].set(rows), valid.at[slots].set(live)
+
+
+@functools.cache
+def index_writer(out_shardings=None):
+    """The one in-place write program of a device-resident index block
+    (``jit_index_write`` in a device trace): block and mask are donated, so
+    the update touches the written rows and no second block exists. With
+    ``out_shardings`` the result keeps the block's sharding: left to itself
+    the compiler replicates the result of an update on a TPU mesh, and every
+    chip then holds the whole index."""
+    return jax.jit(index_write, donate_argnums=(0, 1), out_shardings=out_shardings)
 
 
 def knn_search(queries: np.ndarray, index: np.ndarray, k: int, metric: str = "cos"):
@@ -167,19 +191,7 @@ class ShardedKnnIndex:
             self._data = jnp.zeros((capacity, dim), jnp.float32)
             self._valid_d = jnp.zeros((capacity,), jnp.bool_)
 
-        def write(data, valid, rows, start):
-            data = jax.lax.dynamic_update_slice(
-                data, rows, (start, jnp.zeros_like(start))
-            )
-            ones = jnp.ones((rows.shape[0],), jnp.bool_)
-            return data, jax.lax.dynamic_update_slice(valid, ones, (start,))
-
-        # the block is updated in place (donated) and keeps its sharding:
-        # left to itself the compiler replicates the result of an eager
-        # update on a TPU mesh, and every chip then holds the whole index
-        self._write = jax.jit(
-            write, donate_argnums=(0, 1), out_shardings=shardings
-        )
+        self._write = index_writer(shardings)
         self._keys: list[Any] = []
 
     def add(self, vectors: np.ndarray, keys: list[Any] | None = None) -> None:
@@ -188,7 +200,8 @@ class ShardedKnnIndex:
             raise ValueError("index capacity exceeded")
         self._data, self._valid_d = self._write(
             self._data, self._valid_d,
-            np.asarray(vectors, np.float32), np.int32(self.size),
+            np.arange(self.size, self.size + n, dtype=np.int32),
+            np.asarray(vectors, np.float32), np.ones(n, np.bool_),
         )
         self._keys.extend(keys if keys is not None else range(self.size, self.size + n))
         self.size += n

@@ -56,9 +56,15 @@ SERVE_STATS: dict[str, int] = {
     #: BruteForceKnnEngine.search calls / the queries they carried
     "index_searches_total": 0,
     "index_search_queries_total": 0,
-    #: whole-block host-to-device uploads a search made (index was dirty)
+    #: whole-block placements a search made (no device copy to write into:
+    #: the first search, a new capacity tier, a restored engine)
     "index_uploads_total": 0,
     "index_upload_bytes_total": 0,
+    #: in-place writes of staged slots into the device copy: flushes, the
+    #: distinct slots they carried, bytes of rows sent (padded slots x dim x 4)
+    "index_writes_total": 0,
+    "index_write_rows_total": 0,
+    "index_write_bytes_total": 0,
     #: rows the dataflow added to / removed from an external index
     "index_rows_added_total": 0,
     "index_rows_removed_total": 0,

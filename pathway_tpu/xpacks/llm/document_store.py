@@ -222,12 +222,14 @@ class DocumentStore:
         return joined.with_id(this.qid).select(result=this.result)
 
     def inputs_query(self, input_queries: Table) -> Table:
-        """List indexed input files (path + metadata) per query row."""
-        from ...utils.filters import compile_metadata_filter
+        """List indexed input files (path + metadata) per query row.
 
-        files = self.docs.reduce(
-            metas=pw.reducers.tuple(this._metadata),
-        ).select(__one=0, metas=this.metas)
+        A query meets the documents when it arrives: the documents' side of
+        the join takes a write as the rows it changes. (One tuple of every
+        document's metadata, kept up to date for queries to join, would be
+        built anew and hashed whole by every write: seconds a commit at a
+        million documents, with no inputs query anywhere.)"""
+        from ...utils.filters import compile_metadata_filter
 
         def list_files(metas, metadata_filter, globpattern):
             flt = DocumentStore.merge_filters(metadata_filter, globpattern)
@@ -239,18 +241,28 @@ class DocumentStore:
                     out.append({"path": m.get("path"), **m})
             return tuple(out)
 
+        docs = self.docs.select(__one=0, _metadata=this._metadata)
         tagged = input_queries.with_columns(__one=0)
-        joined = tagged.join_left(
-            files, pw.left["__one"] == pw.right["__one"]
+        # left, so that a query over an empty store is answered too; its
+        # one padded row carries no metadata and the tuple skips it
+        listed = tagged.join_left(
+            docs, pw.left["__one"] == pw.right["__one"]
         ).select(
             qid=pw.left.id,
+            metadata_filter=pw.left.metadata_filter,
+            filepath_globpattern=pw.left.filepath_globpattern,
+            _metadata=pw.right._metadata,
+        )
+        grouped = listed.groupby(this.qid).reduce(
+            qid=this.qid,
             result=apply_with_type(
                 list_files, dt.ANY,
-                pw.right.metas, pw.left.metadata_filter,
-                pw.left.filepath_globpattern,
+                pw.reducers.tuple(this._metadata, skip_nones=True),
+                pw.reducers.any(this.metadata_filter),
+                pw.reducers.any(this.filepath_globpattern),
             ),
         )
-        return joined.with_id(this.qid).select(result=this.result)
+        return grouped.with_id(this.qid).select(result=this.result)
 
 
 class SlidesDocumentStore(DocumentStore):
