@@ -270,12 +270,24 @@ class BruteForceKnnEngine:
 
         import jax.numpy as jnp
 
-        from .knn import WRITE_BUCKETS
+        from .knn import PLACE_ROWS, WRITE_BUCKETS, index_fill, storage_dtype
 
         if self._device is None or not self._staged:
-            with span("index.upload", bytes=self._host.nbytes, whole=True):
+            dtype = storage_dtype(self.metric)
+            with span("index.upload", bytes=self._host.nbytes, whole=True,
+                      dtype=dtype.name):
                 self._device = self._device_valid = None  # never two blocks
-                self._device = jnp.asarray(self._host)
+                # the block is filled chunk by chunk, each chunk of float32
+                # rows cast on the device and waited for, so that what the
+                # device holds beside the block is one chunk at a time
+                self._device = jnp.zeros((self.capacity, self.dim), dtype)
+                chunk = min(self.capacity, PLACE_ROWS)
+                for at in range(0, self.capacity, chunk):
+                    # the last chunk reaches back over rows already placed
+                    at = min(at, self.capacity - chunk)
+                    self._device = index_fill(
+                        self._device, self._host[at:at + chunk], np.int32(at)
+                    ).block_until_ready()
                 self._device_valid = jnp.asarray(self._valid)
             bump("index_uploads_total")
             bump("index_upload_bytes_total", self._host.nbytes)
@@ -293,7 +305,8 @@ class BruteForceKnnEngine:
             ]
             padded = sum(len(p) for p in pieces)
             nbytes = padded * self.dim * 4
-            with span("index.upload", bytes=nbytes, whole=False), \
+            with span("index.upload", bytes=nbytes, whole=False,
+                      dtype=self._device.dtype.name), \
                     span("index.write", rows=len(slots), padded=padded, bytes=nbytes):
                 self._write(pieces)
             bump("index_writes_total")

@@ -31,12 +31,27 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 __all__ = [
     "topk_scores", "knn_search", "ShardedKnnIndex", "sharded_knn_search",
     "merge_shard_topk", "index_write", "index_writer", "WRITE_BUCKETS",
+    "storage_dtype", "index_fill", "PLACE_ROWS",
 ]
 
 #: row counts an in-place write is padded to (a larger batch goes in pieces
 #: of the last): a fixed set of shapes, so every write program of a block can
 #: be compiled when the block is placed and none compiles while serving
 WRITE_BUCKETS = (8, 64, 512)
+
+#: rows of the host block handed to the device at a time when a block is
+#: placed (256 MiB of float32 at width 1024): what the device holds beside
+#: the block it fills is one such chunk, never a second block
+PLACE_ROWS = 1 << 16
+
+
+def storage_dtype(metric: str):
+    """The dtype a device copy of an index is kept in: the one ``topk_scores``
+    multiplies in, where nothing else reads the block. ``cos`` and ``ip`` read
+    the rows only as bfloat16 operands, so the copy is bfloat16, rounded once
+    when a row is written and not at every scan; ``l2`` also reads the
+    float32 rows for their norms, so its copy stays float32."""
+    return jnp.dtype(jnp.bfloat16 if metric in ("cos", "ip") else jnp.float32)
 
 
 def merge_shard_topk(
@@ -61,6 +76,8 @@ def topk_scores(
 ):
     """queries [q, d] (f32), index [n, d] -> (scores [q,k], ids [q,k]).
 
+    index is float32 or already ``storage_dtype(metric)``: the cast below is
+    then no operation and the scan reads the block once, at two bytes a value.
     cos: both sides assumed L2-normalized → dot product == cosine.
     l2: negative squared distance (higher is closer).
     valid [n] bool: rows where False are masked to -inf BEFORE top-k
@@ -83,10 +100,11 @@ def topk_scores(
 
 def index_write(block: jax.Array, valid: jax.Array, slots: jax.Array,
                 rows: jax.Array, live: jax.Array):
-    """block [n, d], valid [n] with ``rows`` [m, d] and ``live`` [m] written at
-    ``slots`` [m]. A slot may repeat as long as it repeats with the same row
-    (padding to a bucket does): the write is then the same whichever lands."""
-    return block.at[slots].set(rows), valid.at[slots].set(live)
+    """block [n, d], valid [n] with ``rows`` [m, d] (float32, cast here to the
+    block's dtype) and ``live`` [m] written at ``slots`` [m]. A slot may repeat
+    as long as it repeats with the same row (padding to a bucket does): the
+    write is then the same whichever lands."""
+    return block.at[slots].set(rows.astype(block.dtype)), valid.at[slots].set(live)
 
 
 @functools.cache
@@ -98,6 +116,15 @@ def index_writer(out_shardings=None):
     the compiler replicates the result of an update on a TPU mesh, and every
     chip then holds the whole index."""
     return jax.jit(index_write, donate_argnums=(0, 1), out_shardings=out_shardings)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def index_fill(block: jax.Array, rows: jax.Array, start: jax.Array):
+    """``rows`` [m, d] cast to the block's dtype and written over
+    ``block[start:start + m]``, the block donated: how a block is placed chunk
+    by chunk (``jit_index_fill`` in a device trace)."""
+    return jax.lax.dynamic_update_slice(
+        block, rows.astype(block.dtype), (start, jnp.zeros_like(start)))
 
 
 def knn_search(queries: np.ndarray, index: np.ndarray, k: int, metric: str = "cos"):
@@ -177,18 +204,19 @@ class ShardedKnnIndex:
         self.axis = axis
         self.size = 0
         shardings = None
+        dtype = storage_dtype(metric)
         if mesh is not None:
             shardings = (
                 NamedSharding(mesh, P(axis, None)), NamedSharding(mesh, P(axis))
             )
             self._data = jax.device_put(
-                jnp.zeros((capacity, dim), jnp.float32), shardings[0]
+                jnp.zeros((capacity, dim), dtype), shardings[0]
             )
             self._valid_d = jax.device_put(
                 jnp.zeros((capacity,), jnp.bool_), shardings[1]
             )
         else:
-            self._data = jnp.zeros((capacity, dim), jnp.float32)
+            self._data = jnp.zeros((capacity, dim), dtype)
             self._valid_d = jnp.zeros((capacity,), jnp.bool_)
 
         self._write = index_writer(shardings)
