@@ -528,8 +528,6 @@ def phase_index(sz: dict, log: PhaseLog, workdir: str) -> None:
 
 def phase_host(sz: dict, log: PhaseLog, workdir: str) -> None:
     import pathway_tpu as pw
-    from pathway_tpu.engine.fusion import FUSION_STATS
-    from pathway_tpu.internals import expression_compiler
     from pathway_tpu.native import native_available, native_unavailable_reason
 
     if not native_available():
@@ -557,17 +555,12 @@ def phase_host(sz: dict, log: PhaseLog, workdir: str) -> None:
     pw.run()
     if got != dict(collections.Counter(words)):
         raise RuntimeError("wordcount: counts differ from the reference")
-    xla_kernels = (
-        len(expression_compiler._JIT_KERNEL_CACHE)
-        + FUSION_STATS["jit_chains_total"]
-    )
-    log.emit(
-        "host", rows=len(words), distinct=len(got), native=True,
-        # which tier computed the host expressions (utils/jaxcfg.py: the
-        # XLA tier needs x64, which is on in a CPU-only process only)
-        expression_tier="xla" if xla_kernels else "numpy",
-        x64=bool(log.jax.config.jax_enable_x64),
-    )
+    x64 = bool(log.jax.config.jax_enable_x64)
+    log.emit("host", rows=len(words), distinct=len(got), native=True, x64=x64)
+    if x64:
+        # the chip never runs a program under x64, and nothing in the
+        # package may switch it on
+        raise RuntimeError("host: jax_enable_x64 is on")
 
 
 def phase_fourchip(sz: dict, log: PhaseLog, workdir: str) -> None:

@@ -16,14 +16,10 @@ compiler/executor boundary:
   dispatch between fused members;
 - filters inside a chain propagate a boolean mask instead of
   compacting, with one compaction at the chain exit, whenever every
-  later member kernel is total on masked-out rows (the same
-  ``jax_ok`` property the per-expression jit gates on); otherwise the
-  chain compacts in place at the filter boundary (still fused — index
-  arrays applied to live columns, no Delta round-trip);
-- chains whose every kernel is a jax-compilable expression tree
-  additionally compile to ONE ``jax.jit`` callable per chain — the
-  whole chain lands on XLA as a single computation, riding the
-  process-wide structural-signature kernel cache;
+  later member kernel is total on masked-out rows (the compiler's
+  ``Compiled.total``); otherwise the chain compacts in place at the
+  filter boundary (still fused — index arrays applied to live columns,
+  no Delta round-trip);
 - reducer preambles feeding groupby/join (the adjacent ``Rowwise``
   the lowering always materializes group keys / join keys in) are
   absorbed into the stateful node itself (``operators.GroupByReduce``
@@ -39,21 +35,20 @@ the same contract the lifted-UDF ladder established.
 Fusion is observable: per-chain ``fusion.exec`` trace spans carry the
 member operator names, per-operator attribution is re-derived from
 per-chain cost splits (measured member-by-member when detailed stats
-are on, EWMA-weighted on the single-kernel jit path) so
-``/attribution`` still names the bottleneck operator *inside* a fused
+are on) so ``/attribution`` still names the bottleneck operator *inside* a fused
 chain, and ``pathway_fusion_{chains,fused_ops,fallbacks}_total`` ship
 on /metrics, the ``fusion.*`` signals series and ``pathway-tpu top``.
 
 ``PATHWAY_FUSION=0`` is the escape hatch (default on): the graph then
-runs the per-node path unchanged — the bench records same-host A/B
-lanes through it.
+runs the per-node path unchanged, and is the reference
+``tests/test_fusion.py`` compares the fused graph against.
 """
 
 from __future__ import annotations
 
 import os
 from dataclasses import dataclass
-from typing import Any, Callable
+from typing import Callable
 
 import numpy as np
 
@@ -80,8 +75,8 @@ def fusion_enabled() -> bool:
     """The PATHWAY_FUSION escape hatch: default on, ``0`` disables the
     whole subsystem (chain fusion, preamble absorption, key reuse and
     the consolidation identity fast path) so a same-host A/B attributes
-    the speedup. Read per call — tests and the bench toggle it between
-    runs within one process."""
+    the speedup. Read per call — tests toggle it between runs within
+    one process."""
     return os.environ.get("PATHWAY_FUSION", "1") != "0"
 
 
@@ -92,7 +87,6 @@ FUSION_STATS: dict[str, int] = {
     "chains_total": 0,        # FusedChain nodes built (per executor build)
     "fused_ops_total": 0,     # member operators those chains absorbed
     "fallbacks_total": 0,     # batches replayed through the per-node path
-    "jit_chains_total": 0,    # chains that compiled to one XLA callable
     "preambles_total": 0,     # Rowwise preambles absorbed into groupby/join
     "key_reuse_total": 0,     # batches whose group/join keys reused row keys
     "consolidation_skips_total": 0,  # provably-identity consolidations skipped
@@ -309,15 +303,12 @@ class _FuseFallback(Exception):
 class FusedChain(Node):
     """One engine node executing a whole Rowwise/Filter chain.
 
-    Three execution tiers per batch, fastest first:
+    Two execution tiers per batch, fastest first:
 
-    1. one jitted XLA callable for the whole chain (pure numeric
-       expression chains, large dense batches — mirrors the
-       per-expression jit gates: threshold, warmup, x64, cpu pinning);
-    2. composed member kernels over a live column dict — no
+    1. composed member kernels over a live column dict — no
        intermediate Delta, masks deferred across total members, one
        compaction at exit;
-    3. the exact per-node path (``member.process`` in sequence) for any
+    2. the exact per-node path (``member.process`` in sequence) for any
        batch that raises or routes Errors through a deferred mask —
        row-error semantics are identical to the unfused graph.
     """
@@ -336,24 +327,18 @@ class FusedChain(Node):
         #: EngineStats.note_node keys emitted-row counts by these, so
         #: the rows and time series share labels inside a fused chain
         self.attribution_labels = tuple(self._labels)
-        #: EWMA per-member cost weights (ns) — the jit path reports one
-        #: fused kernel time; attribution splits it by these
-        self._weights = np.ones(len(members), dtype=np.float64)
         self._member_kind = [
             "filter" if isinstance(m, ops.Filter) else "rowwise"
             for m in members
         ]
         # mask deferral: after member i produced a mask, it may stay
         # deferred only while every LATER kernel is total on masked-out
-        # rows (jax_ok expression kernels: dense numeric, no division,
+        # rows (total expression kernels: dense numeric, no division,
         # no error carriers) — otherwise compact right at the filter
         total_after = [True] * (len(members) + 1)
         for i in range(len(members) - 1, -1, -1):
             total_after[i] = total_after[i + 1] and self._member_total(members[i])
         self._defer_after = total_after[1:]
-        self._jit = None  # lazily-built whole-chain kernel wrapper
-        self._jit_state: dict[str, Any] = {"hot": 0, "broken": False}
-        self._jit_plan = self._build_jit_plan()
 
     # -- planning helpers ------------------------------------------------
 
@@ -367,62 +352,13 @@ class FusedChain(Node):
 
     @staticmethod
     def _member_total(m: Node) -> bool:
-        """Every kernel of ``m`` is a jax-compilable expression — total
-        on any row, so evaluating masked-out rows cannot raise, produce
-        Error carriers, or touch the error log."""
+        """Every kernel of ``m`` is total on any row, so evaluating
+        masked-out rows cannot raise, produce Error carriers, or touch
+        the error log."""
         for fn in FusedChain._member_kernels(m).values():
-            if not getattr(fn, "_pw_jax_ok", False):
+            if not getattr(fn, "_pw_total", False):
                 return False
         return True
-
-    def _build_jit_plan(self):
-        """(member spec, source cols, composite signature) when the whole
-        chain can land on XLA as one computation, else None."""
-        from ..internals import expression_compiler as ec
-
-        spec: list[tuple[str, dict]] = []
-        sigs: list = []
-        src_cols: set[str] = set()
-        produced: set[str] | None = None  # None until a rowwise ran
-        for m, kind in zip(self.members, self._member_kind):
-            kernels = self._member_kernels(m)
-            entry: dict[str, tuple] = {}
-            for name, fn in kernels.items():
-                expr = getattr(fn, "_pw_expr", None)
-                env = getattr(fn, "_pw_env", None)
-                if (
-                    expr is None or env is None
-                    or not getattr(fn, "_pw_jax_ok", False)
-                ):
-                    return None
-                sig = ec._structural_sig(expr, env)
-                if sig is None:
-                    return None
-                entry[name] = (expr, env)
-                sigs.append((kind, name, sig))
-                _, _, _, refs = ec._build(expr, env)
-                src_cols.update(
-                    c
-                    for c in refs
-                    if c is not None
-                    and (produced is None or c not in produced)
-                )
-            if kind == "rowwise":
-                produced = set(kernels.keys())
-            spec.append((kind, entry))
-        # output producibility: after the LAST rowwise the live dict holds
-        # exactly its outputs; a filter-only chain passes the input dict
-        # through, so its output columns must ride in as source columns
-        if produced is None:
-            src_cols.update(self.column_names)
-        elif not set(self.column_names) <= produced:
-            return None
-        return {
-            "spec": spec,
-            "src_cols": sorted(src_cols),
-            "sig": ("chain", *sigs),
-            "member_sigs": [s[2] for s in sigs],
-        }
 
     # -- execution -------------------------------------------------------
 
@@ -510,14 +446,6 @@ class FusedChain(Node):
         from .error import ERROR_LOG, Error as EngineError
         from .operators import _as_column
 
-        jit_out = self._try_jit(d)
-        if jit_out is not None:
-            cols, mask, total_ns = jit_out
-            keys, diffs = d.keys, d.diffs
-            if stats is not None:
-                self._attribute_by_weight(stats, total_ns)
-            return self._exit(keys, cols, diffs, mask)
-
         cols: dict[str, np.ndarray] = d.data
         keys, diffs = d.keys, d.diffs
         mask: np.ndarray | None = None
@@ -546,7 +474,7 @@ class FusedChain(Node):
                     # re-create (and re-log) the per-row errors. A
                     # pending deferred mask cannot coexist with an
                     # object mask (deferral requires every later kernel
-                    # jax_ok-total over dense columns), asserted below.
+                    # total over dense columns), asserted below.
                     if mask is not None:
                         raise _FuseFallback
                     out = np.empty(len(mv), dtype=bool)
@@ -609,123 +537,12 @@ class FusedChain(Node):
         out = Delta(keys=keys, data=dict(cols), diffs=diffs)
         return out if len(out) else None
 
-    # -- whole-chain XLA tier -------------------------------------------
-
-    def _try_jit(self, d: Delta):
-        """Run the whole chain as one XLA computation when the plan,
-        warmup gate and batch dtypes allow; None → use the composed
-        numpy tier. Mirrors the per-expression jit gates in
-        internals/expression_compiler (threshold, warmup, broken-jax
-        short-circuit, x64 requirement, host-CPU pinning)."""
-        from ..internals import expression_compiler as ec
-
-        plan = self._jit_plan
-        st = self._jit_state
-        if plan is None or st["broken"]:
-            return None
-        n = len(d)
-        if n < ec.JIT_THRESHOLD:
-            return None
-        for c in plan["src_cols"]:
-            a = d.data.get(c)
-            if a is None or getattr(a, "dtype", None) == object:
-                return None
-        st["hot"] += 1
-        if st["hot"] <= ec.JIT_WARMUP_BATCHES:
-            return None
-        import time as _wall
-
-        t0 = _wall.perf_counter_ns()
-        try:
-            from ..utils import jaxcfg
-
-            import jax
-        except Exception:
-            st["broken"] = True
-            return None
-        if not jaxcfg.enable_x64_on_cpu():
-            return None
-        dev = ec._engine_device()
-        if dev is None:
-            return None
-        if self._jit is None:
-            self._jit = ec.fused_chain_kernel(
-                plan["sig"], plan["member_sigs"], self._make_traceable(plan)
-            )
-            FUSION_STATS["jit_chains_total"] += 1
-        try:
-            src = {c: d.data[c] for c in plan["src_cols"]}
-            with jax.default_device(dev):
-                outs = self._jit(src, d.keys)
-        except Exception:
-            # shape/dtype combination XLA refuses — numpy tier owns it.
-            # Repeated refusals mean the chain will never trace: stop
-            # paying a failed re-trace on every large batch.
-            st["jit_failures"] = st.get("jit_failures", 0) + 1
-            if st["jit_failures"] >= 3:
-                st["broken"] = True
-            return None
-        *col_vals, mask = outs
-        cols = {
-            name: np.asarray(v)
-            for name, v in zip(self.column_names, col_vals)
-        }
-        mask_np = None if mask is None else np.asarray(mask)
-        return cols, mask_np, _wall.perf_counter_ns() - t0
-
-    def _make_traceable(self, plan):
-        """The function jax traces: every member kernel rebuilt with
-        jax.numpy, composed over a live column dict, masks ANDed —
-        returns (out columns..., mask|None)."""
-        from ..internals import expression_compiler as ec
-
-        spec = plan["spec"]
-        out_cols = list(self.column_names)
-
-        def build():
-            compiled = []
-            for kind, entry in spec:
-                compiled.append((kind, {
-                    name: ec._build(expr, env, "jax")[0]
-                    for name, (expr, env) in entry.items()
-                }))
-
-            def traced(cols, keys):
-                live = dict(cols)
-                mask = None
-                for kind, kernels in compiled:
-                    if kind == "rowwise":
-                        live = {
-                            name: fn(live, keys)
-                            for name, fn in kernels.items()
-                        }
-                    else:
-                        mv = kernels["__pred__"](live, keys)
-                        mask = mv if mask is None else mask & mv
-                return tuple(live[c] for c in out_cols) + (mask,)
-
-            return traced
-
-        return build
-
     # -- attribution + tracing ------------------------------------------
 
     def _note_members(self, stats, member_ns) -> None:
-        total = float(member_ns.sum())
-        if total > 0:
-            # EWMA cost split: the jit path re-uses it
-            self._weights = 0.8 * self._weights + 0.2 * member_ns
         for label, ns in zip(self._labels, member_ns):
             if ns > 0:
                 stats.note_op_time(label, int(ns))
-
-    def _attribute_by_weight(self, stats, total_ns: int) -> None:
-        w = self._weights
-        tot = float(w.sum()) or 1.0
-        for label, wi in zip(self._labels, w):
-            share = int(total_ns * (wi / tot))
-            if share > 0:
-                stats.note_op_time(label, share)
 
     def __repr__(self) -> str:
         inner = "→".join(self._labels)

@@ -16,9 +16,9 @@ Reference being replaced: the timely ``zero_copy`` allocator
 (``external/timely-dataflow/communication/src/allocator/zero_copy/``) +
 shard-by-key-low-bits routing (``src/engine/value.rs:38,75``).
 
-Packing uses uint32 *pairs* per 8-byte value rather than uint64 because TPU
-jax runs without x64 (``utils/jaxcfg.py``) — uint64 device arrays would be
-silently narrowed there; 2×uint32 words are exact on every platform.
+Packing uses uint32 *pairs* per 8-byte value rather than uint64 because jax
+runs without x64 here — uint64 device arrays would be silently narrowed;
+2×uint32 words are exact on every platform.
 
 Protocol cost (r4 redesign): ONE driver-side pack of the whole tick into a
 pinned staging buffer, ONE sharded ``device_put``, one jitted collective

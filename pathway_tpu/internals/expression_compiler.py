@@ -4,16 +4,14 @@ This replaces two reference components at once:
 - the static type interpreter (``python/pathway/internals/type_interpreter.py``)
 - the row-at-a-time typed Rust interpreter (``src/engine/expression.rs:325``)
 
-An expression DAG compiles to ONE function over column arrays. Pure-numeric
-trees additionally compile to a fused ``jax.jit`` kernel that is used for
-large batches, so on TPU the whole expression lands on the VPU/MXU as a
-single XLA computation (cf. SURVEY §7: "jit whole expression DAGs into one
-XLA kernel per operator per batch").
+An expression DAG compiles to ONE function over column arrays: a numpy
+kernel, at every batch size and on every host. The dataflow runs on the
+host by design; the accelerator is for the dense kernels (knn, embedder)
+that amortize a transfer, and nothing here imports jax.
 """
 
 from __future__ import annotations
 
-import logging
 import os
 from dataclasses import dataclass
 from typing import Any, Callable
@@ -51,11 +49,6 @@ from .expression import (
     UnwrapExpression,
 )
 
-_log = logging.getLogger(__name__)
-
-JIT_THRESHOLD = int(os.environ.get("PATHWAY_TPU_JIT_THRESHOLD", "4096"))
-JIT_WARMUP_BATCHES = int(os.environ.get("PATHWAY_TPU_JIT_WARMUP_BATCHES", "16"))
-
 _NUMERIC = {dt.INT, dt.FLOAT, dt.BOOL}
 
 
@@ -84,8 +77,8 @@ class ColumnEnv:
 
     def signature(self) -> frozenset:
         """Identity of the binding environment — compile results are valid
-        for any env with the same bindings (used to reuse jitted kernels
-        across pw.iterate rounds instead of re-tracing every round)."""
+        for any env with the same bindings (used to reuse compiled kernels
+        across pw.iterate rounds instead of rebuilding them every round)."""
         return frozenset(
             (k, v[0], str(v[1])) for k, v in self._map.items()
         )
@@ -95,19 +88,18 @@ class ColumnEnv:
 class Compiled:
     fn: Callable[[dict[str, np.ndarray], np.ndarray], np.ndarray]
     dtype: dt.DType
-    #: the whole tree is jax-compilable (dense numeric, total ops) —
-    #: the chain-fusion pass (engine/fusion.py) uses this both as the
-    #: whole-chain XLA gate and as the mask-deferral proof (a total
-    #: kernel evaluated on masked-out rows cannot raise, build Error
+    #: the whole tree is dense numeric with total ops — the chain-fusion
+    #: pass (engine/fusion.py) uses this as the mask-deferral proof (a
+    #: total kernel evaluated on masked-out rows cannot raise, build Error
     #: carriers, or touch the error log)
-    jax_ok: bool = False
+    total: bool = False
 
 
 def infer_dtype(expr: ColumnExpression, env: ColumnEnv) -> dt.DType:
     """Static dtype of an expression (reference: type_interpreter.py)."""
     if isinstance(expr, ReducerExpression):
         return _reducer_dtype(expr, env)
-    _, dtype, _, _ = _build(expr, env)
+    _, dtype, _ = _build(expr, env)
     return dtype
 
 
@@ -132,7 +124,7 @@ def _reducer_dtype(expr: ReducerExpression, env: ColumnEnv) -> dt.DType:
 def compile_expr(expr: ColumnExpression, env: ColumnEnv) -> Compiled:
     # memoize per (expression, bindings): pw.iterate re-lowers the same
     # captured subgraph every fixpoint round — without this each round
-    # would rebuild closures and re-trace XLA kernels from scratch
+    # would rebuild every kernel closure from scratch
     cache: dict | None = getattr(expr, "_compiled_cache", None)
     if cache is None:
         cache = {}
@@ -143,7 +135,7 @@ def compile_expr(expr: ColumnExpression, env: ColumnEnv) -> Compiled:
     sig = env.signature() if cache is not None else None
     if cache is not None and sig in cache:
         return cache[sig]
-    result = _compile_expr_uncached(expr, env)
+    result = Compiled(*_build(expr, env))
     try:
         # static-analysis breadcrumbs (pathway_tpu/analysis): the lowered
         # engine nodes hold only compiled kernels — tagging each kernel
@@ -151,11 +143,9 @@ def compile_expr(expr: ColumnExpression, env: ColumnEnv) -> Compiled:
         # walk the compiled graph without re-deriving the compile
         result.fn._pw_expr = expr
         result.fn._pw_dtype = result.dtype
-        # chain-fusion breadcrumbs (engine/fusion.py): the fused-chain
-        # compiler rebuilds member kernels with jax.numpy inside ONE
-        # traced function, which needs the binding environment back
-        result.fn._pw_env = env
-        result.fn._pw_jax_ok = result.jax_ok
+        # chain-fusion breadcrumb (engine/fusion.py): a filter's mask
+        # stays deferred across kernels that are total
+        result.fn._pw_total = result.total
         if isinstance(expr, ColumnReference) and not isinstance(
             expr, IdReference
         ):
@@ -173,267 +163,6 @@ def compile_expr(expr: ColumnExpression, env: ColumnEnv) -> Compiled:
     if cache is not None:
         cache[sig] = result
     return result
-
-
-def _compile_expr_uncached(expr: ColumnExpression, env: ColumnEnv) -> Compiled:
-    np_fn, dtype, jax_ok, refs = _build(expr, env)
-    if jax_ok and _jax_available():
-        jitted_box: list = []
-        ref_cols = [c for c in refs if c is not None]
-
-        hot = [0]  # large batches seen; compile only once it pays off
-        jax_broken = [False]  # this fn's own short-circuit: a failed import
-        # must not be retried per batch (each retry re-runs the whole
-        # multi-second failing import inside the hot loop)
-
-        def fn(cols: dict[str, np.ndarray], keys: np.ndarray) -> np.ndarray:
-            n = len(keys)
-            if (
-                not jax_broken[0]
-                and n >= JIT_THRESHOLD
-                # something to compute on: a constant stays the row value
-                # the numpy kernel returns, not a 0-d array off the device
-                and refs
-                and all(cols[c].dtype != object for c in ref_cols)
-            ):
-                # warm-up gate: XLA compilation (~100ms) only pays for
-                # expressions that keep seeing large batches (long-running
-                # streams); short batch jobs stay on the numpy kernels.
-                # jax itself imports only past the gate: without bytecode
-                # caches (PYTHONDONTWRITEBYTECODE) the import costs ~2.5s
-                # per process, which must not land on spawned host workers
-                # that never reach the jit path.
-                hot[0] += 1
-                if hot[0] <= JIT_WARMUP_BATCHES:
-                    return np_fn(cols, keys)
-                try:
-                    from ..utils import jaxcfg
-
-                    import jax
-                except Exception:
-                    # present-but-broken jax (e.g. jaxlib mismatch): the
-                    # numpy kernels compute the same values, so a running
-                    # stream keeps them for good instead of crashing
-                    _log.warning(
-                        "jax failed to import; host expressions stay on "
-                        "the numpy kernels", exc_info=True,
-                    )
-                    _jax_checked[:] = [False]
-                    jax_broken[0] = True
-                    return np_fn(cols, keys)
-
-                # x64 gate: without it the traced kernel silently truncates
-                # INT/FLOAT columns to 32 bits — wrong values, and 32-bit
-                # outputs knock every downstream key hash off the fast path.
-                # x64 is on only in a CPU-only process (utils/jaxcfg.py), so
-                # on an accelerator host this tier is the numpy kernels.
-                if not jaxcfg.enable_x64_on_cpu():
-                    return np_fn(cols, keys)
-                # pin to the host CPU backend: streaming tick batches are
-                # latency-bound host work; shipping them to an accelerator
-                # per tick costs more than the fused kernel saves. The TPU
-                # is for the dense kernels (knn, embedder, window
-                # aggregation) that amortize the transfer.
-                # Override with PATHWAY_TPU_EXPR_BACKEND=tpu.
-                dev = _engine_device()
-                if dev is None:
-                    return np_fn(cols, keys)
-                if not jitted_box:
-                    jitted_box.append(_jitted_kernel(expr, env))
-                # the kernel gets the columns it reads, which the gate above
-                # checked, and not whatever else the delta carries (an
-                # object column beside them is no array to trace)
-                with jax.default_device(dev):
-                    return np.asarray(
-                        jitted_box[0]({c: cols[c] for c in ref_cols}, keys))
-            return np_fn(cols, keys)
-
-        return Compiled(fn, dtype, jax_ok=True)
-    return Compiled(np_fn, dtype, jax_ok=jax_ok)
-
-
-_engine_dev_cache: list = []
-
-
-def _engine_device():
-    """The device host expression kernels are pinned to, or None when the
-    backend ``PATHWAY_TPU_EXPR_BACKEND`` names (default ``cpu``) is not
-    there — e.g. under ``JAX_PLATFORMS=tpu``. None means the numpy kernels
-    run: a kernel never lands on a device nobody named."""
-    if not _engine_dev_cache:
-        import jax
-
-        backend = os.environ.get("PATHWAY_TPU_EXPR_BACKEND", "cpu")
-        try:
-            _engine_dev_cache.append(jax.local_devices(backend=backend)[0])
-        except RuntimeError as e:
-            _log.warning(
-                "jax backend %r is unavailable (%s); host expressions stay "
-                "on the numpy kernels", backend, e,
-            )
-            _engine_dev_cache.append(None)
-    return _engine_dev_cache[0]
-
-
-_jax_checked: list[bool] = []
-
-
-def _jax_available() -> bool:
-    # spec lookup only — importing jax (via utils.jaxcfg) here would charge
-    # every worker process ~2.5s at expression-compile time even when the
-    # jit path is never taken
-    if not _jax_checked:
-        import importlib.util
-
-        try:
-            _jax_checked.append(importlib.util.find_spec("jax") is not None)
-        except Exception:
-            _jax_checked.append(False)
-    return _jax_checked[0]
-
-
-def _make_jitted(expr: ColumnExpression, env: ColumnEnv):
-    import jax
-
-    def traced(cols, keys):
-        import jax.numpy as jnp
-
-        fn, _, _, _ = _build(expr, env, xp_name="jax")
-        return fn(cols, keys)
-
-    return jax.jit(traced)
-
-
-#: process-wide jitted-kernel memo: structural signature -> jit wrapper.
-#: A pipeline REBUILT over fresh table objects (every bench run, every
-#: pw.iterate round, a redeployed streaming service) used to re-trace and
-#: re-compile every XLA kernel from scratch — ~100 ms per expression,
-#: paid inside the tick loop right when the warmup gate opens. Two
-#: expressions with equal structural signatures (same tree shape, ops,
-#: scalar constants, and identically-resolved engine columns + dtypes)
-#: compile to interchangeable kernels, and jax.jit re-traces per
-#: input shape/dtype anyway — so sharing the wrapper is sound.
-#: Tradeoff: each cached wrapper closes over its first (expr, env), so a
-#: retired pipeline's expression tree + table objects stay pinned while
-#: the entry lives — bounded by the cache cap (oldest half evicted at
-#: the cap), and the pin IS the value: the next structurally-equal pipeline
-#: reuses the compiled kernel instead of re-tracing XLA mid-stream.
-_JIT_KERNEL_CACHE: dict = {}
-_JIT_KERNEL_CACHE_MAX = 256
-
-
-def _structural_sig(expr: ColumnExpression, env: ColumnEnv) -> tuple | None:
-    """Identity-free signature of a jax-compilable expression tree, or
-    None when the tree holds anything we cannot sign exactly (non-scalar
-    constants, apply lambdas, method calls...) — those keep a private
-    per-instance jit wrapper instead of risking a wrong cache hit."""
-    t = type(expr)
-    if isinstance(expr, expr_mod.SelfKeysExpression):
-        return ("keys",)
-    if isinstance(expr, expr_mod.HiddenRef):
-        return ("href", expr._engine_name, str(expr._dtype))
-    if isinstance(expr, (IdReference, ColumnReference)):
-        try:
-            engine_col, dtype = env.resolve(expr)
-        except KeyError:
-            return None
-        return ("ref", t.__name__, engine_col, str(dtype))
-    if t is ColumnConstExpression:
-        v = expr._value
-        if v is None or type(v) in (bool, int, float, str):
-            return ("const", type(v).__name__, v)
-        return None
-    if t is ColumnBinaryOpExpression:
-        l = _structural_sig(expr._left, env)
-        r = _structural_sig(expr._right, env)
-        return None if l is None or r is None else ("bin", expr._op, l, r)
-    if t is ColumnUnaryOpExpression:
-        s = _structural_sig(expr._expr, env)
-        return None if s is None else ("un", expr._op, s)
-    if t is IfElseExpression:
-        parts = [
-            _structural_sig(e, env)
-            for e in (expr._if, expr._then, expr._else)
-        ]
-        return None if any(p is None for p in parts) else ("if", *parts)
-    if t in (CastExpression, DeclareTypeExpression):
-        s = _structural_sig(expr._expr, env)
-        if s is None:
-            return None
-        return ("cast", t.__name__, str(expr._return_type), s)
-    if t is CoalesceExpression:
-        parts = [_structural_sig(e, env) for e in expr._args]
-        return None if any(p is None for p in parts) else ("coal", *parts)
-    if t in (UnwrapExpression,):
-        s = _structural_sig(expr._expr, env)
-        return None if s is None else ("unwrap", s)
-    if t is FillErrorExpression:
-        s = _structural_sig(expr._expr, env)
-        r = _structural_sig(expr._replacement, env)
-        return None if s is None or r is None else ("fillerr", s, r)
-    return None
-
-
-#: fused-chain cache entries ("chain", ...) -> frozenset of the member
-#: expression signatures they were compiled from. A fused kernel is only
-#: as alive as its members: the eviction sweep drops any chain entry
-#: whose member signature it just evicted, so a rebuilt pipeline can
-#: never pair a fresh member kernel with a stale fused composite.
-_JIT_CHAIN_DEPS: dict = {}
-
-
-def _evict_jit_cache() -> None:
-    """Oldest-half eviction of the jit kernel cache, with fused-chain
-    entries evicting as a unit with their member-node signatures."""
-    from .udf_lift import evict_oldest_half
-
-    before = set(_JIT_KERNEL_CACHE)
-    evict_oldest_half(_JIT_KERNEL_CACHE)
-    evicted = before - set(_JIT_KERNEL_CACHE)
-    if evicted:
-        for sig in [
-            s
-            for s in _JIT_KERNEL_CACHE
-            if isinstance(s, tuple) and s and s[0] == "chain"
-        ]:
-            if _JIT_CHAIN_DEPS.get(sig, frozenset()) & evicted:
-                del _JIT_KERNEL_CACHE[sig]
-    for sig in list(_JIT_CHAIN_DEPS):
-        if sig not in _JIT_KERNEL_CACHE:
-            del _JIT_CHAIN_DEPS[sig]
-
-
-def _jitted_kernel(expr: ColumnExpression, env: ColumnEnv):
-    sig = _structural_sig(expr, env)
-    if sig is None:
-        return _make_jitted(expr, env)
-    hit = _JIT_KERNEL_CACHE.get(sig)
-    if hit is None:
-        hit = _make_jitted(expr, env)
-        if len(_JIT_KERNEL_CACHE) >= _JIT_KERNEL_CACHE_MAX:
-            # oldest-half eviction, not clear(): a wholesale clear makes
-            # every live pipeline re-trace its XLA kernels at once
-            _evict_jit_cache()
-        _JIT_KERNEL_CACHE[sig] = hit
-    return hit
-
-
-def fused_chain_kernel(chain_sig: tuple, member_sigs: list, build: Callable):
-    """Whole-chain jit wrapper for engine/fusion.py: one ``jax.jit``
-    callable per structurally-distinct chain, shared process-wide on the
-    same cache the per-expression kernels ride (rebuilt pipelines reuse
-    compiled chains instead of re-tracing XLA mid-stream). ``build()``
-    returns the traceable composed function."""
-    hit = _JIT_KERNEL_CACHE.get(chain_sig)
-    if hit is None:
-        import jax
-
-        hit = jax.jit(build())
-        if len(_JIT_KERNEL_CACHE) >= _JIT_KERNEL_CACHE_MAX:
-            _evict_jit_cache()
-        _JIT_KERNEL_CACHE[chain_sig] = hit
-        _JIT_CHAIN_DEPS[chain_sig] = frozenset(member_sigs)
-    return hit
 
 
 # ---------------------------------------------------------------------------
@@ -506,58 +235,48 @@ def binop_dtype(op: str, l: dt.DType, r: dt.DType) -> dt.DType:
 
 
 # ---------------------------------------------------------------------------
-# build: returns (fn, dtype, jax_ok, referenced engine cols)
+# build: returns (fn, dtype, total)
 # ---------------------------------------------------------------------------
 
 
 def _build(
-    expr: ColumnExpression, env: ColumnEnv, xp_name: str = "numpy"
-) -> tuple[Callable, dt.DType, bool, set]:
-    if xp_name == "jax":
-        import jax.numpy as xp
-    else:
-        xp = np
-
+    expr: ColumnExpression, env: ColumnEnv
+) -> tuple[Callable, dt.DType, bool]:
     if isinstance(expr, expr_mod.SelfKeysExpression):
-        return (lambda cols, keys: keys), dt.POINTER, True, set()
+        return (lambda cols, keys: keys), dt.POINTER, True
 
     if isinstance(expr, expr_mod.HiddenRef):
         name = expr._engine_name
         dtype = expr._dtype if expr._dtype is not None else dt.ANY
         numericable = dt.unoptionalize(dtype) in _NUMERIC
-        return (lambda cols, keys: cols[name]), dtype, numericable, {name}
+        return (lambda cols, keys: cols[name]), dtype, numericable
 
     if isinstance(expr, IdReference):
         engine_col, dtype = env.resolve(expr)
         if engine_col is None:
-            return (lambda cols, keys: keys), dt.POINTER, True, {None}
-        return (lambda cols, keys: cols[engine_col]), dtype, True, {engine_col}
+            return (lambda cols, keys: keys), dt.POINTER, True
+        return (lambda cols, keys: cols[engine_col]), dtype, True
 
     if isinstance(expr, ColumnReference):
         engine_col, dtype = env.resolve(expr)
         if engine_col is None:
-            return (lambda cols, keys: keys), dt.POINTER, True, {None}
+            return (lambda cols, keys: keys), dt.POINTER, True
         numericable = dt.unoptionalize(dtype) in _NUMERIC or dtype == dt.POINTER
-        return (
-            (lambda cols, keys: cols[engine_col]),
-            dtype,
-            numericable,
-            {engine_col},
-        )
+        return (lambda cols, keys: cols[engine_col]), dtype, numericable
 
     if isinstance(expr, ColumnConstExpression):
         v = expr._value
         dtype = dt.dtype_of_value(v)
         numericable = dtype in _NUMERIC
-        return (lambda cols, keys: v), dtype, numericable, set()
+        return (lambda cols, keys: v), dtype, numericable
 
     if isinstance(expr, ColumnBinaryOpExpression):
-        lf, ldt, lok, lrefs = _build(expr._left, env, xp_name)
-        rf, rdt, rok, rrefs = _build(expr._right, env, xp_name)
+        lf, ldt, lok = _build(expr._left, env)
+        rf, rdt, rok = _build(expr._right, env)
         op = expr._op
         out_dt = binop_dtype(op, ldt, rdt)
-        fn = _binop_fn(op, lf, rf, ldt, rdt, xp)
-        jax_ok = (
+        fn = _binop_fn(op, lf, rf, ldt, rdt)
+        total = (
             lok
             and rok
             and dt.unoptionalize(out_dt) in _NUMERIC
@@ -565,31 +284,31 @@ def _build(
             and not rdt.is_optional
             and dt.unoptionalize(ldt) in _NUMERIC
             and dt.unoptionalize(rdt) in _NUMERIC
-            # divisions stay on the numpy path: zero denominators must
-            # become per-row Error values, which a jitted kernel can't hold
+            # divisions are not total: zero denominators become per-row
+            # Error values
             and op not in ("/", "//", "%")
         )
-        return fn, out_dt, jax_ok, lrefs | rrefs
+        return fn, out_dt, total
 
     if isinstance(expr, ColumnUnaryOpExpression):
-        f, d, ok, refs = _build(expr._expr, env, xp_name)
+        f, d, ok = _build(expr._expr, env)
         op = expr._op
         if op == "-":
-            return (lambda cols, keys: -f(cols, keys)), d, ok, refs
+            return (lambda cols, keys: -f(cols, keys)), d, ok
         if op == "~":
             out_dt = d
             def notfn(cols, keys, f=f):
                 v = f(cols, keys)
                 if isinstance(v, np.ndarray) and v.dtype == object:
                     return np.array([None if x is None else not x for x in v], dtype=object)
-                return xp.logical_not(v) if dt.unoptionalize(d) == dt.BOOL else ~v
-            return notfn, out_dt, ok and dt.unoptionalize(d) in _NUMERIC, refs
+                return np.logical_not(v) if dt.unoptionalize(d) == dt.BOOL else ~v
+            return notfn, out_dt, ok and dt.unoptionalize(d) in _NUMERIC
         if op == "abs":
-            return (lambda cols, keys: xp.abs(f(cols, keys))), d, ok, refs
+            return (lambda cols, keys: np.abs(f(cols, keys))), d, ok
         raise NotImplementedError(f"unary op {op}")
 
     if isinstance(expr, IsNoneExpression):
-        f, d, ok, refs = _build(expr._expr, env, xp_name)
+        f, d, ok = _build(expr._expr, env)
         negate = isinstance(expr, IsNotNoneExpression)
 
         def fn(cols, keys, f=f, negate=negate):
@@ -602,12 +321,12 @@ def _build(
                 out = np.zeros(len(keys), dtype=bool) if v is not None else np.ones(len(keys), dtype=bool)
             return ~out if negate else out
 
-        return fn, dt.BOOL, False, refs
+        return fn, dt.BOOL, False
 
     if isinstance(expr, IfElseExpression):
-        cf, cd, cok, crefs = _build(expr._if, env, xp_name)
-        tf, td, tok, trefs = _build(expr._then, env, xp_name)
-        ef, ed, eok, erefs = _build(expr._else, env, xp_name)
+        cf, cd, cok = _build(expr._if, env)
+        tf, td, tok = _build(expr._then, env)
+        ef, ed, eok = _build(expr._else, env)
         out_dt = dt.types_lca(td, ed)
 
         def fn(cols, keys):
@@ -615,14 +334,14 @@ def _build(
             tv, ev = tf(cols, keys), ef(cols, keys)
             if isinstance(cond, np.ndarray) and cond.dtype == object:
                 cond = np.array([bool(x) for x in cond], dtype=bool)
-            out = xp.where(cond, tv, ev)
+            out = np.where(cond, tv, ev)
             return out
 
-        jax_ok = cok and tok and eok and dt.unoptionalize(out_dt) in _NUMERIC
-        return fn, out_dt, jax_ok, crefs | trefs | erefs
+        total = cok and tok and eok and dt.unoptionalize(out_dt) in _NUMERIC
+        return fn, out_dt, total
 
     if isinstance(expr, CoalesceExpression):
-        parts = [_build(a, env, xp_name) for a in expr._args]
+        parts = [_build(a, env) for a in expr._args]
         out_dt = dt.types_lca_many([p[1] for p in parts])
         non_none = [p[1] for p in parts if p[1] != dt.NONE]
         if non_none and any(not p[1].is_optional and p[1] != dt.NONE for p in parts):
@@ -631,7 +350,7 @@ def _build(
         def fn(cols, keys):
             n = len(keys)
             result = _materialize(parts[0][0](cols, keys), n)
-            for f, _, _, _ in parts[1:]:
+            for f, _, _ in parts[1:]:
                 mask = np.fromiter((x is None for x in result), dtype=bool, count=n)
                 if not mask.any():
                     break
@@ -639,18 +358,17 @@ def _build(
                 result = np.where(mask, nxt, result)
             return _densify(result, out_dt)
 
-        refs = set().union(*[p[3] for p in parts])
-        return fn, out_dt, False, refs
+        return fn, out_dt, False
 
     if isinstance(expr, RequireExpression):
-        f, d, ok, refs = _build(expr._expr, env, xp_name)
-        conds = [_build(a, env, xp_name) for a in expr._args]
+        f, d, ok = _build(expr._expr, env)
+        conds = [_build(a, env) for a in expr._args]
 
         def fn(cols, keys):
             n = len(keys)
             result = _materialize(f(cols, keys), n)
             mask = np.zeros(n, dtype=bool)
-            for cfn, _, _, _ in conds:
+            for cfn, _, _ in conds:
                 v = _materialize(cfn(cols, keys), n)
                 mask |= np.fromiter((x is None for x in v), dtype=bool, count=n)
             if mask.any():
@@ -658,11 +376,10 @@ def _build(
                 result[mask] = None
             return result
 
-        all_refs = refs.union(*[c[3] for c in conds]) if conds else refs
-        return fn, dt.Optional(d), False, all_refs
+        return fn, dt.Optional(d), False
 
     if isinstance(expr, UnwrapExpression):
-        f, d, ok, refs = _build(expr._expr, env, xp_name)
+        f, d, ok = _build(expr._expr, env)
 
         def fn(cols, keys):
             v = _materialize(f(cols, keys), len(keys))
@@ -677,11 +394,11 @@ def _build(
                 return _densify(v, dt.unoptionalize(d))
             return v
 
-        return fn, dt.unoptionalize(d), False, refs
+        return fn, dt.unoptionalize(d), False
 
     if isinstance(expr, FillErrorExpression):
-        f, d, ok, refs = _build(expr._expr, env, xp_name)
-        rf, rd, rok, rrefs = _build(expr._replacement, env, xp_name)
+        f, d, ok = _build(expr._expr, env)
+        rf, rd, rok = _build(expr._replacement, env)
 
         def fn(cols, keys):
             n = len(keys)
@@ -714,30 +431,30 @@ def _build(
                 return _densify(v, dt.types_lca(d, rd))
             return v
 
-        return fn, dt.types_lca(d, rd), False, refs | rrefs
+        return fn, dt.types_lca(d, rd), False
 
     if isinstance(expr, (CastExpression, ConvertExpression)):
-        f, d, ok, refs = _build(expr._expr, env, xp_name)
+        f, d, ok = _build(expr._expr, env)
         target = expr._return_type
         tu = dt.unoptionalize(target)
-        fn = _cast_fn(f, d, target, xp)
-        jax_ok = (
+        fn = _cast_fn(f, d, target)
+        total = (
             ok
             and dt.unoptionalize(d) in _NUMERIC
             and tu in _NUMERIC
             and not d.is_optional
         )
-        return fn, target, jax_ok, refs
+        return fn, target, total
 
     if isinstance(expr, DeclareTypeExpression):
-        f, d, ok, refs = _build(expr._expr, env, xp_name)
+        f, d, ok = _build(expr._expr, env)
         target = expr._return_type
-        return f, target, ok and dt.unoptionalize(target) in _NUMERIC, refs
+        return f, target, ok and dt.unoptionalize(target) in _NUMERIC
 
     if isinstance(expr, PointerExpression):
-        parts = [_build(a, env, xp_name) for a in expr._args]
+        parts = [_build(a, env) for a in expr._args]
         if expr._instance is not None:
-            parts.append(_build(expr._instance, env, xp_name))
+            parts.append(_build(expr._instance, env))
         optional = getattr(expr, "_optional", False)
 
         def fn(cols, keys):
@@ -761,12 +478,11 @@ def _build(
                     return out
             return ptrs
 
-        refs = set().union(*[p[3] for p in parts]) if parts else set()
         out_dt = dt.Optional(dt.POINTER) if optional else dt.POINTER
-        return fn, out_dt, False, refs
+        return fn, out_dt, False
 
     if isinstance(expr, MakeTupleExpression):
-        parts = [_build(a, env, xp_name) for a in expr._args]
+        parts = [_build(a, env) for a in expr._args]
 
         def fn(cols, keys):
             n = len(keys)
@@ -777,13 +493,12 @@ def _build(
             return out
 
         out_dt = dt.Tuple(*[p[1] for p in parts])
-        refs = set().union(*[p[3] for p in parts]) if parts else set()
-        return fn, out_dt, False, refs
+        return fn, out_dt, False
 
     if isinstance(expr, GetExpression):
-        of, odt, ook, orefs = _build(expr._obj, env, xp_name)
-        ixf, _, _, ixrefs = _build(expr._index, env, xp_name)
-        df, ddt, _, drefs = _build(expr._default, env, xp_name)
+        of, odt, _ = _build(expr._obj, env)
+        ixf, _, _ = _build(expr._index, env)
+        df, ddt, _ = _build(expr._default, env)
         check = expr._check_if_exists
 
         def fn(cols, keys):
@@ -816,15 +531,15 @@ def _build(
             out_dt = dt.JSON
         if not check:
             out_dt = dt.types_lca(out_dt, ddt)
-        return fn, out_dt, False, orefs | ixrefs | drefs
+        return fn, out_dt, False
 
     if isinstance(expr, (AsyncApplyExpression, ApplyExpression)):
-        return _build_apply(expr, env, xp_name)
+        return _build_apply(expr, env)
 
     if isinstance(expr, MethodCallExpression):
         from .expressions_namespaces import compile_method
 
-        return compile_method(expr, env, _build, xp_name)
+        return compile_method(expr, env, _build)
 
     if isinstance(expr, ReducerExpression):
         raise TypeError(
@@ -928,8 +643,8 @@ def _dtype_sig(arrs: list, karrs: dict) -> tuple | None:
 
 
 def _build_apply(
-    expr: "ApplyExpression", env: ColumnEnv, xp_name: str
-) -> tuple[Callable, dt.DType, bool, set]:
+    expr: "ApplyExpression", env: ColumnEnv
+) -> tuple[Callable, dt.DType, bool]:
     """Compile an apply node through the fast-path ladder:
 
     1. static lift (bytecode-execution trace, then AST lift) — the UDF
@@ -972,9 +687,9 @@ def _build_apply(
     def _arg_parts() -> tuple[list, dict]:
         nonlocal parts, kparts
         if parts is None:
-            parts = [_build(a, env, xp_name) for a in expr._args]
+            parts = [_build(a, env) for a in expr._args]
             kparts = {
-                k: _build(v, env, xp_name) for k, v in expr._kwargs.items()
+                k: _build(v, env) for k, v in expr._kwargs.items()
             }
         return parts, kparts
 
@@ -1002,11 +717,6 @@ def _build_apply(
         )
 
     def _guard(vec: Callable) -> Callable:
-        # numpy kernels only: under a fused-jax rebuild the tracer flows
-        # through the try body and the fallback must not trace
-        if xp_name != "numpy":
-            return vec
-
         def fn(cols, keys):
             try:
                 return vec(cols, keys)
@@ -1112,7 +822,7 @@ def _build_apply(
             lifted = None
             if traced is not None:
                 try:
-                    lifted, _odt, agg, refs = _build(traced, env, xp_name)
+                    lifted, _odt, agg = _build(traced, env)
                 except Exception as e:
                     # the traced tree may hit operator/dtype combinations
                     # the columnar compiler refuses (e.g. str * int);
@@ -1126,7 +836,7 @@ def _build_apply(
                 _note_outcome("lifted")
                 return (
                     _align_dtype(_guard(lifted), expr._return_type),
-                    expr._return_type, agg, refs,
+                    expr._return_type, agg,
                 )
             from .udf_lift import evict_oldest_half
 
@@ -1138,11 +848,6 @@ def _build_apply(
             _LIFT_REFUSED_CODES.add(fn_user.__code__)
 
     parts, kparts = _arg_parts()
-    refs = (
-        set().union(*[p[3] for p in parts], *[p[3] for p in kparts.values()])
-        if (parts or kparts)
-        else set()
-    )
 
     if is_coro:
         def fn_async(cols, keys):
@@ -1177,11 +882,11 @@ def _build_apply(
             return _densify(out, expr._return_type)
 
         _note_outcome("async", refusal_reason)
-        return fn_async, expr._return_type, False, refs
+        return fn_async, expr._return_type, False
 
     # ---- 2./3. runtime: probe-row trace, else vectorized per-row -----
     trace_ok = False
-    if trace_eligible and xp_name == "numpy":
+    if trace_eligible:
         from .udf_lift import traceable
 
         trace_ok = traceable(fn_user)
@@ -1202,7 +907,7 @@ def _build_apply(
             texpr, probe_val = trace_probe(
                 fn_user, probe, list(expr._args), kprobe, dict(expr._kwargs)
             )
-            kernel, _odt, _agg, _refs = _build(texpr, env, "numpy")
+            kernel, _odt, _agg = _build(texpr, env)
             kernel = _align_dtype(kernel, expr._return_type)
             # consistency check: the compiled plan must reproduce the
             # probe row's genuine result before it serves the stream
@@ -1247,7 +952,7 @@ def _build_apply(
             fn_user, lists, klists, n, prop_none, expr._return_type
         )
 
-    return fn, expr._return_type, False, refs
+    return fn, expr._return_type, False
 
 
 #: (fn code, arg dtypes) -> refusal reason (str | None) of apply lambdas
@@ -1337,9 +1042,7 @@ def _align_dtype(fn: Callable, want: dt.DType) -> Callable:
 
     def cast(cols, keys):
         out = fn(cols, keys)
-        # trace-safe: never np.asarray here — under the fused-DAG jit
-        # (``_make_jitted``) ``out`` is a jax tracer. astype exists on both
-        # numpy arrays and tracers; anything without a dtype passes through.
+        # anything without a dtype (a constant's row value) passes through
         dtype = getattr(out, "dtype", None)
         if (
             dtype is not None
@@ -1398,19 +1101,17 @@ def _densify(arr: np.ndarray, dtype: dt.DType) -> np.ndarray:
         return arr
 
 
-def _binop_fn(op, lf, rf, ldt, rdt, xp):
+def _binop_fn(op, lf, rf, ldt, rdt):
     lu, ru = dt.unoptionalize(ldt), dt.unoptionalize(rdt)
 
     if op in ("/", "//", "%") and (
         op != "/" or (lu in _NUMERIC and ru in _NUMERIC)
     ):
         base = {
-            "/": xp.true_divide, "//": xp.floor_divide, "%": xp.mod
+            "/": np.true_divide, "//": np.floor_divide, "%": np.mod
         }[op]
 
         def vec(lv, rv, keys):
-            if xp is not np:  # inside a fused jax kernel: no Error carriers
-                return base(lv, rv)
             ra = np.asarray(rv)
             if ra.dtype.kind in "iuf":
                 zeros = ra == 0
@@ -1430,11 +1131,11 @@ def _binop_fn(op, lf, rf, ldt, rdt, xp):
         return _objsafe(vec, op, lf, rf)
     if op == "&" and lu == dt.BOOL and ru == dt.BOOL:
         return _objsafe(
-            lambda lv, rv, keys: xp.logical_and(lv, rv), op, lf, rf
+            lambda lv, rv, keys: np.logical_and(lv, rv), op, lf, rf
         )
     if op == "|" and lu == dt.BOOL and ru == dt.BOOL:
         return _objsafe(
-            lambda lv, rv, keys: xp.logical_or(lv, rv), op, lf, rf
+            lambda lv, rv, keys: np.logical_or(lv, rv), op, lf, rf
         )
 
     import operator as _op
@@ -1520,7 +1221,7 @@ def _objsafe(vec_fn, op, lf, rf):
     return fn
 
 
-def _cast_fn(f, src: dt.DType, target: dt.DType, xp):
+def _cast_fn(f, src: dt.DType, target: dt.DType):
     tu = dt.unoptionalize(target)
     su = dt.unoptionalize(src)
 
@@ -1562,11 +1263,11 @@ def _cast_fn(f, src: dt.DType, target: dt.DType, xp):
                 out[i] = convert_scalar(arr[i])
             return _densify(out, target)
         if tu == dt.INT:
-            return xp.asarray(arr).astype(xp.int64 if xp is np else "int64")
+            return np.asarray(arr).astype(np.int64)
         if tu == dt.FLOAT:
-            return xp.asarray(arr).astype(xp.float64 if xp is np else "float64")
+            return np.asarray(arr).astype(np.float64)
         if tu == dt.BOOL:
-            return xp.asarray(arr).astype(bool)
+            return np.asarray(arr).astype(bool)
         if tu == dt.STR:
             out = np.empty(n, dtype=object)
             av = np.asarray(arr)

@@ -338,12 +338,11 @@ _METHODS: dict[str, tuple[Callable, Callable]] = {
 }
 
 
-def compile_method(expr: MethodCallExpression, env, build, xp_name):
+def compile_method(expr: MethodCallExpression, env, build):
     name = expr._method
     kw = expr._method_kwargs
-    parts = [build(a, env, xp_name) for a in expr._args]
+    parts = [build(a, env) for a in expr._args]
     arg_dtypes = [p[1] for p in parts]
-    refs = set().union(*[p[3] for p in parts]) if parts else set()
 
     if name in ("str.parse_int", "str.parse_float", "str.parse_bool"):
         optional = kw.get("optional", False)
@@ -385,7 +384,7 @@ def compile_method(expr: MethodCallExpression, env, build, xp_name):
                 return out.astype(out_dt.numpy_dtype)
             return out
 
-        return fn, (dt.Optional(out_dt) if optional else out_dt), False, refs
+        return fn, (dt.Optional(out_dt) if optional else out_dt), False
 
     if name == "dt.timestamp":
         unit = kw.get("unit")
@@ -405,7 +404,7 @@ def compile_method(expr: MethodCallExpression, env, build, xp_name):
                 out[i] = ns / div if as_float else ns // div
             return out
 
-        return fn, dt.FLOAT if as_float else dt.INT, False, refs
+        return fn, dt.FLOAT if as_float else dt.INT, False
 
     if name == "dt.from_timestamp":
         mul = _UNIT_NS[kw["unit"]]
@@ -425,7 +424,7 @@ def compile_method(expr: MethodCallExpression, env, build, xp_name):
                 out[i] = epoch + datetime.timedelta(microseconds=us)
             return out
 
-        return fn, dt.DATE_TIME_NAIVE, False, refs
+        return fn, dt.DATE_TIME_NAIVE, False
 
     if name == "dt.strptime":
         contains_tz = kw.get("contains_timezone", False)
@@ -440,7 +439,7 @@ def compile_method(expr: MethodCallExpression, env, build, xp_name):
                 out[i] = datetime.datetime.strptime(vals[i], fmts[i])
             return out
 
-        return fn, dt.DATE_TIME_UTC if contains_tz else dt.DATE_TIME_NAIVE, False, refs
+        return fn, dt.DATE_TIME_UTC if contains_tz else dt.DATE_TIME_NAIVE, False
 
     if name in ("dt.round", "dt.floor"):
         def fn(cols, keys, f=parts[0][0], df=parts[1][0]):
@@ -460,7 +459,7 @@ def compile_method(expr: MethodCallExpression, env, build, xp_name):
                 out[i] = epoch + datetime.timedelta(microseconds=ns / 1000)
             return out
 
-        return fn, arg_dtypes[0], False, refs
+        return fn, arg_dtypes[0], False
 
     if name == "num.fill_na":
         def fn(cols, keys, f=parts[0][0], dflt=parts[1][0]):
@@ -483,7 +482,7 @@ def compile_method(expr: MethodCallExpression, env, build, xp_name):
 
             return _densify(out, dt.unoptionalize(arg_dtypes[0]))
 
-        return fn, dt.unoptionalize(arg_dtypes[0]), False, refs
+        return fn, dt.unoptionalize(arg_dtypes[0]), False
 
     if name not in _METHODS:
         # internal invariant: every namespace method constructs a name listed
@@ -509,4 +508,4 @@ def compile_method(expr: MethodCallExpression, env, build, xp_name):
                 out[i] = impl(*args_i)
         return _densify(out, out_dt)
 
-    return fn, (dt.Optional(out_dt) if any_opt else out_dt), False, refs
+    return fn, (dt.Optional(out_dt) if any_opt else out_dt), False

@@ -55,9 +55,9 @@ class _Batch:
         self.frame: Any = None
 
 
-#: process-wide ingest-build accounting (read by bench.py's ingest-split
-#: extra block): ns spent building batch deltas on subject (producer)
-#: threads vs on the engine thread, and the rows covered by each
+#: process-wide ingest-build accounting: ns spent building batch deltas on
+#: subject (producer) threads vs on the engine thread, and the rows covered
+#: by each
 INGEST_BUILD_STATS = {
     "subject_ns": 0,
     "subject_rows": 0,
@@ -73,7 +73,7 @@ INGEST_BUILD_STATS = {
 #: Delta assembly + per-flush concat. Accrued only while the profiling
 #: plane is on (PATHWAY_PROFILE, same kill switch as the sampler);
 #: surfaces: pathway_ingest_stage_seconds on /metrics, ingest.* signals
-#: series, the `pathway-tpu top` ingest line, bench's ingest_stage_split.
+#: series, the `pathway-tpu top` ingest line.
 INGEST_STAGE_STATS = {
     "parse_ns": 0,
     "hash_ns": 0,

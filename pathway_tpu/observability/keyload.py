@@ -27,9 +27,7 @@ Design (SpaceSaving, Metwally et al. 2005; merge discipline from
   halve every interval, so the ranking reflects the recent window
   rather than the whole run.
 
-The accounting is windowed OFF with ``PATHWAY_KEYLOAD=0`` — the bench's
-accounting A/B (``bench.py`` sharded lanes) holds the on/off throughput
-delta under 3%.
+The accounting is windowed OFF with ``PATHWAY_KEYLOAD=0``.
 
 Everything here is pure (no threads, no comm): per-worker accounts live
 on ``EngineStats.keyload``, ship in the hub snapshot like every other

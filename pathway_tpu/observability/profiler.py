@@ -33,9 +33,7 @@ The profiler is ON by default and OFF with ``PATHWAY_PROFILE=0`` — the
 kill switch silences everything at once: no sampler thread, no op slots
 (``current_op_slot()`` returns ``None`` — one branch per node on the
 executor hot path), no ingest stage counters, no ``pathway_profile_*``
-metric families, no ``profile.*`` signals series. The bench A/B
-(``bench.py ingest_stage_split`` lane) holds the on/off throughput delta
-under 3%.
+metric families, no ``profile.*`` signals series.
 
 The sampler also deposits its top-K collapsed stacks into the mmap
 flight ring (``flightrecorder.py``) every ``PATHWAY_PROFILE_FLIGHT_S``

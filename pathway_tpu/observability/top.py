@@ -196,8 +196,6 @@ def render_frame(doc: dict, now: float | None = None) -> str:
             f"{_fmt(f.get('preambles_total'), nd=0)} preamble(s), "
             f"{_fmt(f.get('fallbacks_total'), nd=0)} fallback(s)"
         )
-        if f.get("jit_chains_total"):
-            line += f", {_fmt(f.get('jit_chains_total'), nd=0)} XLA"
         lines.append(line)
     srv = doc.get("serve", {})
     # merged docs key serve by process; single-process docs are flat

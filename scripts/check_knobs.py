@@ -39,7 +39,7 @@ _KNOB = re.compile(r"(?<![A-Z0-9_])(PATHWAY_[A-Z0-9_]+)(?![A-Z0-9_])")
 
 #: code trees scanned for "is this documented knob still referenced"
 _REFERENCE_ROOTS = ("pathway_tpu", "scripts", "tests")
-_REFERENCE_FILES = ("bench.py", "__graft_entry__.py")
+_REFERENCE_FILES = ("__graft_entry__.py",)
 
 
 def collect_knobs(package_dir: str | None = None) -> dict[str, list[str]]:

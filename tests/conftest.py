@@ -1,7 +1,5 @@
 """Test harness config: force a virtual 8-device CPU platform BEFORE any
-backend initializes, so multi-chip sharding tests run without TPU hardware.
-x64 is on from the start, as in any CPU-only process once the host
-expression tier has built its first XLA kernel."""
+backend initializes, so multi-chip sharding tests run without TPU hardware."""
 
 import os
 
@@ -13,7 +11,6 @@ os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 from pathway_tpu.utils import jaxcfg  # noqa: E402
 
 jaxcfg.guard_cpu_platform(force_device_count=8)
-jaxcfg.enable_x64_on_cpu()
 
 import pytest  # noqa: E402
 

@@ -115,21 +115,37 @@ def test_compile_cache_path_is_not_built_from_process_state():
         assert ".jax_cache/" in f.read().split()
 
 
+def _package_sources():
+    """(path relative to the checkout, text) of every module of the package."""
+    for root, _dirs, files in os.walk(os.path.join(REPO, "pathway_tpu")):
+        for name in files:
+            if name.endswith(".py"):
+                path = os.path.join(root, name)
+                with open(path) as f:
+                    yield os.path.relpath(path, REPO), f.read()
+
+
 def test_every_jax_import_goes_through_jaxcfg():
     """A module that imports jax without jaxcfg could compile before the
     cache is placed."""
-    offenders = []
-    for root, _dirs, files in os.walk(os.path.join(REPO, "pathway_tpu")):
-        for name in files:
-            path = os.path.join(root, name)
-            if not name.endswith(".py") or path.endswith("utils/jaxcfg.py"):
-                continue
-            with open(path) as f:
-                src = f.read()
-            if re.search(r"^\s*(import jax\b|from jax\b)", src, re.M) and (
-                "jaxcfg" not in src
-            ):
-                offenders.append(os.path.relpath(path, REPO))
+    offenders = [
+        path for path, src in _package_sources()
+        if not path.endswith("utils/jaxcfg.py")
+        and re.search(r"^\s*(import jax\b|from jax\b)", src, re.M)
+        and "jaxcfg" not in src
+    ]
+    assert not offenders, offenders
+
+
+def test_nothing_switches_x64_on():
+    """The chip runs every device program without x64, so the package never
+    updates ``jax_enable_x64``, and the tests do not run in another mode."""
+    with open(os.path.join(REPO, "tests", "conftest.py")) as f:
+        sources = [("tests/conftest.py", f.read()), *_package_sources()]
+    offenders = [
+        path for path, src in sources
+        if re.search(r"enable_x64|JAX_ENABLE_X64", src)
+    ]
     assert not offenders, offenders
 
 
@@ -185,17 +201,6 @@ def test_chip_smoke_rehearsal_runs_every_phase(tmp_path):
     by_phase = {x["phase"]: x for x in lines[:-1]}
     assert by_phase["host"]["native"] is True
     assert by_phase["index"]["cache_hits"] >= 1
-
-
-def test_bench_fails_without_an_accelerator_and_knows_no_default_peak():
-    r = _run("import bench; bench._require_device()",
-             _env(JAX_PLATFORMS=None))
-    assert r.returncode != 0 and "no accelerator" in r.stderr
-    import bench
-
-    assert bench._require_device() == "cpu"  # conftest pinned it
-    with pytest.raises(SystemExit, match="no peak on record"):
-        bench._peak_bf16_flops()
 
 
 def test_native_object_is_named_by_its_source(tmp_path):

@@ -33,7 +33,6 @@ from pathway_tpu.engine.fusion import (
     FusedChain,
     plan_chains,
 )
-from pathway_tpu.internals import expression_compiler as ec
 from pathway_tpu.internals.parse_graph import G
 
 
@@ -391,7 +390,8 @@ def test_sorted_side_deferred_maintenance_parity(monkeypatch):
     assert harvest(restored) == harvest(eager)
 
 
-def test_hash_range_index_matches_searchsorted():
+def test_hash_range_index_matches_searchsorted(monkeypatch):
+    monkeypatch.setenv("PATHWAY_FUSION", "1")  # the index is fusion's
     side = ops._SortedSide(1)
     rng = np.random.default_rng(1)
     n = 8192
@@ -635,22 +635,19 @@ def test_attribution_names_member_inside_chain():
     assert f"FusedChain#{chain.node_id}" not in stats.time_by_node
 
 
-def test_whole_chain_jit_tier(monkeypatch):
-    """A pure numeric chain compiles to ONE XLA callable past the
-    warmup gate, with identical results."""
-    pytest.importorskip("jax")
-    monkeypatch.setattr(ec, "JIT_THRESHOLD", 8)
-    monkeypatch.setattr(ec, "JIT_WARMUP_BATCHES", 1)
+def test_numeric_chain_select_filter_select_rows(monkeypatch):
+    """A pure numeric select-filter-select chain fuses into one node and
+    emits exactly the rows the expressions define."""
     monkeypatch.setenv("PATHWAY_FUSION", "1")
     G.clear()
-    before = FUSION_STATS["jit_chains_total"]
+    before = FUSION_STATS["chains_total"]
     n = 64
     batches = [{"a": list(range(s, s + n))} for s in range(0, 5 * n, n)]
     got: list = []
 
     t = _stream(batches, pw.schema_from_types(a=int))
-    # % stays off the jit tier (per-row error semantics) — pure
-    # arithmetic + comparison keeps every kernel jax-compilable
+    # every kernel is total, so the filter's mask stays deferred across
+    # the last select
     out = t.select(b=pw.this.a * 3 + 1, a=pw.this.a).filter(
         pw.this.b > 16
     ).select(c=pw.this.b - pw.this.a)
@@ -659,20 +656,16 @@ def test_whole_chain_jit_tier(monkeypatch):
     ))
     pw.run()
     G.clear()
-    assert FUSION_STATS["jit_chains_total"] > before
+    assert FUSION_STATS["chains_total"] > before
     want = sorted(
         (2 * a + 1, 1) for a in range(5 * n) if 3 * a + 1 > 16
     )
     assert sorted(got) == want
 
 
-def test_filter_only_chain_jit_passthrough(monkeypatch):
-    """A chain with no Rowwise (or with pass-through columns) must carry
-    every output column as a jit source column — a filter-only chain
-    used to build a plan whose traced function always KeyError'd."""
-    pytest.importorskip("jax")
-    monkeypatch.setattr(ec, "JIT_THRESHOLD", 8)
-    monkeypatch.setattr(ec, "JIT_WARMUP_BATCHES", 1)
+def test_filter_only_chain_carries_every_output_column(monkeypatch):
+    """A chain with no Rowwise passes its input columns through: every
+    output column must come out, also the ones no predicate reads."""
     monkeypatch.setenv("PATHWAY_FUSION", "1")
     G.clear()
     n = 64
@@ -682,48 +675,127 @@ def test_filter_only_chain_jit_passthrough(monkeypatch):
         for s in range(0, 4 * n, n)
     ]
     got: list = []
-    before = FUSION_STATS["jit_chains_total"]
+    before = FUSION_STATS["chains_total"]
     t = _stream(batches, pw.schema_from_types(a=int, b=int, c=int))
     out = t.filter(pw.this.a > 1).filter(pw.this.b > 2)
     pw.io.subscribe(out, on_batch=lambda tm, bb: got.extend(
-        bb.data["c"].tolist()
+        zip(bb.data["a"].tolist(), bb.data["b"].tolist(),
+            bb.data["c"].tolist())
     ))
     pw.run()
     G.clear()
-    assert sorted(got) == list(range(3, 4 * n))
-    assert FUSION_STATS["jit_chains_total"] > before  # plan really usable
+    assert FUSION_STATS["chains_total"] > before
+    assert sorted(got) == [(v, v, v) for v in range(3, 4 * n)]
 
 
-def test_fused_cache_entries_evict_with_members():
-    """A fused-chain kernel must not outlive any member signature the
-    oldest-half sweep evicts (no stale composite serving a rebuilt
-    member)."""
-    cache = ec._JIT_KERNEL_CACHE
-    deps = ec._JIT_CHAIN_DEPS
-    saved_cache, saved_deps = dict(cache), dict(deps)
-    cache.clear()
-    deps.clear()
-    try:
-        old = [("m", i) for i in range(4)]
-        young = [("m", i) for i in range(4, 8)]
-        for s in old + young:
-            cache[s] = object()
-        chain_old = ("chain", old[0])
-        chain_young = ("chain", young[-1])
-        cache[chain_old] = object()
-        deps[chain_old] = frozenset([old[0]])
-        cache[chain_young] = object()
-        deps[chain_young] = frozenset([young[-1]])
-        ec._evict_jit_cache()
-        assert old[0] not in cache           # oldest half gone
-        assert chain_old not in cache        # fused entry went with it
-        assert chain_young in cache          # members intact → survives
-        assert chain_old not in deps
-    finally:
-        cache.clear()
-        cache.update(saved_cache)
-        deps.clear()
-        deps.update(saved_deps)
+def test_large_numeric_batches_leave_x64_off(monkeypatch):
+    """Host expressions are numpy kernels at every batch size: a numeric
+    chain that keeps seeing large batches must not change the precision
+    mode the device programs of this process run in."""
+    from pathway_tpu.utils import jaxcfg  # noqa: F401
+    import jax
+
+    monkeypatch.setenv("PATHWAY_FUSION", "1")
+    G.clear()
+    n, batches = 8192, 20
+    kept = [0]
+    t = _stream(
+        [{"a": np.arange(s, s + n)} for s in range(0, batches * n, n)],
+        pw.schema_from_types(a=int),
+    )
+    out = t.select(b=pw.this.a * 3 + 1).filter(pw.this.b > 16)
+
+    def on_batch(tm, b):
+        kept[0] += len(b.keys)
+
+    pw.io.subscribe(out, on_batch=on_batch)
+    pw.run()
+    G.clear()
+    assert kept[0] == batches * n - 6
+    assert jax.config.jax_enable_x64 is False
+
+
+def _wide_rows(n: int) -> dict[str, list]:
+    """Operands whose results need all 64 bits: ints near 2**62 of both
+    signs, factors near 2**31, floats that differ below float32's
+    resolution."""
+    i = list(range(n))
+    return {
+        "i": i,
+        "a": [(1 if k % 2 else -1) * (2**62 - 1 - 1_000_003 * k) for k in i],
+        "b": [(1 if k % 3 else -1) * (1 + k % 997) for k in i],
+        "x": [2**31 - 1 - 7 * k for k in i],
+        "f": [1.0 + k * 2.0**-40 for k in i],
+        "g": [(1 if k % 5 else -1) * (0.1 + k * 2.0**-33) for k in i],
+    }
+
+
+#: op -> (int expression, its reference on Python ints; float expression,
+#: its reference on Python floats, which are IEEE doubles)
+_WIDE_CASES = {
+    "+": (lambda t: t.a + t.b, lambda r: r["a"] + r["b"],
+          lambda t: t.f + t.g, lambda r: r["f"] + r["g"]),
+    "-": (lambda t: t.a - t.b, lambda r: r["a"] - r["b"],
+          lambda t: t.f - t.g, lambda r: r["f"] - r["g"]),
+    "*": (lambda t: t.x * (t.x - t.b), lambda r: r["x"] * (r["x"] - r["b"]),
+          lambda t: t.f * t.g, lambda r: r["f"] * r["g"]),
+    "//": (lambda t: t.a // t.b, lambda r: r["a"] // r["b"],
+           lambda t: t.f // t.g, lambda r: r["f"] // r["g"]),
+    "%": (lambda t: t.a % t.b, lambda r: r["a"] % r["b"],
+          lambda t: t.f % t.g, lambda r: r["f"] % r["g"]),
+    # int / int divides the operands as doubles
+    "/": (lambda t: t.a // t.x, lambda r: r["a"] // r["x"],
+          lambda t: t.a / t.b, lambda r: float(r["a"]) / float(r["b"])),
+    "cast": (lambda t: pw.cast(int, t.f * 2.0**61),
+             lambda r: int(r["f"] * 2.0**61),
+             lambda t: pw.cast(float, t.a) + t.f,
+             lambda r: float(r["a"]) + r["f"]),
+}
+
+
+@pytest.mark.parametrize("op", list(_WIDE_CASES))
+def test_wide_values_agree_at_small_and_large_batches(op, monkeypatch):
+    """64-bit semantics are the numpy kernel's own at every batch size:
+    the same select-filter-select chain fed 8 rows and 8,192 rows a batch
+    gives the rows Python's ints and doubles give, bit for bit."""
+    int_expr, int_ref, float_expr, float_ref = _WIDE_CASES[op]
+    monkeypatch.setenv("PATHWAY_FUSION", "1")
+    large, small, n_small = 8192, 8, 64
+    rows = _wide_rows(large)
+    schema = pw.schema_from_types(a=int, b=int, x=int, f=float, g=float, i=int)
+
+    def run(batches) -> dict:
+        G.clear()
+        got: dict = {}
+        t = _stream(batches, schema)
+        out = t.select(
+            i=pw.this.i, ri=int_expr(pw.this), rf=float_expr(pw.this)
+        ).filter(pw.this.i > 2).select(
+            i=pw.this.i, ri=pw.this.ri - pw.this.i, rf=pw.this.rf * 0.5
+        )
+
+        def on_batch(tm, b):
+            assert b.data["ri"].dtype == np.int64
+            assert b.data["rf"].dtype == np.float64
+            for i, ri, rf in zip(*(b.data[c].tolist() for c in ("i", "ri", "rf"))):
+                got[i] = (ri, rf)
+
+        pw.io.subscribe(out, on_batch=on_batch)
+        pw.run()
+        G.clear()
+        return got
+
+    at_large = run([rows])
+    at_small = run([
+        {c: v[s:s + small] for c, v in rows.items()}
+        for s in range(0, n_small, small)
+    ])
+    want = {}
+    for k in range(3, large):
+        r = {c: v[k] for c, v in rows.items()}
+        want[k] = (int_ref(r) - k, float_ref(r) * 0.5)
+    assert at_large == want
+    assert at_small == {k: want[k] for k in range(3, n_small)}
 
 
 def test_fusion_counters_render_on_metrics():

@@ -467,17 +467,13 @@ def test_a_column_is_normalised_while_another_thread_imports_jax(monkeypatch):
     assert out.tolist() == [1, 2, 3]
 
 
-def test_the_inputs_route_lists_documents_that_arrived_in_large_batches(monkeypatch):
+def test_the_inputs_route_lists_documents_that_arrived_in_large_batches():
     """The documents' side of the inputs join computes its key over deltas
-    that also carry ``_metadata`` dicts. Past the warm-up gate of large
-    batches that key is a jitted kernel, which must be handed the columns it
-    reads and not the object columns beside them."""
-    from pathway_tpu.internals import expression_compiler as ec
+    that also carry ``_metadata`` dicts: the key's kernel reads its own
+    columns, whatever object columns ride beside them."""
     from pathway_tpu.stdlib.indexing.nearest_neighbors import BruteForceKnnFactory
     from pathway_tpu.xpacks.llm.document_store import DocumentStore
 
-    monkeypatch.setattr(ec, "JIT_THRESHOLD", 8)
-    monkeypatch.setattr(ec, "JIT_WARMUP_BATCHES", 1)
     G.clear()
     n, batches = 16, 4
     rng = np.random.default_rng(12)
