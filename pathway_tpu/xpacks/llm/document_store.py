@@ -72,6 +72,10 @@ class DocumentStore:
         #: skipped and the index is built over the vectors directly (the
         #: retriever's embedder then only embeds queries). The common
         #: "embeddings computed offline / by another pipeline" deployment.
+        #: The vectors live in the index and are no part of a reply or of
+        #: ``DataIndex``'s right side: carried there, every matched row's
+        #: 3-4 KB array was joined, grouped and hashed cell by cell in
+        #: Python each tick, for a column ``retrieve_query`` never reads.
         self.vector_column = vector_column
         self.build_pipeline()
 
@@ -111,9 +115,11 @@ class DocumentStore:
                 text=this.text, _metadata=this._metadata
             )
             self.chunked_documents = chunked
+            # indexed over the vectors, repacked from what a reply shows
+            # (same universe and row ids as ``chunked``)
             self.index = self.retriever_factory.build_index(
                 pw.ColumnReference(chunked, "_pw_vector"),
-                chunked,
+                self.parsed_documents,
                 metadata_column=this._metadata,
             )
             return

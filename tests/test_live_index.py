@@ -453,6 +453,51 @@ def test_a_store_answers_whole_while_a_writer_commits_between_and_inside_ticks()
     assert shown == max(wrote.values())
 
 
+def test_a_commit_that_changes_only_a_vector_reaches_the_next_reply():
+    """The documents' side of the reply join holds ``text`` and ``_metadata``
+    only, so a commit that replaces a row's vector and nothing else cancels
+    there by key. The index's side must still see it: the next reply's
+    ``dist`` moves, a delete removes the row, an add brings it back."""
+    from pathway_tpu.stdlib.indexing.nearest_neighbors import BruteForceKnnFactory
+    from pathway_tpu.xpacks.llm.document_store import DocumentStore
+
+    def row(i: int, cos: float, time: int, diff: int) -> tuple:
+        vec = np.zeros(DIM, np.float32)
+        vec[0], vec[1] = cos, np.sqrt(1.0 - cos * cos)
+        return (i, f"doc {i}", {"path": f"d{i}.txt"}, vec, time, diff)
+
+    schema = pw.schema_builder({
+        "id": pw.column_definition(dtype=int, primary_key=True),
+        "data": str, "_metadata": dict, "vec": np.ndarray})
+    docs = pw.debug.table_from_rows(schema, [
+        *(row(i, 0.1 * i, 2, 1) for i in range(6)),
+        row(3, 0.3, 6, -1), row(3, 0.9, 6, 1),  # the vector alone changes
+        row(3, 0.9, 10, -1),                    # deleted
+        row(3, 0.3, 14, 1),                     # and added back
+    ], is_stream=True)
+    store = DocumentStore(
+        docs, BruteForceKnnFactory(dimensions=DIM, reserved_space=16, metric="cos",
+                                   embedder=lambda text: PROBE),
+        vector_column="vec")
+    queries = pw.debug.table_from_rows(
+        DocumentStore.RetrieveQuerySchema,
+        [(f"at {t:02d}", 6, None, None, t) for t in (4, 8, 12, 16)], is_stream=True)
+    asked = pw.debug.table_to_pandas(queries)["query"]
+    got = pw.debug.table_to_pandas(store.retrieve_query(queries))["result"]
+    G.clear()
+    first, moved, gone, back = (
+        {hit["text"]: hit for hit in reply}
+        for _, reply in sorted((asked[key], got[key]) for key in asked.index))
+    assert sorted(first) == sorted(moved) == sorted(back) == [f"doc {i}" for i in range(6)]
+    assert abs(first["doc 3"]["dist"] + 0.3) <= TOL
+    assert abs(moved["doc 3"]["dist"] + 0.9) <= TOL
+    assert moved["doc 3"]["metadata"] == first["doc 3"]["metadata"] == {"path": "d3.txt"}
+    assert sorted(gone) == [f"doc {i}" for i in (0, 1, 2, 4, 5)]
+    assert back["doc 3"] == first["doc 3"]
+    for other in ("doc 0", "doc 1", "doc 2", "doc 4", "doc 5"):
+        assert first[other] == moved[other] == gone[other] == back[other]
+
+
 def test_a_column_is_normalised_while_another_thread_imports_jax(monkeypatch):
     """``sys.modules`` holds a module from the moment its import starts: the
     engine thread must not ask a half-imported jax for ``Array`` (a shard

@@ -282,6 +282,113 @@ def test_document_store_pre_embedded_mode():
     assert len(row) == 2
 
 
+def _seeded_store(n: int, dim: int, seed: int):
+    """``n`` pre-embedded rows from ``seed`` and a store over them whose
+    embedder maps a query ``q<j>`` to the ``j``-th of 8 seeded vectors."""
+    rng = np.random.default_rng(seed)
+    vecs = rng.standard_normal((n, dim)).astype(np.float32)
+    probes = rng.standard_normal((8, dim)).astype(np.float32)
+    rows = [
+        (f"chunk {i}", {"path": f"dir{i % 3}/f{i}.txt", "owner": f"o{i % 5}"}, vecs[i])
+        for i in range(n)
+    ]
+    docs = pw.debug.table_from_rows(
+        pw.schema_from_types(data=str, _metadata=dict, vec=np.ndarray), rows
+    )
+    factory = BruteForceKnnFactory(
+        dimensions=dim, reserved_space=64, metric="cos",
+        embedder=lambda text: probes[int(text[1:])],
+    )
+    return DocumentStore(docs, factory, vector_column="vec"), factory
+
+
+def _asked_and_answered(store, queries) -> list[tuple]:
+    """(query text, reply) of every row of ``queries``, in row-id order."""
+    asked = pw.debug.table_to_pandas(queries)["query"]
+    got = pw.debug.table_to_pandas(store.retrieve_query(queries))["result"]
+    return [(asked[key], got[key]) for key in asked.index]
+
+
+def _holds_array(value) -> bool:
+    if isinstance(value, np.ndarray):
+        return True
+    if isinstance(value, dict):
+        return any(_holds_array(v) for v in value.values())
+    return isinstance(value, (tuple, list)) and any(_holds_array(v) for v in value)
+
+
+def test_a_pre_embedded_store_keeps_the_vectors_out_of_the_reply_path():
+    from pathway_tpu.internals.graph_runner import GraphRunner
+
+    store, _ = _seeded_store(40, 16, seed=3)
+    assert store.index.data_table.column_names() == ["text", "_metadata"]
+    assert store.chunked_documents.column_names() == ["text", "_metadata", "_pw_vector"]
+    queries = pw.debug.table_from_rows(
+        DocumentStore.RetrieveQuerySchema,
+        [("q0", 5, None, None), ("q1", 3, "owner == 'o2'", None), ("q2", 4, None, "dir1/*")],
+    )
+    result = store.retrieve_query(queries)
+    # every table from the reply (the index node's output) and the documents'
+    # side of the join down to ``result``: the index's own input, above the
+    # reply, is the one place the vectors are
+    seen, todo = {}, [result]
+    while todo:
+        table = todo.pop()
+        if id(table) in seen:
+            continue
+        seen[id(table)] = table
+        if table._kind != "custom" and table is not store.index.data_table:
+            todo.extend(table._inputs)
+    between = list(seen.values())
+    kinds = {t._kind for t in between}
+    assert "custom" in kinds and id(store.index.data_table) in seen
+    assert {"flatten", "join_select", "groupby_reduce", "update_rows"} <= kinds, kinds
+    rows = 0
+    for table, cap in zip(between, GraphRunner().run_tables(*between)):
+        for _, row in cap.state.iter_items():
+            rows += 1
+            assert not _holds_array(row), (table._kind, table.column_names())
+    assert rows > 40 + 3 * 4
+    r0, r1, r2 = (reply for _, reply in sorted(_asked_and_answered(store, queries)))
+    assert len(r0) == 5 and set(r0[0]) == {"text", "metadata", "dist"}
+    assert [d["metadata"]["owner"] for d in r1] == ["o2"] * 3
+    assert all(d["metadata"]["path"].startswith("dir1/") for d in r2) and len(r2) == 4
+
+
+def test_replies_equal_those_of_a_store_whose_data_table_carries_the_vector():
+    queries_rows = [(f"q{j}", 10, None, None) for j in range(8)] + [
+        ("q1", 7, "owner == 'o3'", None), ("q5", 25, None, "dir2/*"),
+        ("q6", 1, "owner == 'o0'", "dir0/*"), ("q7", 3, "owner == 'nobody'", None),
+    ]
+
+    def replies(carry_vector: bool):
+        G.clear()
+        store, factory = _seeded_store(300, 24, seed=17)
+        if carry_vector:
+            # what the store handed ``DataIndex`` before: the chunk table whole
+            chunked = store.chunked_documents
+            store.index = factory.build_index(
+                pw.ColumnReference(chunked, "_pw_vector"), chunked,
+                metadata_column=pw.this._metadata,
+            )
+            assert "_pw_vector" in store.index.data_table.column_names()
+        queries = pw.debug.table_from_rows(DocumentStore.RetrieveQuerySchema, queries_rows)
+        return _asked_and_answered(store, queries)
+
+    without, carried = replies(False), replies(True)
+    assert len(without) == len(carried) == len(queries_rows)
+    for (q_a, a), (q_b, b) in zip(without, carried):
+        assert q_a == q_b and len(a) == len(b)
+        for hit_a, hit_b in zip(a, b):
+            assert set(hit_a) == set(hit_b) == {"text", "metadata", "dist"}
+            assert hit_a["text"] == hit_b["text"]
+            assert hit_a["metadata"] == hit_b["metadata"]
+            assert hit_a["dist"] == hit_b["dist"]  # score by score, to the bit
+        dists = [hit["dist"] for hit in a]
+        assert dists == sorted(dists)
+    assert sorted(len(a) for _, a in without) == sorted([10] * 8 + [7, 25, 1, 0])
+
+
 def test_brute_force_bulk_add_matches_per_row():
     from pathway_tpu.ops.index_engines import BruteForceKnnEngine
 
