@@ -18,8 +18,9 @@ def read(trace, spans, counts, cell):
     padded = sum(w["args"].get("padded", 0) for w in writes)
     if not t or not padded:
         return None
+    cfg = cell["config"]
     nbytes = workcount_write.index_write_bytes(
-        padded / t["calls"], cell["config"]["hidden_size"])
+        padded / t["calls"], cfg["hidden_size"], cfg["metric"])
     least = nbytes / cell["chip"]["hbm_bytes_per_s"]
     print(f"[layer] index_write: {padded / t['calls']:.1f} padded slots a call, least "
           f"{least * 1e6:.3f} us, device {t['mean_s'] * 1e6:.1f} us a call over "

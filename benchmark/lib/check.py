@@ -12,7 +12,12 @@ Numbers compared (each against a limit of its own, ``benchmark/limits/``):
 - ``score_err``: the widest difference between a score the server returned
   and the reference's score of that very row and version;
 - ``bad_replies``: replies that are no 200, not ``k`` rows, unparsable, a row or
-  version that never existed, or one row twice (limit 0);
+  version that never existed, or one row twice (limit 0); a request confined
+  to a folder is answered whole by ``k`` rows where the folder holds ``k`` live
+  rows, else by every live row of it, no more;
+- scoped mixes: ``out_of_scope`` (rows answered that lie outside their
+  request's folder, over all replies; limit 0), and ``rank_gap`` is taken
+  against the reference's best over the folder's rows;
 - live mixes: ``order_violations`` (a row retired by a write that an earlier
   reply had already shown applied; limit 0), ``lost_writes`` (never seen a
   minute past the close; limit 0), ``count_off`` (rows the store counts after
@@ -26,9 +31,10 @@ import numpy as np
 from . import traffic
 
 
-def reply_rows(body, k: int):
-    """[(doc, chunk, ver, score)] of one reply, or None if it is malformed."""
-    if not isinstance(body, list) or len(body) != k:
+def reply_rows(body, fewest: int, most: int):
+    """[(doc, chunk, ver, score)] of one reply, or None if it is malformed or
+    holds fewer rows than ``fewest`` or more than ``most``."""
+    if not isinstance(body, list) or not fewest <= len(body) <= most:
         return None
     out, seen = [], set()
     for hit in body:
@@ -65,6 +71,15 @@ def order_violations(replies: list[dict], plan: "traffic.WriterPlan | None") -> 
     return bad
 
 
+def out_of_scope(replies: list[dict], folder_of_doc: np.ndarray) -> int:
+    """Rows answered that are not of the folder their request was confined to,
+    over the replies given; a request with no filter has none."""
+    docs = len(folder_of_doc)
+    return sum(not (0 <= d < docs and folder_of_doc[d] == r["scope"])
+               for r in replies if r["scope"] is not None
+               for d, *_ in r["rows"])
+
+
 def first_seen(replies: list[dict], plan: "traffic.WriterPlan") -> list[float | None]:
     """For each commit, when the first reply arrived that shows it applied: a
     row of that commit, or of a later one (the connector's stream is ordered)."""
@@ -85,15 +100,17 @@ def first_seen(replies: list[dict], plan: "traffic.WriterPlan") -> list[float | 
 
 
 def compare_sample(sample: list[dict], ref_vec: dict[str, np.ndarray],
-                   ref_top: dict[str, np.ndarray], row_vector, unstable: set[int]):
-    """(rank_gap, score_err, bad) over the sampled replies. ``ref_top[text]`` is
-    the reference's best-first scores over the stable rows; ``row_vector(doc,
-    chunk, ver)`` is the vector that version of the row was written with, or
-    None if it never existed."""
+                   ref_top: dict[tuple, np.ndarray], row_vector, unstable: set[int]):
+    """(rank_gap, score_err, bad) over the sampled replies. ``ref_top[(text,
+    scope)]`` is the reference's best-first scores over the stable rows of the
+    request's folder (scope None: of the store); ``row_vector(doc, chunk, ver)``
+    is the vector that version of the row was written with, or None if it never
+    existed."""
     rank_gap = score_err = 0.0
     bad = 0
     for r in sample:
         q = ref_vec[r["query"]].astype(np.float64)
+        top = ref_top[(r["query"], r["scope"])]
         j = 0
         for doc, chunk, ver, score in r["rows"]:
             vec = row_vector(doc, chunk, ver)
@@ -103,7 +120,7 @@ def compare_sample(sample: list[dict], ref_vec: dict[str, np.ndarray],
             ref = float(q @ vec.astype(np.float64))
             score_err = max(score_err, abs(score - ref))
             if doc not in unstable:
-                rank_gap = max(rank_gap, float(ref_top[r["query"]][j]) - ref)
+                rank_gap = max(rank_gap, float(top[j]) - ref)
                 j += 1
     return rank_gap, score_err, bad
 

@@ -1,5 +1,5 @@
 """Everything a run makes from ``--seed``: vocabulary, texts, encoder weights,
-index rows and the writer's schedule. numpy only; imports nothing of the
+index rows, the documents' folders and the writer's schedule. numpy only; imports nothing of the
 program and nothing of JAX. The same seed gives the same bytes whatever the
 number of threads, because every block draws from a generator of its own.
 """
@@ -81,6 +81,42 @@ def make_texts(seed: int, part: int, words: list[str], count: int,
 def zipf_weights(n: int, s: float) -> np.ndarray:
     w = 1.0 / np.arange(1, n + 1, dtype=np.float64) ** s
     return w / w.sum()
+
+
+def fixed_counts(total: int, weights: np.ndarray) -> np.ndarray:
+    """``total`` split into parts in proportion to ``weights`` (largest
+    remainders take what rounding leaves), each part at least 1: the same
+    sizes for every seed."""
+    if total < len(weights):
+        raise ValueError(f"{total} cannot fill {len(weights)} parts")
+    exact = np.asarray(weights, np.float64) * (total - len(weights))
+    counts = np.floor(exact).astype(np.int64)
+    left = total - len(weights) - int(counts.sum())
+    counts[np.argsort(counts - exact, kind="stable")[:left]] += 1
+    return counts + 1
+
+
+def fixed_draws(count: int, weights: np.ndarray) -> np.ndarray:
+    """``count`` draws from ``weights`` at the distribution's quantiles: the
+    same multiset for every seed, for the caller to put in a seeded order."""
+    u = (np.arange(count) + 0.5) / count
+    return np.minimum(np.searchsorted(np.cumsum(weights), u), len(weights) - 1)
+
+
+# -- folders ------------------------------------------------------------------
+
+
+def doc_folders(seed: int, docs: int, metadata: dict | None) -> np.ndarray:
+    """The folder (tenant) of every document, which it keeps through every
+    version: folder sizes are the Zipf shares of ``tenant_zipf_s`` (folder 0
+    the largest, the same sizes for every seed), and which documents a folder
+    holds is a seeded draw. No ``metadata`` key: one folder holds them all."""
+    if not metadata:
+        return np.zeros(docs, np.int32)
+    sizes = fixed_counts(docs, zipf_weights(int(metadata["tenants"]),
+                                            float(metadata["tenant_zipf_s"])))
+    folders = np.repeat(np.arange(len(sizes), dtype=np.int32), sizes)
+    return folders[stream(seed, 6).permutation(docs)]
 
 
 # -- encoder weights --------------------------------------------------------

@@ -38,6 +38,9 @@ for p in (ROOT, BENCH):
 from lib import check, datagen, peaks, spans as spans_mod, traffic  # noqa: E402
 
 NO_CHIP_EXIT = 3
+#: a scoped warm-up ends after this many sweeps even if the last still compiled
+WARM_SWEEPS = 4
+SCOPED_WARM_TIMEOUT_S = 600.0
 
 
 def say(event: str, **facts) -> None:
@@ -133,6 +136,19 @@ class Deployment:
                                spec["seconds"])
             if mix.get("writer") else None
         )
+        #: every document's folder and the template of a row's ``path``; with
+        #: no ``metadata`` in the configuration one folder and ``d<doc>``
+        meta = cfg.get("metadata") or {}
+        self.folder_of_doc = datagen.doc_folders(seed, self.docs, meta)
+        #: a document's path, made once: its rows' metadata share it
+        self.doc_paths = [
+            traffic.row_metadata(d, 0, f, meta.get("path", "d{doc}"))["path"]
+            for d, f in enumerate(self.folder_of_doc.tolist())]
+        self.scope = (
+            traffic.Scope(seed, mix["scope"], self.folder_of_doc, self.pool,
+                          int(mix["clients"]), self.chunks, self.plan)
+            if mix.get("scope") else None
+        )
         self.rows: np.ndarray | None = None
         self.state_dict: dict | None = None
         self.u = self.w = None
@@ -172,8 +188,8 @@ class Deployment:
         self.probe_share = float(norms[n_pool + best])
         # one passage row for each pool text, on rows the writer never touches
         banned = set(self.plan.touched) | set(self.plan.base_ladder) if self.plan else set()
-        order = rng.permutation(self.docs)
-        docs = [int(d) for d in order if int(d) not in banned][:len(self.pool.texts)]
+        free = [int(d) for d in rng.permutation(self.docs) if int(d) not in banned]
+        docs = self.scope.place_passages(free) if self.scope else free[:n_pool]
         chunk = rng.integers(0, self.chunks, size=len(docs))
         self.passage_rows = np.asarray(docs) * self.chunks + chunk
         self.rows[self.passage_rows] = datagen.near(
@@ -203,7 +219,7 @@ class Deployment:
             "id": np.asarray([d * self.chunks + c for d, c, _ in doc_chunk_ver],
                              dtype=np.int64),
             "data": [traffic.row_text(d, c, v) for d, c, v in doc_chunk_ver],
-            "_metadata": [{"path": f"d{d}", "ver": v} for d, _, v in doc_chunk_ver],
+            "_metadata": [{"path": self.doc_paths[d], "ver": v} for d, _, v in doc_chunk_ver],
             "vec": list(vecs),
         }
 
@@ -342,6 +358,15 @@ def plant_fault(name: str, engine, embedder) -> None:
     elif name == "stale":  # a step that returns its state unchanged
         engine.add_batch = lambda *a, **kw: None
         engine.remove = lambda *a, **kw: None
+    elif name == "ignore_scope":  # the engine is handed no filter at all
+        search = engine.search
+        engine.search = lambda queries, limits, filters: search(
+            queries, limits, [None] * len(filters))
+    elif name == "scope_short":  # a scoped reply loses its last row
+        search = engine.search
+        engine.search = lambda queries, limits, filters: [
+            hits[:-1] if f is not None else hits
+            for hits, f in zip(search(queries, limits, filters), filters)]
     elif name == "slow":
         # no planted fault: every search 1.5 s slower, as a dirty search is on
         # the chip, so that commits pile up behind a tick (PERF.md section 7)
@@ -377,10 +402,17 @@ def row_count(port: int):
     return body.get("file_count") if status == 200 and isinstance(body, dict) else None
 
 
-def warm_up(dep: Deployment, engine, embedder, port: int) -> dict:
-    """Every shape the cell's traffic can form: the embed forward and
-    ``topk_scores`` at 1..warm_batch_max queries (neither buckets the batch
-    axis), called as ``search`` calls them."""
+def warm_up(dep: Deployment, engine, embedder, port: int, compiles: Compiles) -> dict:
+    """Every shape the cell's traffic can form: the embed forward and, for
+    requests with no filter, ``topk_scores`` at 1..warm_batch_max queries
+    (neither buckets the batch axis), called as ``search`` calls them.
+
+    A scoped mix warms its filtered searches through the route: what the
+    program compiles for them is its own business. A request of every
+    client's, filter and all, is posted 1..warm_batch_max at a time, sweep after
+    sweep until one whole sweep hands the backend no program (``WARM_SWEEPS``
+    at the most: which requests share a tick is the program's to decide, so
+    one sweep may not form every batch)."""
     import jax
 
     from pathway_tpu.ops.knn import topk_scores
@@ -388,14 +420,38 @@ def warm_up(dep: Deployment, engine, embedder, port: int) -> dict:
     status, body = post(port, "/v1/retrieve", {"query": dep.pool.texts[0], "k": dep.k})
     if status != 200 or len(body) != dep.k:
         raise RuntimeError(f"first retrieve: status {status}: {str(body)[:200]}")
-    texts = dep.pool.texts
-    for q in range(1, int(dep.mix["warm_batch_max"]) + 1):
-        vecs = embedder.embed_texts_device(texts[:q])
-        out = topk_scores(vecs, engine._device, dep.k, engine.metric,
-                          valid=engine._device_valid)
+    texts, batch_max, scope = dep.pool.texts, int(dep.mix["warm_batch_max"]), dep.scope
+    unfiltered = scope is None or scope.share_unscoped > 0
+    for q in range(1, batch_max + 1):
+        out = embedder.embed_texts_device(texts[:q])
+        if unfiltered:
+            out = topk_scores(out, engine._device, dep.k, engine.metric,
+                              valid=engine._device_valid)
         jax.block_until_ready(out)
-    return {"embed_shapes": embedder._fwd._cache_size(),
-            "topk_shapes": topk_scores._cache_size()}
+    shapes = {"embed_shapes": embedder._fwd._cache_size(),
+              "topk_shapes": topk_scores._cache_size()}
+    if scope is None:
+        return shapes
+    bodies = []
+    for i in range(batch_max):
+        qids, folders = scope.client_requests(i % int(dep.mix["clients"]), 1)
+        folder = int(folders[0]) if folders[0] >= 0 else int(scope.home[qids[0]])
+        bodies.append({"query": texts[qids[0]], "k": dep.k, **scope.body_fields(folder)})
+    with ThreadPoolExecutor(batch_max) as posts:
+        for sweep in range(1, WARM_SWEEPS + 1):
+            t = time.monotonic()
+            for q in range(1, batch_max + 1):
+                for status, body in posts.map(
+                        lambda b: post(port, "/v1/retrieve", b, SCOPED_WARM_TIMEOUT_S),
+                        bodies[:q]):
+                    if status != 200:
+                        raise RuntimeError(f"scoped warm-up: status {status}: {str(body)[:200]}")
+            loaded = compiles.between(t, time.monotonic())["programs"]
+            log(f"scoped warm-up, sweep {sweep}: {time.monotonic() - t:.1f} s, "
+                f"{loaded} programs handed to the backend")
+            if not loaded:
+                break
+    return {**shapes, "warm_sweeps": sweep}
 
 
 def device_memory(jax) -> dict:
@@ -405,44 +461,67 @@ def device_memory(jax) -> dict:
 
 
 def run_reference(dep: Deployment, sample: list[dict], precision: str) -> dict:
-    """The reference's query vectors and best-first scores over the stable
-    rows for the sampled queries; with ``precision="fp8"`` also its own
-    answers, to be judged in the program's place (the control)."""
+    """The reference's query vectors (``vec[text]``) and, for each (text,
+    scope) the sample asks, its best-first scores and rows over the stable rows
+    of that scope (``top``); with ``precision="fp8"`` these are also its own
+    answers, to be judged in the program's place (the control).
+
+    A scope is a mask over the rows made from the seeded folder array, never a
+    filter string parsed. The scan goes over the rows the mask keeps, gathered
+    and padded with dead rows to whole blocks (one compiled shape, and a tenth
+    of the store costs a tenth of the scan)."""
     from lib import reference
 
     texts = sorted({r["query"] for r in sample})
     ids = reference.tokenize(texts, dep.vocab_index, dep.query_width)
     params = reference.to_device(dep.state_dict)
-    vec = reference.encode(params, ids, dep.model, precision)
+    vec = dict(zip(texts, reference.encode(params, ids, dep.model, precision)))
     del params
     live = np.ones(dep.n_rows, bool)
     unstable = sorted(dep.plan.touched) if dep.plan else []
     for doc in unstable:
         live[doc * dep.chunks:(doc + 1) * dep.chunks] = False
-    scores, rows = reference.scan_topk(vec, dep.rows, live, dep.k, precision)
-    return {"texts": texts, "vec": vec, "scores": scores, "rows": rows,
-            "unstable": set(unstable)}
+    asked: dict = {}
+    for r in sample:
+        asked.setdefault(r["scope"], set()).add(r["query"])
+    top = {}
+    for scope, of_scope in asked.items():
+        of_scope = sorted(of_scope)
+        q = np.stack([vec[t] for t in of_scope])
+        if scope is None:
+            scores, rows = reference.scan_topk(q, dep.rows, live, dep.k, precision)
+        else:
+            at = np.flatnonzero(dep.scope.mask(scope))
+            pad = -len(at) % datagen.BLOCK_ROWS
+            kept = np.zeros((len(at) + pad, dep.dim), np.float32)
+            np.take(dep.rows, at, axis=0, out=kept[:len(at)])
+            scores, rows = reference.scan_topk(
+                q, kept, np.concatenate([live[at], np.zeros(pad, bool)]), dep.k,
+                precision, block=datagen.BLOCK_ROWS)
+            del kept
+            rows = np.concatenate([at, np.full(pad, -1)])[rows]
+        for t, sc, ro in zip(of_scope, scores, rows):
+            top[(t, scope)] = (sc, ro)
+    return {"vec": vec, "top": top, "unstable": set(unstable)}
 
 
 def judge(dep: Deployment, sample: list[dict], control: bool) -> dict:
     ref = run_reference(dep, sample, "float32")
-    ref_vec = dict(zip(ref["texts"], ref["vec"]))
-    ref_top = dict(zip(ref["texts"], ref["scores"]))
+    ref_top = {key: scores for key, (scores, _) in ref["top"].items()}
     gap, err, bad = check.compare_sample(
-        sample, ref_vec, ref_top, dep.row_vector, ref["unstable"])
+        sample, ref["vec"], ref_top, dep.row_vector, ref["unstable"])
     out = {"rank_gap": gap, "score_err": err, "bad_rows": bad,
-           "sampled": len(sample), "queries": len(ref["texts"])}
+           "sampled": len(sample), "queries": len(ref["vec"])}
     if control:
         ctl = run_reference(dep, sample, "fp8")
-        by_text = {t: i for i, t in enumerate(ctl["texts"])}
         answers = []
         for r in sample:
-            i = by_text[r["query"]]
-            answers.append({"query": r["query"], "rows": [
+            scores, rows = ctl["top"][(r["query"], r["scope"])]
+            answers.append({"query": r["query"], "scope": r["scope"], "rows": [
                 (int(row) // dep.chunks, int(row) % dep.chunks, 0, float(s))
-                for s, row in zip(ctl["scores"][i], ctl["rows"][i])]})
+                for s, row in zip(scores, rows) if np.isfinite(s)]})
         cgap, cerr, _ = check.compare_sample(
-            answers, ref_vec, ref_top, dep.row_vector, ref["unstable"])
+            answers, ref["vec"], ref_top, dep.row_vector, ref["unstable"])
         out["control"] = {"precision": "fp8", "rank_gap": cgap, "score_err": cerr}
     return out
 
@@ -513,7 +592,7 @@ def main() -> int:
         if len(engines) != 1:
             raise RuntimeError(f"expected one index engine, found {len(engines)}")
         engine = engines.pop()
-        shapes = warm_up(dep, engine, embedder, spec["port"])
+        shapes = warm_up(dep, engine, embedder, spec["port"], compiles)
         wrap_layers(engine, embedder, rec)
         plant_fault(spec.get("fault", ""), engine, embedder)
         resident = device_memory(jax)

@@ -11,11 +11,14 @@ standard error or into ``benchmark/out/``.
 
 A cell, a configuration, a traffic mix, a per-layer metric and a cell's limits
 are files found by name (``find``); ``BENCHMARK.json`` names them.
+A configuration may put its documents in folders (``metadata``) and a mix may
+confine its requests to them (``scope``): ``lib/traffic.py`` has the keys.
 """
 
 from __future__ import annotations
 
 import argparse
+import gc
 import importlib.util
 import json
 import os
@@ -35,7 +38,7 @@ if HERE not in sys.path:
 
 import numpy as np  # noqa: E402
 
-from lib import check, datagen, loadgen, traffic, workcount  # noqa: E402
+from lib import check, datagen, loadgen, traffic  # noqa: E402
 
 REFUSED_EXIT = 4
 READY_TIMEOUT_S = 1150.0
@@ -181,11 +184,16 @@ def percentile(values: list[float], q: float) -> float:
     return float(np.percentile(np.asarray(values, dtype=np.float64), q))
 
 
-def reader_queries(pool, client: int):
-    """``next_query`` of one closed-loop client: (query id, text) in the order
-    it sends them. The ids are drawn here, before the window opens."""
-    ids = pool.client_sequence(client).tolist()
-    return ((q, pool.texts[q]) for q in ids).__next__
+def reader_queries(pool, scope, client: int):
+    """``next_query`` of one closed-loop client: (query id, text, folder) in
+    the order it sends them; the folder is None where the request carries no
+    filter. All are drawn here, before the window opens."""
+    if scope is None:
+        ids = pool.client_sequence(client).tolist()
+        return ((q, pool.texts[q], None) for q in ids).__next__
+    ids, folders = (a.tolist() for a in scope.client_requests(client))
+    return ((q, pool.texts[q], f if f >= 0 else None)
+            for q, f in zip(ids, folders)).__next__
 
 
 def draw_sample(records: list[dict], pool_tokens, count: int, seed: int) -> list[dict]:
@@ -264,6 +272,10 @@ def drive(args, loaded: dict, spec: dict, child: Child, tail: list[str]) -> int:
     pool = traffic.QueryPool(seed, words, mix["query"])
     plan = (traffic.WriterPlan(seed, mix["writer"], cfg["rows"] // chunks, chunks, seconds)
             if mix.get("writer") else None)
+    folder_of_doc = datagen.doc_folders(seed, cfg["rows"] // chunks, cfg.get("metadata"))
+    scope = (traffic.Scope(seed, mix["scope"], folder_of_doc, pool, int(mix["clients"]),
+                           chunks, plan)
+             if mix.get("scope") else None)
 
     try:
         ready = child.wait_event("ready", READY_TIMEOUT_S)
@@ -271,31 +283,45 @@ def drive(args, loaded: dict, spec: dict, child: Child, tail: list[str]) -> int:
         code = child.proc.poll()
         print(f"benchmark: {e}", file=sys.stderr)
         return code if code else 1
-    setup_s = time.monotonic() - T_START
     probe_text = ready["probe_text"]
-    log(f"ready after {setup_s:.1f} s: {json.dumps(ready)}")
+    log(f"ready after {time.monotonic() - T_START:.1f} s: {json.dumps(ready)}")
 
-    # -- the window
-    t0 = time.monotonic() + 0.25
+    # -- the window. The callers start ``ramp_s`` before it opens: sixteen first
+    # requests sent at one instant make one tick of one query and one of
+    # fifteen, a state the closed loop takes a second or three to leave, and the
+    # window measures the loop that has settled. The ramp is set-up.
+    ramp = float(mix.get("ramp_s", 0.0))
+    t0 = time.monotonic() + 0.25 + ramp
     t_end = t0 + seconds
+    setup_s = t0 - T_START
     child.send(cmd="window", t0=t0, seconds=seconds)
     readers = []
     for c in range(int(mix["clients"])):
         readers.append(loadgen.Client(
-            f"r{c}", port, mix["route"], k, t0,
-            reader_queries(pool, c),
-            lambda: time.monotonic() >= t_end,
+            f"r{c}", port, mix["route"], k, t0 - ramp,
+            reader_queries(pool, scope, c),
+            lambda: time.monotonic() >= t_end, scope,
         ))
     probes, probe_state = [], {"done": False}
     for p in range(int(mix.get("probes", 0))):
         probes.append(loadgen.Client(
-            f"p{p}", port, mix["route"], k, t0 + p * 0.05,
-            lambda: (-1, probe_text),
+            f"p{p}", port, mix["route"], k, t0 - ramp + p * 0.05,
+            lambda: (-1, probe_text, None),
             lambda: probe_state["done"] or time.monotonic() >= t_end + AFTER_CLOSE_S,
         ))
     for c in probes:
         c.start()
-    records = loadgen.run_clients(readers, seconds + loadgen.REQUEST_TIMEOUT_S + 30)
+    # the generator's own full collections (two a window, 12-18 ms each over the
+    # records it keeps) stopped all sixteen callers at once, and the loop fell
+    # back into the one-and-fifteen state for up to two seconds each time
+    # (PERF.md section 6, PR 32): none while callers run
+    gc.collect()
+    gc.disable()
+    try:
+        records = loadgen.run_clients(
+            readers, ramp + seconds + loadgen.REQUEST_TIMEOUT_S + 30)
+    finally:
+        gc.enable()
     log(f"readers done: {len(records)} requests")
     seen: list[float | None] = []
     if plan is not None:
@@ -322,7 +348,8 @@ def drive(args, loaded: dict, spec: dict, child: Child, tail: list[str]) -> int:
     sample += draw_sample(probe_records, pool.tokens, int(mix["sample_requests"]) // 4, seed + 1)
     sample_path = os.path.join(out_dir, "sample.json")
     with open(sample_path, "w") as f:
-        json.dump([{"query": r["query"], "rows": r["rows"]} for r in sample], f)
+        json.dump([{"query": r["query"], "scope": r["scope"], "rows": r["rows"]}
+                   for r in sample], f)
     child.send(cmd="check", sample=sample_path)
     verdict = child.wait_event("checked", 300.0)
 
@@ -339,6 +366,9 @@ def drive(args, loaded: dict, spec: dict, child: Child, tail: list[str]) -> int:
     }
     if not sample:
         numbers["rank_gap"] = numbers["score_err"] = float("inf")
+    if scope is not None:
+        numbers["out_of_scope"] = float(check.out_of_scope(
+            [r for r in everything if r["rows"] is not None], folder_of_doc))
     lost = 0
     if plan is not None:
         lost = sum(s is None for s in seen)
@@ -392,7 +422,7 @@ def drive(args, loaded: dict, spec: dict, child: Child, tail: list[str]) -> int:
             + ", ".join(f"{d * 1e3:.0f} ms at {at:.1f} s" for d, at in quiet))
     with open(os.path.join(out_dir, "requests.jsonl"), "w") as f:
         for r in everything:
-            f.write(json.dumps({"client": r["client"], "qid": r["qid"],
+            f.write(json.dumps({"client": r["client"], "qid": r["qid"], "scope": r["scope"],
                                 "send": r["send"] - t0, "recv": r["recv"] - t0,
                                 "status": r["status"], "ok": r["rows"] is not None}) + "\n")
 
@@ -427,10 +457,18 @@ def drive(args, loaded: dict, spec: dict, child: Child, tail: list[str]) -> int:
         counts = {
             "compiles_in_window": facts["compiles_in_window"]["compiled"],
             "traced_requests": len(traced),
-            "traced_flops": sum(workcount.retrieve_flops(
-                t, live_rows, cfg["hidden_size"], cfg) for t in tokens),
+            "traced_tokens": tokens,
             "live_rows": live_rows,
+            # send to full reply of every request of the window, as the
+            # end-to-end percentiles take them (a failed one the worst)
+            "window_latency_ms": [x * 1e3 for x in lat_all],
         }
+        if scope is not None:
+            # the live rows a traced request was confined to (of the store,
+            # where it carried no filter)
+            counts["traced_scope_rows"] = [
+                live_rows if r["scope"] is None else int(scope.rows_in[r["scope"]])
+                for r in traced]
         cell_facts = {"name": name, "config": cfg, "mix": mix, "chip": facts["chip"],
                       "window": [t0, t_end], "trace_window": tw}
         for m in metrics_of(manifest, "per_layer", name):

@@ -9,6 +9,7 @@ import json
 import os
 
 import harness
+import pytest
 from lib import workcount_write
 
 LIVE = "tiny-bert.live-upsert-c4"
@@ -52,6 +53,12 @@ def test_a_traced_live_run_reads_the_writing_tick(tmp_path):
     assert not set(got) & {"index_write_device_ms", "index_write_roofline"}
 
 
-def test_write_bytes_by_hand():
-    # 8 slots of width 768: 8 rows in and 8 written at 3,072 B, 16 mask bytes
-    assert workcount_write.index_write_bytes(8, 768) == 8 * 3072 * 2 + 16 == 49168
+@pytest.mark.parametrize("metric,expect", [
+    # 8 slots of width 768: 8 float32 rows in at 3,072 B, written as bfloat16
+    # at 1,536 B (the device copy of cos and ip), 16 mask bytes
+    ("cos", 8 * (3072 + 1536) + 16), ("ip", 36880),
+    # l2 keeps a float32 copy: written at 3,072 B, the count of before PR 32
+    ("l2", 8 * 3072 * 2 + 16),
+])
+def test_write_bytes_by_hand(metric, expect):
+    assert workcount_write.index_write_bytes(8, 768, metric) == expect

@@ -43,6 +43,43 @@ def test_retrieve_flops_is_encoder_over_real_tokens_plus_scan():
     assert got == pytest.approx(7.30e9, rel=5e-3)
 
 
+def test_scoped_counts_by_hand_at_one_small_shape():
+    # 3 queries of one search over a store of 1,000 live rows of width 64, k = 10:
+    # scopes of 100, 100 (the same folder) and 300 rows, so the union is 400
+    flops, nbytes = workcount.scoped_topk_scores_work(3, 100 + 100 + 300, 400, 64, 10)
+    assert flops == 2 * 64 * 500 == 64_000
+    assert nbytes == 400 * 64 * 2 + 3 * 64 * 4 + 3 * 10 * 8 == 52_208
+    # a scope that is the whole store is the old count: which returns what it did
+    assert workcount.scoped_topk_scores_work(3, 3 * 1000, 1000, 64, 10) == \
+        workcount.topk_scores_work(3, 1000, 64, 10) == (384_000.0, 129_008.0)
+    model = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2)
+    assert workcount.scoped_retrieve_flops(8, 100, 64, model) == \
+        8 * workcount.encoder_flops_per_token(model, 8) + 2 * 100 * 64
+    assert workcount.scoped_retrieve_flops(8, 1000, 64, model) == \
+        workcount.retrieve_flops(8, 1000, 64, model)
+
+
+@pytest.mark.parametrize("program_reads", ["every row under a mask", "only the scopes"])
+def test_a_scoped_share_cannot_pass_100(program_reads):
+    # the large widths, 16 queries each in a folder of a tenth of 1.2M rows;
+    # the layer reader counts the union as ONE mean scope, its lower bound
+    n, d, k, q, scope = 1_200_000, 1024, 10, 16, 120_000
+    flops, nbytes = workcount.scoped_topk_scores_work(q, q * scope, scope, d, k)
+    least, bound = workcount.least_time(flops, nbytes, V5E)
+    assert bound == "memory"  # 0.30 ms for the rows against 0.02 ms of products
+    # the fastest a program could be: its own bytes at the chip's full bandwidth
+    # (every row once under a mask; or each folder's rows once, all 16 distinct)
+    rows_read = n if program_reads == "every row under a mask" else q * scope
+    device = max((rows_read * d * 2 + q * d * 4 + q * k * 8) / V5E["hbm_bytes_per_s"],
+                 flops / V5E["bf16_flops"])
+    share = 100 * least / device
+    assert share <= 100
+    assert share == pytest.approx(10.0 if rows_read == n else 6.25, rel=2e-3)
+    # and only a program that reads one shared folder once reaches the whole of it
+    one_folder = (scope * d * 2 + q * d * 4 + q * k * 8) / V5E["hbm_bytes_per_s"]
+    assert 100 * least / one_folder == pytest.approx(100.0)
+
+
 def test_unknown_device_kind_is_an_error():
     with pytest.raises(KeyError):
         peaks.peaks_for("TPU v9 imaginary")
