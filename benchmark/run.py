@@ -13,6 +13,13 @@ A cell, a configuration, a traffic mix, a per-layer metric and a cell's limits
 are files found by name (``find``); ``BENCHMARK.json`` names them.
 A configuration may put its documents in folders (``metadata``) and a mix may
 confine its requests to them (``scope``): ``lib/traffic.py`` has the keys.
+
+A run owns the whole life of the child. It ends in one of four ways, and each
+leaves no process of the run alive: with a result line (exit 0); by a signal
+(``SIGTERM``, ``SIGINT``, ``SIGHUP``: exit 128 + its number); by its own
+deadline (``SETUP_LIMIT_S``, ``RUN_LIMIT_S``: exit 5); or because the child
+ended first (its code, or 1; its reason is the last line of standard error).
+Killed outright, it is followed by the child (``server_proc.die_with_parent``).
 """
 
 from __future__ import annotations
@@ -41,12 +48,80 @@ import numpy as np  # noqa: E402
 from lib import check, datagen, loadgen, traffic  # noqa: E402
 
 REFUSED_EXIT = 4
-READY_TIMEOUT_S = 1150.0
+DEADLINE_EXIT = 5
+# The two limits of a run, from this process's start; its waits on the child
+# and on the callers have no other. They are one timer (``Life.arm``), so they cut
+# a wait wherever the main thread is: on the child, on the callers, on the probes.
+# - Set-up, to the child's ``ready``: twice the slowest on record, the large
+#   cell's first run in a checkout, which compiles (``first_setup_s`` 326.73,
+#   ledger, PR 29): 2 x 326.73 = 653, rounded up. A warm set-up is 64-86 s.
+# - The whole run: the contract gives a cell's first run in a checkout 1,200 s
+#   (a later one 360 s) and ``README.md``'s command by hand 1,500 s; 50 s under
+#   the lesser, for the kill, the last lines and the interpreter's exit. After
+#   the slowest set-up allowed that leaves 450 s for ramp, window (51 s), the
+#   minute the probes may wait, close and reference (up to 30 s, README).
+SETUP_LIMIT_S = 700.0
+RUN_LIMIT_S = 1150.0
+#: a child that has said ``checked`` ends by itself; so long is it given
+CHILD_EXIT_GRACE_S = 20.0
 AFTER_CLOSE_S = 60.0
+ENDING_SIGNALS = (signal.SIGTERM, signal.SIGINT, signal.SIGHUP)
 
 
 def log(msg: str) -> None:
     print(f"[run {time.monotonic() - T_START:7.1f}] {msg}", file=sys.stderr, flush=True)
+
+
+class Ending(BaseException):
+    """The run ends itself: a signal from outside, or its own deadline."""
+
+    def __init__(self, code: int, why: str):
+        super().__init__(why)
+        self.code = code
+
+
+class ChildEnded(RuntimeError):
+    """The child ended before it said what the run was waiting for."""
+
+
+class Life:
+    """How a run ends itself: the stage it is in, the one timer that is its
+    deadline, and the signals that end it. Each raises ``Ending`` into the main
+    thread, wherever that is waiting, so ``main`` clears up on every path."""
+
+    def __init__(self):
+        self.stage = "set-up"
+        self.armed = ""  # the limit the timer stands for, as its line names it
+
+    def enter(self, stage: str) -> None:
+        """Name the stage the run is in, for the line an ending prints."""
+        self.stage = stage
+        log(f"stage: {stage}")
+
+    def arm(self, name: str, limit_s: float) -> None:
+        """(Re)set the timer: the run ends ``limit_s`` after it started."""
+        self.armed = f"{name} = {limit_s:.0f} s"
+        signal.setitimer(signal.ITIMER_REAL,
+                         max(T_START + limit_s - time.monotonic(), 0.001))
+
+    def take(self) -> None:
+        """From here a signal or the timer ends the run."""
+        for s in ENDING_SIGNALS:
+            signal.signal(s, self._on_signal)
+        signal.signal(signal.SIGALRM, self._on_deadline)
+
+    def release(self) -> None:
+        """The run has its result, or an ending is under way: nothing from
+        outside interrupts the clearing up, or turns a result into a loss."""
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        for s in (*ENDING_SIGNALS, signal.SIGALRM):
+            signal.signal(s, signal.SIG_IGN)
+
+    def _on_signal(self, signum, frame) -> None:
+        raise Ending(128 + signum, f"stopped by {signal.Signals(signum).name}")
+
+    def _on_deadline(self, signum, frame) -> None:
+        raise Ending(DEADLINE_EXIT, f"the run met its deadline ({self.armed})")
 
 
 def find(kind: str, name: str, ext: str, manifest_dir: str) -> str:
@@ -147,29 +222,34 @@ class Child:
             self._eof = True
             self._cv.notify_all()
 
-    def wait_event(self, name: str, timeout: float) -> dict:
-        t_end = time.monotonic() + timeout
+    def wait_event(self, name: str) -> dict:
+        """Until the child says ``name`` or ends; the run's deadline and a
+        signal raise into this wait (``Ending``), so it has no limit of its own."""
         with self._cv:
             while True:
                 for ev in self.events:
                     if ev["event"] == name:
                         return ev
                 if self._eof:
-                    raise RuntimeError(
-                        f"the child ended (exit {self.proc.wait()}) before {name!r}")
-                left = t_end - time.monotonic()
-                if left <= 0:
-                    raise RuntimeError(f"no {name!r} from the child in {timeout:.0f} s")
-                self._cv.wait(left)
+                    why = [ev["reason"] for ev in self.events if ev["event"] == "failed"]
+                    raise ChildEnded(
+                        f"the child ended (exit {self.proc.wait()}) before {name!r}"
+                        + (f": {why[-1]}" if why else ""))
+                self._cv.wait()
 
     def send(self, **cmd) -> None:
-        self.proc.stdin.write(json.dumps(cmd) + "\n")
-        self.proc.stdin.flush()
-
-    def stop(self) -> int | None:
-        """End the child and anything it started; wait until it has ended."""
         try:
-            code = self.proc.wait(timeout=20)
+            self.proc.stdin.write(json.dumps(cmd) + "\n")
+            self.proc.stdin.flush()
+        except BrokenPipeError:
+            pass  # the child has ended: the wait that follows says so, and why
+
+    def stop(self, grace_s: float) -> int | None:
+        """End the child and anything it started; wait until it has ended. A
+        child that is finishing by itself is given ``grace_s``; None where it
+        had to be killed."""
+        try:
+            code = self.proc.wait(timeout=grace_s)
         except subprocess.TimeoutExpired:
             code = None
         try:
@@ -243,24 +323,55 @@ def main() -> int:
         "seconds": args.seconds, "trace": args.trace, "control": args.control,
         "chips": cell["chips"], "port": port, "out_dir": out_dir,
         "keep_trace": args.keep_trace, "fault": args.fault,
+        # the child ends with this process (``server_proc.die_with_parent``)
+        "parent_pid": os.getpid(),
     }
     spec_path = os.path.join(out_dir, "spec.json")
     with open(spec_path, "w") as f:
         json.dump(spec, f)
 
-    child = Child(spec_path)
-    tail: list[str] = []
+    life = Life()
+    life.take()
+    life.arm("SETUP_LIMIT_S", SETUP_LIMIT_S)
+    child, done, code, last = None, None, 1, None
     try:
-        return drive(args, loaded, spec, child, tail)
+        child = Child(spec_path)
+        with open(os.path.join(out_dir, "child.pid"), "w") as f:
+            f.write(f"{child.proc.pid}\n")
+        log(f"child started: pid {child.proc.pid} (out/{name}/child.pid), its own session")
+        done = drive(args, loaded, spec, child, life)
+        life.release()
+        log("the run has its result; the child is ending by itself")
+        child.stop(CHILD_EXIT_GRACE_S)
+        code = 0
+    except Ending as e:
+        # also one that came between the result and ``release``: a run is a
+        # result or a loss, never both
+        code, done = e.code, None
+        last = f"benchmark: {e} in stage {life.stage!r}; the child is killed, no result"
+    except ChildEnded as e:
+        code = child.proc.poll() or 1
+        last = f"benchmark: {e}"
     finally:
-        code = child.stop()
-        log(f"child ended (exit {code})")
-        # the numbers compared, each beside its limit, end standard error
-        if tail:
-            print("\n".join(tail), file=sys.stderr, flush=True)
+        # on every path but the one above the child's session is killed at once
+        life.release()
+        if child is not None:
+            log(f"child ended (exit {child.stop(0)})")
+    # the numbers compared, each beside its limit, end standard error; where
+    # there are none, the reason the run gave no result does. The result line
+    # comes when nothing of the run is left, and only with exit 0.
+    if done is not None:
+        result, tail = done
+        print("\n".join(tail), file=sys.stderr, flush=True)
+        print(json.dumps(result), flush=True)
+    if last:
+        print(last, file=sys.stderr, flush=True)
+    return code
 
 
-def drive(args, loaded: dict, spec: dict, child: Child, tail: list[str]) -> int:
+def drive(args, loaded: dict, spec: dict, child: Child, life: Life) -> tuple[dict, list[str]]:
+    """The run from the child's start to its verdict: the result line's object,
+    and the numbers compared, each beside its limit, as lines."""
     manifest, cell, cfg, mix, limits = (
         loaded[k] for k in ("manifest", "cell", "config", "mix", "limits"))
     name, seed, seconds, port = cell["name"], args.seed, args.seconds, spec["port"]
@@ -277,14 +388,11 @@ def drive(args, loaded: dict, spec: dict, child: Child, tail: list[str]) -> int:
                            chunks, plan)
              if mix.get("scope") else None)
 
-    try:
-        ready = child.wait_event("ready", READY_TIMEOUT_S)
-    except RuntimeError as e:
-        code = child.proc.poll()
-        print(f"benchmark: {e}", file=sys.stderr)
-        return code if code else 1
+    ready = child.wait_event("ready")
     probe_text = ready["probe_text"]
     log(f"ready after {time.monotonic() - T_START:.1f} s: {json.dumps(ready)}")
+    life.enter("window")
+    life.arm("RUN_LIMIT_S", RUN_LIMIT_S)
 
     # -- the window. The callers start ``ramp_s`` before it opens: sixteen first
     # requests sent at one instant make one tick of one query and one of
@@ -318,8 +426,8 @@ def drive(args, loaded: dict, spec: dict, child: Child, tail: list[str]) -> int:
     gc.collect()
     gc.disable()
     try:
-        records = loadgen.run_clients(
-            readers, ramp + seconds + loadgen.REQUEST_TIMEOUT_S + 30)
+        # every caller ends by its own ``until`` and its request's timeout
+        records = loadgen.run_clients(readers)
     finally:
         gc.enable()
     log(f"readers done: {len(records)} requests")
@@ -336,8 +444,9 @@ def drive(args, loaded: dict, spec: dict, child: Child, tail: list[str]) -> int:
         for c in probes:
             c.join(timeout=loadgen.REQUEST_TIMEOUT_S)
     probe_records = sorted((r for c in probes for r in c.records), key=lambda r: r["send"])
+    life.enter("close")
     child.send(cmd="close")
-    child.wait_event("closed", 240.0)
+    child.wait_event("closed")
     facts = load_json(os.path.join(out_dir, "child_facts.json"))
 
     # -- correctness: the sample goes to the child, which runs the reference
@@ -350,8 +459,9 @@ def drive(args, loaded: dict, spec: dict, child: Child, tail: list[str]) -> int:
     with open(sample_path, "w") as f:
         json.dump([{"query": r["query"], "scope": r["scope"], "rows": r["rows"]}
                    for r in sample], f)
+    life.enter("check")
     child.send(cmd="check", sample=sample_path)
-    verdict = child.wait_event("checked", 300.0)
+    verdict = child.wait_event("checked")
 
     bad = [r for r in everything if r["rows"] is None]
     if bad:
@@ -491,10 +601,8 @@ def drive(args, loaded: dict, spec: dict, child: Child, tail: list[str]) -> int:
             f"{json.dumps(verdict['control'])}")
         result["control"] = verdict["control"]
     result["compared"] = compared  # last: the numbers compared, beside their limits
-    tail += [f"compared {n} = {v} (limit {lim})" for n, v, lim in table]
-    tail.append(f"correct = {correct}")
-    print(json.dumps(result), flush=True)
-    return 0
+    tail = [f"compared {n} = {v} (limit {lim})" for n, v, lim in table]
+    return result, [*tail, f"correct = {correct}"]
 
 
 if __name__ == "__main__":
