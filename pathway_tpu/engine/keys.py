@@ -924,9 +924,12 @@ def recurring(keys: KeyArray) -> np.ndarray | None:
     return np.bincount(slots)[slots] > 1
 
 
-def derive(keys: KeyArray, salt: int) -> KeyArray:
-    """Derive child keys from parent keys (concat_reindex, flatten branches)."""
-    return _splitmix(keys ^ _splitmix(np.full(len(keys), np.uint64(salt), dtype=np.uint64)))
+def derive(keys: KeyArray, salt: int | KeyArray) -> KeyArray:
+    """Derive child keys from parent keys (concat_reindex, flatten branches).
+    ``salt`` is one salt for every key or a uint64 array of one salt a key."""
+    if not isinstance(salt, np.ndarray):
+        salt = np.full(len(keys), np.uint64(salt), dtype=np.uint64)
+    return _splitmix(keys ^ _splitmix(salt))
 
 
 def derive_pair(left: KeyArray, right: KeyArray) -> KeyArray:

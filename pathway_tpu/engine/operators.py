@@ -3053,7 +3053,17 @@ def _emit_resolved_diffs(node: Node, affected: dict[int, None], old: dict[int, t
 
 class Flatten(Node):
     """flatten (table.py:2089): explode an iterable column into rows with
-    derived keys mix(parent_key, position). Stateless — diffs propagate."""
+    derived keys mix(parent_key, position). Stateless — diffs propagate.
+
+    A delta is exploded by column: one pass listifies the cells, then keys,
+    diffs and passenger columns are repeated by the cells' lengths in one
+    numpy step each. Nothing here runs once per item."""
+
+    #: the dtypes ``column_of_values`` gives back unchanged when handed a
+    #: dense column's own cells
+    _ROUND_TRIP_DTYPES = (
+        np.dtype(np.int64), np.dtype(np.float64), np.dtype(np.bool_),
+    )
 
     def __init__(self, inp: Node, flatten_col: str):
         super().__init__([inp], inp.column_names)
@@ -3063,25 +3073,20 @@ class Flatten(Node):
         d = ins[0]
         if d is None or not len(d):
             return None
-        keys_out: list[int] = []
-        rows_out: list[tuple] = []
-        diffs_out: list[int] = []
-        names = self.column_names
-        flat_ix = names.index(self._col)
-        arrs = [d.data[c] for c in names]
-        for i in range(len(d)):
-            value = arrs[flat_ix][i]
-            items = None
+        items: list = []
+        lengths: list[int] = []
+        for value in d.data[self._col]:
+            cell = None
             if value is not None and not isinstance(value, EngineError):
                 try:
                     # listifying (not hasattr __iter__) also catches
                     # wrappers whose __iter__ fails at runtime, e.g. a
                     # scalar pw.Json — Json.__iter__ exists but iter(42)
                     # inside it raises
-                    items = list(value)
+                    cell = list(value)
                 except TypeError:
-                    items = None
-            if items is None:
+                    pass
+            if cell is None:
                 # a row whose flatten column holds Error/None/any
                 # non-iterable cannot explode; log and skip instead of
                 # crashing the run (reference flatten error-row semantics)
@@ -3089,20 +3094,33 @@ class Flatten(Node):
                     "non-iterable value in flatten column; row skipped",
                     "flatten",
                 )
-                continue
-            base = tuple(a[i] for a in arrs)
-            parent = np.array([d.keys[i]], dtype=np.uint64)
-            for pos, item in enumerate(items):
-                keys_out.append(int(K.derive(parent, pos * 2 + 0x7)[0]))
-                rows_out.append(base[:flat_ix] + (item,) + base[flat_ix + 1 :])
-                diffs_out.append(int(d.diffs[i]))
-        if not keys_out:
+                cell = ()
+            lengths.append(len(cell))
+            items += cell
+        if not items:
             return None
-        return Delta(
-            keys=np.array(keys_out, dtype=np.uint64),
-            data=rows_to_columns(rows_out, names),
-            diffs=np.array(diffs_out, dtype=np.int64),
+        counts = np.array(lengths, dtype=np.int64)
+        pos = np.arange(len(items)) - np.repeat(np.cumsum(counts) - counts, counts)
+        # the salt of item ``pos`` of a cell is pos * 2 + 0x7: child keys are
+        # row ids downstream (snapshots, origin_id joins) and must not change
+        keys = K.derive(
+            np.repeat(d.keys, counts), (pos * 2 + 0x7).astype(np.uint64)
         )
+        data = {}
+        for name in self.column_names:
+            if name == self._col:
+                data[name] = column_of_values(items)
+                continue
+            col = np.repeat(d.data[name], counts)
+            # a passenger column keeps the dtype ``column_of_values`` gives its
+            # cells: an int64, float64 or bool column as it is, any other
+            # through it (an object column of uniform ints comes out dense,
+            # a uint64 column of keys as python ints)
+            data[name] = (
+                col if col.dtype in self._ROUND_TRIP_DTYPES
+                else column_of_values(col.tolist())
+            )
+        return Delta(keys=keys, data=data, diffs=np.repeat(d.diffs, counts))
 
 
 def _split_temporal_state(cls, state: dict, key_mask) -> dict:
