@@ -12,13 +12,32 @@ shape for the MXU), mirroring the reference's Tantivy choice of CPU.
 
 from __future__ import annotations
 
+import collections
 import math
 import re
 from typing import Any, Callable
 
 import numpy as np
 
-from ..utils.filters import compile_metadata_filter
+from ..utils.filters import (
+    compile_metadata_filter,
+    eval_filter_columns,
+    lookup_path,
+    parse_metadata_filter,
+)
+
+#: bytes of filter masks a ``BruteForceKnnEngine`` keeps on the device, the
+#: least recently used dropped first. A mask is one byte a slot, so 128 MiB
+#: hold 111 filters' masks at 1,200,000 slots and 64 at 2,048,000: under one
+#: per cent of a chip's 16 GiB for more folders than a tick's callers ask.
+MASK_CACHE_BYTES = 128 << 20
+#: the fewest queries a search that carries a filter is scanned as. Every batch
+#: size is a program of its own: the sizes 8, 16, 32, ... are few enough that a
+#: server has met them all soon after its first filtered requests, where 1, 2
+#: and 4 turn up late and singly. At 1,200,000 x 1024 a scan of eight takes
+#: the device no longer than a scan of one, and their masks 0.35 ms more to
+#: stack (PERF.md section 6, PR 36)
+SCOPED_BATCH_MIN = 8
 
 __all__ = [
     "BruteForceKnnEngine",
@@ -74,6 +93,27 @@ class _SlotArena:
         return slot
 
 
+class _MetaColumn:
+    """One metadata path over the slots, dictionary-encoded: slot i reads
+    ``values[codes[i]]``, and code 0 is None (no metadata, no such key). Two
+    values share a code only where every construct of the filter grammar
+    reads them alike: equal strings, or one type and one ``repr``."""
+
+    def __init__(self, capacity: int):
+        self.codes = np.zeros(capacity, np.int32)
+        self.values: list = []
+        self._code: dict = {}
+        self.encode(None)
+
+    def encode(self, value: Any) -> int:
+        key = value if type(value) is str else (type(value), repr(value))
+        code = self._code.get(key)
+        if code is None:
+            code = self._code[key] = len(self.values)
+            self.values.append(value)
+        return code
+
+
 class BruteForceKnnEngine:
     """Exact KNN on TPU: the index block is one [capacity, dim] device array.
 
@@ -88,6 +128,20 @@ class BruteForceKnnEngine:
     search, after ``_grow`` (a new capacity tier, so the scan and the write
     programs compile once more — all of them at that placement, none later),
     after unpickling, and when ``_dirty`` was set with nothing staged.
+
+    A filtered query is an unfiltered one plus a mask the engine already
+    holds. Metadata is kept by column for the paths filters have named
+    (``_columns``, built in one pass over the slots when a path is first
+    named, kept current by ``add`` and ``_bulk_add``); a filter's mask over
+    the slots, its rows among the live ones, is evaluated from them once and
+    cached on the device under the filter's source (``_masks``, bounded by
+    ``MASK_CACHE_BYTES``); an in-place write evaluates each cached mask at the
+    written slots only and writes it in place, and whatever drops the device
+    copy drops the masks with it. A search stacks its queries' masks to
+    ``valid`` [q, n] and scans once; its query axis is padded to a power of
+    two, ``SCOPED_BATCH_MIN`` at the least, so that a deployment's filtered
+    searches are two or three programs whatever batches its ticks form. A
+    store that is never sent a filter builds none of this.
     """
 
     def __init__(self, dimensions: int, *, metric: str = "cos",
@@ -106,12 +160,17 @@ class BruteForceKnnEngine:
         #: slots written on the host since the device copy was last current
         #: (ints and arrays); empty while there is no device copy
         self._staged: list = []
+        #: metadata path -> its column, for the paths filters have named
+        self._columns: dict[tuple[str, ...], _MetaColumn] = {}
+        #: filter source -> (parsed tree, device mask [capacity] of the live
+        #: rows it keeps), least recently used first
+        self._masks: collections.OrderedDict = collections.OrderedDict()
 
-    # operator snapshots pickle the whole engine; the device mirror is a
-    # cache rebuilt on first search after restore
+    # operator snapshots pickle the whole engine; the device mirror and what
+    # is derived from the metadata are caches rebuilt after restore
     def __getstate__(self):
         st = dict(self.__dict__)
-        for name in ("_device", "_device_valid", "_staged"):
+        for name in ("_device", "_device_valid", "_staged", "_columns", "_masks"):
             st.pop(name, None)
         # the embedder may be an arbitrary closure (not picklable); the
         # restoring node grafts the freshly-constructed engine's embedder back
@@ -123,6 +182,8 @@ class BruteForceKnnEngine:
         self._device = self._device_valid = None
         self._dirty = True
         self._staged = []
+        self._columns = {}
+        self._masks = collections.OrderedDict()
 
     def _stage(self, slots) -> None:
         """The host block changed at ``slots``: the device copy, if there is
@@ -158,7 +219,8 @@ class BruteForceKnnEngine:
             self._grow()
         self._host[slot] = v
         self._valid[slot] = True
-        self._slots.meta[slot] = _as_json(filter_data)
+        meta = self._slots.meta[slot] = _as_json(filter_data)
+        self._note_metadata([slot], [meta])
         self._stage(slot)
 
     def add_batch(self, keys: list[int], datas: list[Any], filters: list[Any]) -> None:
@@ -235,9 +297,10 @@ class BruteForceKnnEngine:
             self._grow(self._slots.high)
         self._host[slots] = vecs
         self._valid[slots] = True
-        for slot, f in zip(slots.tolist(), filters):
-            if f is not None:
-                self._slots.meta[slot] = _as_json(f)
+        metas = [_as_json(f) for f in filters]
+        self._slots.meta.update(
+            (slot, m) for slot, m in zip(slots.tolist(), metas) if m is not None)
+        self._note_metadata(slots, metas)
         self._stage(slots)
 
     def remove(self, key: int) -> None:
@@ -256,9 +319,12 @@ class BruteForceKnnEngine:
         valid[: self.capacity] = self._valid
         self._host, self._valid, self.capacity = host, valid, new_cap
         # a new tier is a new block: the old device copy goes now, so that
-        # the two never coexist, and the next search places the new one
+        # the two never coexist, and the next search places the new one;
+        # columns and masks are of the old capacity, and go with it
         self._device = self._device_valid = None
         self._staged = []
+        self._columns = {}
+        self._drop_masks()
 
     def _sync_device(self) -> None:
         """Bring the device copy up to date with the host block (span
@@ -274,6 +340,7 @@ class BruteForceKnnEngine:
 
         if self._device is None or not self._staged:
             dtype = storage_dtype(self.metric)
+            self._drop_masks()  # of a copy that goes, or of an unknown change
             with span("index.upload", bytes=self._host.nbytes, whole=True,
                       dtype=dtype.name):
                 self._device = self._device_valid = None  # never two blocks
@@ -309,6 +376,10 @@ class BruteForceKnnEngine:
                       dtype=self._device.dtype.name), \
                     span("index.write", rows=len(slots), padded=padded, bytes=nbytes):
                 self._write(pieces)
+                if self._masks:
+                    with span("index.mask.update", masks=len(self._masks),
+                              slots=len(slots)):
+                        self._write_masks(self._masks.values(), pieces)
             bump("index_writes_total")
             bump("index_write_rows_total", len(slots))
             bump("index_write_bytes_total", nbytes)
@@ -323,6 +394,98 @@ class BruteForceKnnEngine:
             self._device, self._device_valid = write(
                 self._device, self._device_valid, p, self._host[p], self._valid[p])
 
+    # -- filters: metadata by column, masks on the device -------------------
+    def _note_metadata(self, slots, metas: list) -> None:
+        """``metas`` were written at ``slots``: the columns follow. (A freed
+        slot keeps its codes: it is not live, so no mask reads them.)"""
+        for path, col in list(self._columns.items()):
+            col.codes[slots] = [col.encode(lookup_path(m, path)) for m in metas]
+            if len(col.values) > 2 * self.capacity:
+                # values no slot holds any more pile up under rewrites: the
+                # next filter that names the path builds the column afresh
+                del self._columns[path]
+
+    def _column(self, path: tuple[str, ...]) -> _MetaColumn:
+        col = self._columns.get(path)
+        if col is None:
+            col = self._columns[path] = _MetaColumn(self.capacity)
+            meta = self._slots.meta
+            col.codes[np.fromiter(meta, np.int64, len(meta))] = np.fromiter(
+                (col.encode(lookup_path(m, path)) for m in meta.values()),
+                np.int32, len(meta))
+        return col
+
+    def _keeps(self, ast: tuple, slots=slice(None)) -> np.ndarray:
+        """The live rows a filter keeps, over every slot or at ``slots``."""
+        def column(path):
+            col = self._column(path)
+            return col.codes[slots], col.values
+
+        live = self._valid[slots]
+        return live & eval_filter_columns(ast, column, len(live))
+
+    def _drop_masks(self) -> None:
+        from ..serve.stats import bump
+
+        if self._masks:
+            bump("index_filter_masks_dropped_total", len(self._masks))
+            self._masks.clear()
+
+    def _write_masks(self, entries, pieces: list[np.ndarray]) -> None:
+        """Each of the cache's ``entries`` evaluated at the slots of ``pieces``
+        (each padded to a write bucket) and written there in place."""
+        from .knn import mask_write
+
+        for entry in entries:
+            for p in pieces:
+                entry[1] = mask_write(entry[1], p, self._keeps(entry[0], p))
+
+    def _valid_of(self, keys: list, pad: int):
+        """``valid`` [q + pad, n] of a search whose queries carry the filters
+        ``keys`` (None for a query with none): each query's mask from the
+        cache, built and cached where it is not there, the store's own for a
+        query with no filter, the last repeated for the padding."""
+        import jax.numpy as jnp
+
+        from ..internals.tracing import span
+        from ..serve.stats import bump
+        from .knn import WRITE_BUCKETS, stack_valid
+
+        distinct = dict.fromkeys(k for k in keys if k is not None)
+        filtered = len(keys) - keys.count(None)
+        hits = sum(k in self._masks for k in keys if k is not None)
+        with span("index.mask", filtered=filtered, distinct=len(distinct),
+                  hits=hits, built=sum(k not in self._masks for k in distinct)):
+            masks = {None: self._device_valid}
+            for key in distinct:
+                entry = self._masks.get(key)
+                if entry is None:
+                    columns = len(self._columns)
+                    with span("index.mask.build", slots=self.capacity) as sp:
+                        ast = parse_metadata_filter(key)
+                        keep = self._keeps(ast)
+                        entry = self._masks[key] = [ast, jnp.asarray(keep)]
+                        # the programs that keep it current compile now, on
+                        # writes that change nothing (slot 0 with its own
+                        # value), and none while serving
+                        self._write_masks(
+                            [entry], [np.zeros(b, np.int32) for b in WRITE_BUCKETS])
+                        if sp is not None:
+                            sp.args.update(kept=int(keep.sum()),
+                                           columns_built=len(self._columns) - columns)
+                    bump("index_filter_masks_built_total")
+                self._masks.move_to_end(key)
+                masks[key] = entry[1]
+            rows = [masks[k] for k in keys]
+            valid = stack_valid(*rows, *rows[-1:] * pad)
+            # the bound holds between searches; this one has its rows in hand
+            while len(self._masks) > 1 and len(self._masks) * self.capacity > MASK_CACHE_BYTES:
+                self._masks.popitem(last=False)
+                bump("index_filter_masks_dropped_total")
+        bump("index_filtered_queries_total", filtered)
+        bump("index_filter_mask_hits_total", hits)
+        return valid
+
     # -- search ------------------------------------------------------------
     def search(self, queries: list[Any], limits: list[int], filters: list[Any]):
         from ..internals.tracing import span
@@ -330,10 +493,14 @@ class BruteForceKnnEngine:
 
         bump("index_searches_total")
         bump("index_search_queries_total", len(queries))
+        # a filter is known by its source, a callable by itself
+        keys = [f if f is None or callable(f) else str(f) for f in filters]
+        filtered = len(keys) - keys.count(None)
         with span(
             "index.search", q=len(queries),
             dirty=bool(self._dirty or self._device is None),
             k=max(limits, default=0),
+            filtered=filtered, filters=len(set(keys) - {None}),
         ):
             n = self._slots.high
             if n == 0 or not queries:
@@ -344,6 +511,12 @@ class BruteForceKnnEngine:
 
             from .knn import topk_scores
 
+            # a search that carries a filter is padded along its query axis
+            # to a power of two, its last query over again: so few programs
+            # serve whatever batches the ticks form, and all are met early
+            pad = 0
+            if filtered:
+                pad = max(SCOPED_BATCH_MIN, 1 << (len(queries) - 1).bit_length()) - len(queries)
             dev_embed = getattr(self.embedder, "embed_texts_device", None)
             if dev_embed is not None and all(isinstance(x, str) for x in queries):
                 # device-resident query embeddings (already L2-normalized by the
@@ -351,9 +524,10 @@ class BruteForceKnnEngine:
                 # top_k pipelines as queued device work with a single blocking
                 # fetch at _pack time
                 with span("index.embed", q=len(queries)):
-                    q = dev_embed(list(queries))
+                    q = dev_embed(list(queries) + list(queries[-1:]) * pad)
             else:
                 q = np.stack([self._vec(x) for x in queries])
+                q = np.concatenate([q, np.repeat(q[-1:], pad, axis=0)])
             if self._dirty or self._device is None:
                 self._sync_device()
 
@@ -361,31 +535,13 @@ class BruteForceKnnEngine:
             if kmax <= 0:
                 return [[] for _ in queries]
 
-            filt_fns = [compile_metadata_filter(f) for f in filters]
-            if any(f is not None for f in filt_fns):
-                # per-query validity: metadata filter evaluated on the host
-                # directory, applied as a -inf mask before device top-k
-                out = []
-                for qi, (fv, lim) in enumerate(zip(filt_fns, limits)):
-                    mask = self._valid.copy()
-                    if fv is not None:
-                        for slot in range(n):
-                            if mask[slot] and not fv(self._slots.meta.get(slot)):
-                                mask[slot] = False
-                    k_eff = min(lim, int(mask.sum()))
-                    if k_eff <= 0:
-                        out.append([])
-                        continue
-                    s, ids = topk_scores(
-                        jnp.asarray(q[qi : qi + 1]), self._device, k_eff,
-                        self.metric, valid=jnp.asarray(mask),
-                    )
-                    out.append(self._pack(np.asarray(s)[0], np.asarray(ids)[0], lim))
-                return out
-
+            # a query's rows are the live ones its filter keeps: masked to
+            # -inf before the device's top-k, so a scope of fewer than k
+            # rows answers with every one of them and no more
+            valid = self._valid_of(keys, pad) if filtered else self._device_valid
             with span("index.score", q=len(queries), rows=n):
                 s, ids = topk_scores(jnp.asarray(q), self._device, kmax,
-                                     self.metric, valid=self._device_valid)
+                                     self.metric, valid=valid)
             with span("index.fetch"):  # where the host waits for the device
                 s, ids = np.asarray(s), np.asarray(ids)
             with span("index.pack", replies=len(queries)):

@@ -31,7 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 __all__ = [
     "topk_scores", "knn_search", "ShardedKnnIndex", "sharded_knn_search",
     "merge_shard_topk", "index_write", "index_writer", "WRITE_BUCKETS",
-    "storage_dtype", "index_fill", "PLACE_ROWS",
+    "storage_dtype", "index_fill", "PLACE_ROWS", "stack_valid", "mask_write",
 ]
 
 #: row counts an in-place write is padded to (a larger batch goes in pieces
@@ -81,7 +81,8 @@ def topk_scores(
     cos: both sides assumed L2-normalized → dot product == cosine.
     l2: negative squared distance (higher is closer).
     valid [n] bool: rows where False are masked to -inf BEFORE top-k
-    (capacity padding must never displace real documents).
+    (capacity padding must never displace real documents); valid [q, n] is a
+    mask for each query (its filter's rows among the live ones).
     """
     qb = queries.astype(jnp.bfloat16)
     ib = index.astype(jnp.bfloat16)
@@ -94,7 +95,7 @@ def topk_scores(
         sq_q = (queries.astype(jnp.float32) ** 2).sum(-1, keepdims=True)
         scores = -(sq_q - 2 * dots + sq_i[None, :])
     if valid is not None:
-        scores = jnp.where(valid[None, :], scores, -jnp.inf)
+        scores = jnp.where(valid if valid.ndim == 2 else valid[None, :], scores, -jnp.inf)
     return jax.lax.top_k(scores, k)
 
 
@@ -125,6 +126,21 @@ def index_fill(block: jax.Array, rows: jax.Array, start: jax.Array):
     by chunk (``jit_index_fill`` in a device trace)."""
     return jax.lax.dynamic_update_slice(
         block, rows.astype(block.dtype), (start, jnp.zeros_like(start)))
+
+
+@jax.jit
+def stack_valid(*masks: jax.Array):
+    """The masks [n] of a search's queries, one a query, as the ``valid``
+    [q, n] of ``topk_scores``: one program for each number of queries."""
+    return jnp.stack(masks)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def mask_write(mask: jax.Array, slots: jax.Array, keep: jax.Array):
+    """mask [n] with ``keep`` [m] written at ``slots`` [m], the mask donated:
+    how a cached filter mask follows an in-place write of the block. A slot
+    may repeat with the same value, as in ``index_write``."""
+    return mask.at[slots].set(keep)
 
 
 def knn_search(queries: np.ndarray, index: np.ndarray, k: int, metric: str = "cos"):

@@ -65,6 +65,15 @@ SERVE_STATS: dict[str, int] = {
     "index_writes_total": 0,
     "index_write_rows_total": 0,
     "index_write_bytes_total": 0,
+    #: filters (BruteForceKnnEngine): queries that carried one / of them,
+    #: those whose mask was cached on the device when their search began /
+    #: masks built (a filter first seen, or seen again after its mask went) /
+    #: masks dropped (the cache's byte bound, a new capacity tier, a whole
+    #: placement of the block)
+    "index_filtered_queries_total": 0,
+    "index_filter_mask_hits_total": 0,
+    "index_filter_masks_built_total": 0,
+    "index_filter_masks_dropped_total": 0,
     #: rows the dataflow added to / removed from an external index
     "index_rows_added_total": 0,
     "index_rows_removed_total": 0,

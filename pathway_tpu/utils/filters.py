@@ -10,6 +10,12 @@ metadata JSON dict:
     contains(path, 'x')           starts_with / ends_with
     globmatch('pat', path)        glob on string fields
     expr && expr, expr || expr, !expr, parentheses
+
+One parser, one tree, two evaluators: ``compile_metadata_filter`` gives the
+predicate over one dict (what a filter means), ``eval_filter_columns`` the
+same answers for many slots at once from dictionary-encoded columns, the
+leaves computed by the very code of the predicate (``_compare``, ``_call``)
+once for each distinct value and never once for each slot.
 """
 
 from __future__ import annotations
@@ -18,7 +24,12 @@ import fnmatch
 import re
 from typing import Any, Callable
 
-__all__ = ["compile_metadata_filter", "FilterSyntaxError"]
+import numpy as np
+
+__all__ = [
+    "compile_metadata_filter", "parse_metadata_filter", "eval_filter_columns",
+    "lookup_path", "FilterSyntaxError",
+]
 
 
 class FilterSyntaxError(ValueError):
@@ -144,7 +155,8 @@ class _Parser:
         return lhs
 
 
-def _lookup(meta: Any, path: list[str]) -> Any:
+def lookup_path(meta: Any, path: "list[str] | tuple[str, ...]") -> Any:
+    """The value a filter's ``path`` reads in one metadata dict, or None."""
     cur = meta
     for p in path:
         if isinstance(cur, dict):
@@ -154,12 +166,47 @@ def _lookup(meta: Any, path: list[str]) -> Any:
     return cur
 
 
+def _compare(op: str, l: Any, r: Any) -> bool:
+    try:
+        if op == "==":
+            return l == r
+        if op == "!=":
+            return l != r
+        if l is None or r is None:
+            return False
+        if op == "<":
+            return l < r
+        if op == "<=":
+            return l <= r
+        if op == ">":
+            return l > r
+        return l >= r
+    except TypeError:
+        return False
+
+
+def _call(fn: str, a: Any, b: Any) -> bool:
+    if fn == "globmatch":
+        # jmespath-extension argument order: globmatch(pattern, field)
+        return isinstance(b, str) and isinstance(a, str) and fnmatch.fnmatch(b, a)
+    if not isinstance(a, str):
+        if fn == "contains" and isinstance(a, (list, tuple)):
+            return b in a
+        return False
+    b = "" if b is None else str(b)
+    if fn == "contains":
+        return b in a
+    if fn == "starts_with":
+        return a.startswith(b)
+    return a.endswith(b)
+
+
 def _eval(node, meta: Any) -> Any:
     tag = node[0]
     if tag == "lit":
         return node[1]
     if tag == "path":
-        return _lookup(meta, node[1])
+        return lookup_path(meta, node[1])
     if tag == "and":
         return bool(_eval(node[1], meta)) and bool(_eval(node[2], meta))
     if tag == "or":
@@ -167,43 +214,90 @@ def _eval(node, meta: Any) -> Any:
     if tag == "not":
         return not bool(_eval(node[1], meta))
     if tag == "cmp":
-        op, l, r = node[1], _eval(node[2], meta), _eval(node[3], meta)
-        try:
-            if op == "==":
-                return l == r
-            if op == "!=":
-                return l != r
-            if l is None or r is None:
-                return False
-            if op == "<":
-                return l < r
-            if op == "<=":
-                return l <= r
-            if op == ">":
-                return l > r
-            if op == ">=":
-                return l >= r
-        except TypeError:
-            return False
+        return _compare(node[1], _eval(node[2], meta), _eval(node[3], meta))
+    if tag == "call":
+        return _call(node[1], _eval(node[2], meta), _eval(node[3], meta))
+    raise FilterSyntaxError(f"cannot evaluate node {node!r}")
+
+
+def parse_metadata_filter(src: Any) -> tuple:
+    """The tree both evaluators walk. A callable is a predicate over the
+    whole metadata: ``compile_metadata_filter`` hands it back untouched, and
+    over columns it is one node (``pred``) of the tree."""
+    if callable(src):
+        return ("pred", src)
+    return _Parser(_lex(str(src))).parse()
+
+
+# -- the same tree over columns ---------------------------------------------
+# A value of many slots is (codes, values): ``values[codes[i]]`` is slot i's,
+# and ``codes`` None means one value for every slot (a literal).
+
+
+def _pairs(fn: Callable[[Any, Any], Any], a, b):
+    """``fn`` of two such values, called once for each distinct pair: every
+    pair there can be, or where the slots are fewer than those (a few slots
+    of a column of many values, as a write evaluates) only the pairs they
+    hold."""
+    (ca, va), (cb, vb) = a, b
+    if ca is None and cb is None:
+        return None, [fn(va[0], vb[0])]
+    if ca is None or cb is None:
+        codes = cb if ca is None else ca
+    else:
+        codes = ca.astype(np.int64) * len(vb) + cb
+    pairs = range(len(va) * len(vb))
+    if len(pairs) > len(codes):
+        present, codes = np.unique(codes, return_inverse=True)
+        pairs = present.tolist()
+    return codes, [fn(va[p // len(vb)], vb[p % len(vb)]) for p in pairs]
+
+
+def _truth(value, n: int) -> np.ndarray:
+    codes, values = value
+    truth = np.fromiter((bool(v) for v in values), bool, len(values))
+    return np.broadcast_to(truth, n) if codes is None else truth[codes]
+
+
+def _eval_columns(node, column, n: int):
+    tag = node[0]
+    if tag == "lit":
+        return None, [node[1]]
+    if tag == "path":
+        return column(tuple(node[1]))
+    if tag == "pred":
+        codes, values = column(())
+        return codes, [node[1](v) for v in values]
+    if tag == "cmp":
+        op = node[1]
+        return _pairs(lambda l, r: _compare(op, l, r),
+                      _eval_columns(node[2], column, n), _eval_columns(node[3], column, n))
     if tag == "call":
         fn = node[1]
-        a = _eval(node[2], meta)
-        b = _eval(node[3], meta)
-        if fn == "globmatch":
-            # jmespath-extension argument order: globmatch(pattern, field)
-            return isinstance(b, str) and isinstance(a, str) and fnmatch.fnmatch(b, a)
-        if not isinstance(a, str):
-            if fn == "contains" and isinstance(a, (list, tuple)):
-                return b in a
-            return False
-        b = "" if b is None else str(b)
-        if fn == "contains":
-            return b in a
-        if fn == "starts_with":
-            return a.startswith(b)
-        if fn == "ends_with":
-            return a.endswith(b)
-    raise FilterSyntaxError(f"cannot evaluate node {node!r}")
+        return _pairs(lambda a, b: _call(fn, a, b),
+                      _eval_columns(node[2], column, n), _eval_columns(node[3], column, n))
+    if tag == "not":
+        keep = ~_truth(_eval_columns(node[1], column, n), n)
+    elif tag in ("and", "or"):
+        l = _truth(_eval_columns(node[1], column, n), n)
+        r = _truth(_eval_columns(node[2], column, n), n)
+        keep = l & r if tag == "and" else l | r
+    else:
+        raise FilterSyntaxError(f"cannot evaluate node {node!r}")
+    return keep.astype(np.intp), [False, True]
+
+
+def eval_filter_columns(
+    ast: tuple,
+    column: "Callable[[tuple[str, ...]], tuple[np.ndarray, list]]",
+    n: int,
+) -> np.ndarray:
+    """What ``compile_metadata_filter``'s predicate gives for each of ``n``
+    slots, as a boolean array. ``column(path)`` is (codes [n], values) with
+    ``values[codes[i]]`` what ``lookup_path`` reads at ``path`` in slot i's
+    metadata (None for a slot with none); the path ``()`` is the whole
+    metadata, which only a callable filter reads."""
+    return np.array(_truth(_eval_columns(ast, column, n), n))
 
 
 def compile_metadata_filter(src: Any) -> Callable[[Any], bool] | None:
@@ -213,7 +307,7 @@ def compile_metadata_filter(src: Any) -> Callable[[Any], bool] | None:
         return None
     if callable(src):
         return src
-    ast = _Parser(_lex(str(src))).parse()
+    ast = parse_metadata_filter(src)
 
     def predicate(meta: Any) -> bool:
         return bool(_eval(ast, meta if meta is not None else {}))
