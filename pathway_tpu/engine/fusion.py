@@ -95,6 +95,10 @@ FUSION_STATS: dict[str, int] = {
     # and the ones whose key recurred so their row content was hashed
     "consolidation_rows_total": 0,
     "consolidation_rows_hashed_total": 0,
+    # values the native hash (native/native.c hash_scalar2) handed back to
+    # the Python ladder of engine/keys.py, one a lane, wherever an object
+    # column is hashed; stays 0 without the native module
+    "hash_fallback_calls_total": 0,
 }
 
 

@@ -111,6 +111,16 @@ _STR_MEMO: dict = {}
 _STR_MEMO2: dict = {}
 
 
+def _count_fallbacks(calls: int) -> None:
+    """Every native hash returns how many values it handed back to
+    ``_hash_scalar`` / ``_hash_scalar_hi``, one a lane: the values native.c
+    does not hash itself (subclasses of the builtin and numpy types)."""
+    if calls:
+        from .fusion import FUSION_STATS
+
+        FUSION_STATS["hash_fallback_calls_total"] += calls
+
+
 def _hash_object_column(col: np.ndarray) -> np.ndarray:
     cache_key = None
     if len(col) >= _OBJ_HASH_CACHE_MIN_ROWS:
@@ -125,7 +135,9 @@ def _hash_object_column(col: np.ndarray) -> np.ndarray:
     native = get_native()
     if native is not None:
         # group-key hot path — same per-scalar semantics, in C
-        native.hash_scalars(list(col), _hash_scalar, out, _STR_MEMO)
+        _count_fallbacks(
+            native.hash_scalars(list(col), _hash_scalar, out, _STR_MEMO)
+        )
     else:
         for i, v in enumerate(col):
             out[i] = _hash_scalar(v)
@@ -164,9 +176,9 @@ def _hash_object_column2(col: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     hi = np.empty(len(col), dtype=np.uint64)
     native = get_native()
     if native is not None:
-        native.hash_scalars2(
+        _count_fallbacks(native.hash_scalars2(
             list(col), _hash_scalar, _hash_scalar_hi, _STR_MEMO2, lo, hi
-        )
+        ))
     else:
         for i, v in enumerate(col):
             lo[i] = _hash_scalar(v)
@@ -822,10 +834,10 @@ def mix_columns_fused(
     lo = np.empty(n, dtype=np.uint64)
     hi = np.empty(n, dtype=np.uint64)
     salt64 = int(salt) & _M64_
-    native.mix_cols2(
+    _count_fallbacks(native.mix_cols2(
         arrs, n, salt64, salt64, _hash_scalar, _hash_scalar_hi,
         _STR_MEMO2, lo, hi,
-    )
+    ))
     _register_keys(lo, hi)
     return lo
 
@@ -860,7 +872,7 @@ def hash_values(
         if native is None:
             return _hash_values_py(rows, salt)
         out = np.empty(len(rows), dtype=np.uint64)
-        native.hash_rows(rows, salt64, _hash_scalar, out)
+        _count_fallbacks(native.hash_rows(rows, salt64, _hash_scalar, out))
         return out
     lo = np.empty(len(rows), dtype=np.uint64)
     hi = np.empty(len(rows), dtype=np.uint64)
@@ -873,10 +885,10 @@ def hash_values(
                 acc = _splitmix2_int(acc ^ _hash_scalar_hi(v))
             hi[i] = acc
     else:
-        native.hash_rows2(
+        _count_fallbacks(native.hash_rows2(
             rows, salt64, salt64, _hash_scalar, _hash_scalar_hi,
             _STR_MEMO2, lo, hi,
-        )
+        ))
     _register_keys(lo, hi)
     return lo
 

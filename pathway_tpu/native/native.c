@@ -153,142 +153,198 @@ static inline uint64_t splitmix2(uint64_t x) {
     return x ^ (x >> 32);
 }
 
-/* hash one scalar with keys._hash_scalar semantics; `fallback` is the
- * Python implementation used for types this C path doesn't know
- * (ndarrays, datetimes, Json wrappers, ...). Returns 0 + sets err on
- * failure. */
-static int hash_scalar(PyObject *v, PyObject *fallback, uint64_t *out) {
+/* numpy's scalar and array types, looked up once at module init. The
+ * concrete types are matched exactly: a subclass may override what the
+ * Python ladder reads, so it goes to the ladder. */
+static PyTypeObject *np_bool, *np_integer, *np_floating, *np_ndarray;
+static PyTypeObject *np_float64;
+static PyTypeObject *np_ints[10];  /* dtype chars bhilqBHILQ */
+static PyTypeObject *np_narrow_floats[2];  /* float16, float32 */
+static PyObject *str_tobytes, *str_shape, *str_class;
+
+/* values handed to the Python ladder since the module loaded, one per
+ * lane asked for; every py_hash_* entry point returns its own share */
+static uint64_t fallback_calls;
+
+static inline void word_lanes(uint64_t x, uint64_t *lo, uint64_t *hi) {
+    *lo = splitmix(x);
+    if (hi != NULL) *hi = splitmix2(x);
+}
+
+static inline void bytes_lanes(const uint8_t *data, Py_ssize_t len,
+                               uint64_t *lo, uint64_t *hi) {
+    *lo = blake2b8(data, len);
+    if (hi != NULL) *hi = blake2b16hi(data, len);
+}
+
+static inline void double_lanes(double d, uint64_t *lo, uint64_t *hi) {
+    uint64_t bits;
+    memcpy(&bits, &d, 8);
+    word_lanes(bits, lo, hi);
+}
+
+static int text_lanes(PyObject *text, uint64_t *lo, uint64_t *hi) {
+    Py_ssize_t len;
+    const char *utf8 = PyUnicode_AsUTF8AndSize(text, &len);
+    if (utf8 == NULL) return -1;
+    bytes_lanes((const uint8_t *)utf8, len, lo, hi);
+    return 0;
+}
+
+static int call_ladder(PyObject *ladder, PyObject *v, uint64_t *out) {
+    PyObject *res = PyObject_CallFunctionObjArgs(ladder, v, NULL);
+    uint64_t x;
+    fallback_calls++;
+    if (res == NULL) return -1;
+    x = PyLong_AsUnsignedLongLongMask(res);
+    Py_DECREF(res);
+    if (x == (uint64_t)-1 && PyErr_Occurred()) return -1;
+    *out = x;
+    return 0;
+}
+
+/* an exact ndarray: digest of tobytes() xor digest of str(shape) */
+static int ndarray_lanes(PyObject *v, uint64_t *lo, uint64_t *hi) {
+    PyObject *data = NULL, *shape = NULL, *text = NULL;
+    uint64_t shape_lo, shape_hi = 0;
+    int rc = -1;
+    data = PyObject_CallMethodNoArgs(v, str_tobytes);
+    if (data == NULL) goto done; /* numpy's own method: always bytes */
+    shape = PyObject_GetAttr(v, str_shape);
+    if (shape == NULL) goto done;
+    text = PyObject_Str(shape);
+    if (text == NULL) goto done;
+    if (text_lanes(text, &shape_lo, hi != NULL ? &shape_hi : NULL) < 0)
+        goto done;
+    bytes_lanes((const uint8_t *)PyBytes_AS_STRING(data),
+                PyBytes_GET_SIZE(data), lo, hi);
+    *lo ^= shape_lo;
+    if (hi != NULL) *hi ^= shape_hi;
+    rc = 0;
+done:
+    Py_XDECREF(data);
+    Py_XDECREF(shape);
+    Py_XDECREF(text);
+    return rc;
+}
+
+/* would one of the isinstance checks of keys._hash_scalar take v? Only
+ * asked of values whose exact type the branches above did not take, so a
+ * yes means a subclass, which the ladder itself hashes. */
+static int ladder_knows(PyObject *v) {
+    PyObject *cls;
+    int lies;
+    if (PyDict_CheckExact(v)) return 0; /* the reply path's _metadata */
+    if (PyLong_Check(v) || PyFloat_Check(v) || PyUnicode_Check(v) ||
+        PyBytes_Check(v) || PyTuple_Check(v) ||
+        PyObject_TypeCheck(v, np_bool) || PyObject_TypeCheck(v, np_integer) ||
+        PyObject_TypeCheck(v, np_floating) || PyObject_TypeCheck(v, np_ndarray))
+        return 1;
+    /* isinstance also believes a __class__ that differs from the type */
+    cls = PyObject_GetAttr(v, str_class);
+    if (cls == NULL) {
+        PyErr_Clear();
+        return 1;
+    }
+    lies = cls != (PyObject *)Py_TYPE(v);
+    Py_DECREF(cls);
+    return lies;
+}
+
+/* hash one scalar with keys._hash_scalar semantics, and with
+ * keys._hash_scalar_hi's on the HI lane (the upper 64 bits of the 128-bit
+ * keyspace) when `hi` is given. That ladder's order is the specification,
+ * and it matters for subclasses alone: those, and numpy.longdouble, are
+ * not provably hashed here as there, so fb_lo/fb_hi, the ladder itself,
+ * take them. Returns 0, or -1 with an error set. */
+static int hash_scalar2(PyObject *v, PyObject *fb_lo, PyObject *fb_hi,
+                        uint64_t *lo, uint64_t *hi) {
+    PyTypeObject *tp = Py_TYPE(v);
+    size_t k;
+    /* exact types first, the commonest before the rest: they exclude one
+     * another, so their order is free */
     if (v == Py_None) {
-        *out = NONE_TAG;
+        *lo = NONE_TAG;
+        if (hi != NULL) *hi = NONE_TAG_HI;
         return 0;
     }
-    if (PyBool_Check(v)) {
-        *out = splitmix((v == Py_True ? 1ULL : 0ULL) + 0xB001ULL);
+    if (PyBool_Check(v) || tp == np_bool) {
+        int truth = PyObject_IsTrue(v);
+        if (truth < 0) return -1;
+        word_lanes((uint64_t)truth + 0xB001ULL, lo, hi);
         return 0;
     }
     if (PyLong_CheckExact(v)) {
         uint64_t x = PyLong_AsUnsignedLongLongMask(v); /* low 64 bits */
         if (x == (uint64_t)-1 && PyErr_Occurred()) return -1;
-        *out = splitmix(x);
+        word_lanes(x, lo, hi);
         return 0;
     }
-    if (PyFloat_CheckExact(v)) {
-        double d = PyFloat_AS_DOUBLE(v);
-        uint64_t bits;
-        memcpy(&bits, &d, 8);
-        *out = splitmix(bits);
+    /* numpy.float64 is a float subclass that holds its double where a
+     * float does: no numpy call */
+    if (PyFloat_CheckExact(v) || tp == np_float64) {
+        double_lanes(PyFloat_AS_DOUBLE(v), lo, hi);
         return 0;
     }
-    if (PyUnicode_CheckExact(v)) {
-        Py_ssize_t len;
-        const char *utf8 = PyUnicode_AsUTF8AndSize(v, &len);
-        if (utf8 == NULL) return -1;
-        *out = blake2b8((const uint8_t *)utf8, len);
-        return 0;
-    }
+    if (PyUnicode_CheckExact(v)) return text_lanes(v, lo, hi);
     if (PyBytes_CheckExact(v)) {
-        *out = blake2b8((const uint8_t *)PyBytes_AS_STRING(v),
-                        PyBytes_GET_SIZE(v));
+        bytes_lanes((const uint8_t *)PyBytes_AS_STRING(v),
+                    PyBytes_GET_SIZE(v), lo, hi);
         return 0;
     }
     if (PyTuple_CheckExact(v)) {
-        uint64_t acc = TUPLE_SEED, h;
+        uint64_t acc_lo = TUPLE_SEED, acc_hi = TUPLE_SEED_HI, l, h = 0;
         Py_ssize_t i, n = PyTuple_GET_SIZE(v);
         for (i = 0; i < n; i++) {
-            if (hash_scalar(PyTuple_GET_ITEM(v, i), fallback, &h) < 0)
+            if (hash_scalar2(PyTuple_GET_ITEM(v, i), fb_lo, fb_hi, &l,
+                             hi != NULL ? &h : NULL) < 0)
                 return -1;
-            acc = splitmix(acc ^ h);
+            acc_lo = splitmix(acc_lo ^ l);
+            if (hi != NULL) acc_hi = splitmix2(acc_hi ^ h);
         }
-        *out = acc;
+        *lo = acc_lo;
+        if (hi != NULL) *hi = acc_hi;
         return 0;
     }
-    /* numpy scalars, ndarrays, datetimes, wrappers: defer to Python */
-    {
-        PyObject *res = PyObject_CallFunctionObjArgs(fallback, v, NULL);
-        uint64_t x;
-        if (res == NULL) return -1;
-        x = PyLong_AsUnsignedLongLongMask(res);
-        Py_DECREF(res);
-        if (x == (uint64_t)-1 && PyErr_Occurred()) return -1;
-        *out = x;
+    for (k = 0; k < sizeof(np_ints) / sizeof(np_ints[0]); k++) {
+        if (tp == np_ints[k]) {
+            /* two's complement in 64 bits, whatever the width and sign */
+            PyObject *index = PyNumber_Index(v);
+            uint64_t x;
+            if (index == NULL) return -1;
+            x = PyLong_AsUnsignedLongLongMask(index);
+            Py_DECREF(index);
+            if (x == (uint64_t)-1 && PyErr_Occurred()) return -1;
+            word_lanes(x, lo, hi);
+            return 0;
+        }
+    }
+    if (tp == np_narrow_floats[0] || tp == np_narrow_floats[1]) {
+        PyObject *wide = PyNumber_Float(v); /* widening is exact */
+        if (wide == NULL) return -1;
+        double_lanes(PyFloat_AS_DOUBLE(wide), lo, hi);
+        Py_DECREF(wide);
         return 0;
+    }
+    if (tp == np_ndarray) return ndarray_lanes(v, lo, hi);
+    if (ladder_knows(v)) {
+        if (call_ladder(fb_lo, v, lo) < 0) return -1;
+        return hi != NULL ? call_ladder(fb_hi, v, hi) : 0;
+    }
+    /* dicts, datetimes, Json wrappers, arbitrary objects: by repr */
+    {
+        PyObject *text = PyObject_Repr(v);
+        int rc;
+        if (text == NULL) return -1;
+        rc = text_lanes(text, lo, hi);
+        Py_DECREF(text);
+        return rc;
     }
 }
 
-/* hash one scalar on BOTH key lanes. fb_lo/fb_hi are the Python fallback
- * implementations for types this C path doesn't know. */
-static int hash_scalar2(PyObject *v, PyObject *fb_lo, PyObject *fb_hi,
-                        uint64_t *lo, uint64_t *hi) {
-    if (v == Py_None) {
-        *lo = NONE_TAG;
-        *hi = NONE_TAG_HI;
-        return 0;
-    }
-    if (PyBool_Check(v)) {
-        uint64_t x = (v == Py_True ? 1ULL : 0ULL) + 0xB001ULL;
-        *lo = splitmix(x);
-        *hi = splitmix2(x);
-        return 0;
-    }
-    if (PyLong_CheckExact(v)) {
-        uint64_t x = PyLong_AsUnsignedLongLongMask(v);
-        if (x == (uint64_t)-1 && PyErr_Occurred()) return -1;
-        *lo = splitmix(x);
-        *hi = splitmix2(x);
-        return 0;
-    }
-    if (PyFloat_CheckExact(v)) {
-        double d = PyFloat_AS_DOUBLE(v);
-        uint64_t bits;
-        memcpy(&bits, &d, 8);
-        *lo = splitmix(bits);
-        *hi = splitmix2(bits);
-        return 0;
-    }
-    if (PyUnicode_CheckExact(v)) {
-        Py_ssize_t len;
-        const char *utf8 = PyUnicode_AsUTF8AndSize(v, &len);
-        if (utf8 == NULL) return -1;
-        *lo = blake2b8((const uint8_t *)utf8, len);
-        *hi = blake2b16hi((const uint8_t *)utf8, len);
-        return 0;
-    }
-    if (PyBytes_CheckExact(v)) {
-        *lo = blake2b8((const uint8_t *)PyBytes_AS_STRING(v),
-                       PyBytes_GET_SIZE(v));
-        *hi = blake2b16hi((const uint8_t *)PyBytes_AS_STRING(v),
-                          PyBytes_GET_SIZE(v));
-        return 0;
-    }
-    if (PyTuple_CheckExact(v)) {
-        uint64_t acc_lo = TUPLE_SEED, acc_hi = TUPLE_SEED_HI, l, h;
-        Py_ssize_t i, n = PyTuple_GET_SIZE(v);
-        for (i = 0; i < n; i++) {
-            if (hash_scalar2(PyTuple_GET_ITEM(v, i), fb_lo, fb_hi, &l, &h) < 0)
-                return -1;
-            acc_lo = splitmix(acc_lo ^ l);
-            acc_hi = splitmix2(acc_hi ^ h);
-        }
-        *lo = acc_lo;
-        *hi = acc_hi;
-        return 0;
-    }
-    {
-        PyObject *res = PyObject_CallFunctionObjArgs(fb_lo, v, NULL);
-        uint64_t x;
-        if (res == NULL) return -1;
-        x = PyLong_AsUnsignedLongLongMask(res);
-        Py_DECREF(res);
-        if (x == (uint64_t)-1 && PyErr_Occurred()) return -1;
-        *lo = x;
-        res = PyObject_CallFunctionObjArgs(fb_hi, v, NULL);
-        if (res == NULL) return -1;
-        x = PyLong_AsUnsignedLongLongMask(res);
-        Py_DECREF(res);
-        if (x == (uint64_t)-1 && PyErr_Occurred()) return -1;
-        *hi = x;
-        return 0;
-    }
+/* the LO lane alone: the persisted keyspace */
+static inline int hash_scalar(PyObject *v, PyObject *fallback, uint64_t *out) {
+    return hash_scalar2(v, fallback, NULL, out, NULL);
 }
 
 #define STR_MEMO_CAP 65536
@@ -328,10 +384,11 @@ static int hash_scalar2_memo(PyObject *v, PyObject *fb_lo, PyObject *fb_hi,
 }
 
 /* hash_scalars2(values, fb_lo, fb_hi, memo_or_None,
- *               out_lo_u64, out_hi_u64) -> None */
+ *               out_lo_u64, out_hi_u64) -> fallback calls made */
 static PyObject *py_hash_scalars2(PyObject *self, PyObject *args) {
     PyObject *values, *fb_lo, *fb_hi, *memo, *lo_obj, *hi_obj;
     Py_buffer lo, hi;
+    uint64_t before = fallback_calls;
     (void)self;
     if (!PyArg_ParseTuple(args, "OOOOOO", &values, &fb_lo, &fb_hi, &memo,
                           &lo_obj, &hi_obj))
@@ -365,7 +422,7 @@ static PyObject *py_hash_scalars2(PyObject *self, PyObject *args) {
     }
     PyBuffer_Release(&lo);
     PyBuffer_Release(&hi);
-    Py_RETURN_NONE;
+    return PyLong_FromUnsignedLongLong(fallback_calls - before);
 fail:
     PyBuffer_Release(&lo);
     PyBuffer_Release(&hi);
@@ -373,7 +430,7 @@ fail:
 }
 
 /* mix_cols2(cols, n, salt_lo, salt_hi, fb_lo, fb_hi, memo_or_None,
- *           out_lo_u64, out_hi_u64) -> None
+ *           out_lo_u64, out_hi_u64) -> fallback calls made
  * Fused column-key fold for the columnar ingest plane: accumulate every
  * OBJECT column of a batch into both key lanes in one C pass —
  * out[i] starts at ROW_SEED ^ salt and folds splitmix(acc ^ lane(v))
@@ -386,6 +443,7 @@ static PyObject *py_mix_cols2(PyObject *self, PyObject *args) {
     unsigned long long salt_lo, salt_hi;
     Py_ssize_t n;
     Py_buffer lo, hi;
+    uint64_t before = fallback_calls;
     (void)self;
     if (!PyArg_ParseTuple(args, "OnKKOOOOO", &cols, &n, &salt_lo, &salt_hi,
                           &fb_lo, &fb_hi, &memo, &lo_obj, &hi_obj))
@@ -443,7 +501,7 @@ static PyObject *py_mix_cols2(PyObject *self, PyObject *args) {
     }
     PyBuffer_Release(&lo);
     PyBuffer_Release(&hi);
-    Py_RETURN_NONE;
+    return PyLong_FromUnsignedLongLong(fallback_calls - before);
 fail:
     PyBuffer_Release(&lo);
     PyBuffer_Release(&hi);
@@ -451,11 +509,13 @@ fail:
 }
 
 /* hash_rows2(rows, salt_lo, salt_hi, fb_lo, fb_hi, memo_or_None,
- *            out_lo_u64, out_hi_u64) -> None — both key lanes per row */
+ *            out_lo_u64, out_hi_u64) -> fallback calls made — both key
+ * lanes per row */
 static PyObject *py_hash_rows2(PyObject *self, PyObject *args) {
     PyObject *rows, *fb_lo, *fb_hi, *memo, *lo_obj, *hi_obj;
     unsigned long long salt_lo, salt_hi;
     Py_buffer lo, hi;
+    uint64_t before = fallback_calls;
     (void)self;
     if (!PyArg_ParseTuple(args, "OKKOOOOO", &rows, &salt_lo, &salt_hi,
                           &fb_lo, &fb_hi, &memo, &lo_obj, &hi_obj))
@@ -508,7 +568,7 @@ static PyObject *py_hash_rows2(PyObject *self, PyObject *args) {
     }
     PyBuffer_Release(&lo);
     PyBuffer_Release(&hi);
-    Py_RETURN_NONE;
+    return PyLong_FromUnsignedLongLong(fallback_calls - before);
 fail:
     PyBuffer_Release(&lo);
     PyBuffer_Release(&hi);
@@ -535,11 +595,12 @@ static PyObject *py_blake2b16hi(PyObject *self, PyObject *arg) {
 }
 
 /* hash_rows(rows: sequence of tuples, salt: int, fallback, out: writable
- * uint64 buffer of len(rows)) -> None */
+ * uint64 buffer of len(rows)) -> fallback calls made */
 static PyObject *py_hash_rows(PyObject *self, PyObject *args) {
     PyObject *rows, *fallback, *out_obj;
     unsigned long long salt;
     Py_buffer out;
+    uint64_t before = fallback_calls;
     (void)self;
     if (!PyArg_ParseTuple(args, "OKOO", &rows, &salt, &fallback, &out_obj))
         return NULL;
@@ -587,7 +648,7 @@ static PyObject *py_hash_rows(PyObject *self, PyObject *args) {
         Py_DECREF(seq);
     }
     PyBuffer_Release(&out);
-    Py_RETURN_NONE;
+    return PyLong_FromUnsignedLongLong(fallback_calls - before);
 }
 
 /* memoized LO-lane hash of an exact str (see hash_scalar2_memo) */
@@ -615,11 +676,13 @@ static int hash_scalar_memo(PyObject *v, PyObject *fallback, PyObject *memo,
 }
 
 /* hash_scalars(values: sequence, fallback, out: writable uint64 buffer
- * [, memo_dict]) -> None — per-element hash_scalar (group-key/hash_column
- * hot path; the optional memo caches string digests value-wise) */
+ * [, memo_dict]) -> fallback calls made — per-element hash_scalar
+ * (group-key/hash_column hot path; the optional memo caches string digests
+ * value-wise) */
 static PyObject *py_hash_scalars(PyObject *self, PyObject *args) {
     PyObject *values, *fallback, *out_obj, *memo = NULL;
     Py_buffer out;
+    uint64_t before = fallback_calls;
     (void)self;
     if (!PyArg_ParseTuple(args, "OOO|O", &values, &fallback, &out_obj, &memo))
         return NULL;
@@ -652,7 +715,7 @@ static PyObject *py_hash_scalars(PyObject *self, PyObject *args) {
         Py_DECREF(seq);
     }
     PyBuffer_Release(&out);
-    Py_RETURN_NONE;
+    return PyLong_FromUnsignedLongLong(fallback_calls - before);
 }
 
 /* blake2b8(data: bytes-like) -> int — exposed for parity tests */
@@ -1172,8 +1235,52 @@ static struct PyModuleDef module = {
     NULL, NULL, NULL, NULL,
 };
 
+/* `found` (stolen, may be NULL with its error set) as a type, or NULL */
+static PyTypeObject *as_type(PyObject *found) {
+    if (found != NULL && !PyType_Check(found)) {
+        PyErr_Format(PyExc_TypeError, "numpy gave %R where a type is due", found);
+        Py_CLEAR(found);
+    }
+    return (PyTypeObject *)found;
+}
+
+/* numpy.dtype(code).type, a new reference */
+static PyTypeObject *np_scalar_type(PyObject *numpy, char code) {
+    PyObject *dtype = PyObject_CallMethod(numpy, "dtype", "C", (int)code);
+    PyObject *type = dtype != NULL ? PyObject_GetAttrString(dtype, "type") : NULL;
+    Py_XDECREF(dtype);
+    return as_type(type);
+}
+
+/* the types and names hash_scalar2 compares against, kept for the life of
+ * the process */
+static int lookup_numpy(void) {
+    static const char int_codes[] = "bhilqBHILQ";
+    PyObject *numpy = PyImport_ImportModule("numpy");
+    size_t k;
+    int ok = numpy != NULL; /* the first failure leaves its error set */
+    for (k = 0; ok && k < sizeof(np_ints) / sizeof(np_ints[0]); k++)
+        ok = (np_ints[k] = np_scalar_type(numpy, int_codes[k])) != NULL;
+    ok = ok && (np_narrow_floats[0] = np_scalar_type(numpy, 'e')) != NULL;
+    ok = ok && (np_narrow_floats[1] = np_scalar_type(numpy, 'f')) != NULL;
+    ok = ok && (np_float64 = np_scalar_type(numpy, 'd')) != NULL;
+    ok = ok && (np_bool = np_scalar_type(numpy, '?')) != NULL;
+    ok = ok && (np_integer = as_type(
+        PyObject_GetAttrString(numpy, "integer"))) != NULL;
+    ok = ok && (np_floating = as_type(
+        PyObject_GetAttrString(numpy, "floating"))) != NULL;
+    ok = ok && (np_ndarray = as_type(
+        PyObject_GetAttrString(numpy, "ndarray"))) != NULL;
+    ok = ok && (str_tobytes = PyUnicode_InternFromString("tobytes")) != NULL;
+    ok = ok && (str_shape = PyUnicode_InternFromString("shape")) != NULL;
+    ok = ok && (str_class = PyUnicode_InternFromString("__class__")) != NULL;
+    Py_XDECREF(numpy);
+    return ok ? 0 : -1;
+}
+
 PyMODINIT_FUNC PyInit__pathway_native(void) {
     PyObject *m;
+    if (lookup_numpy() < 0) return NULL;
     if (PyType_Ready(&KeyTableType) < 0) return NULL;
     m = PyModule_Create(&module);
     if (m == NULL) return NULL;

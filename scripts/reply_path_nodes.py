@@ -9,7 +9,9 @@ over a pre-embedded store shaped as ``DocumentStore``'s ``vector_column``
 branch shapes it (indexed over the vectors, repacked from ``text`` and
 ``_metadata``), with every node's ``process`` timed: 20,000 rows, and in
 every tick 8 new queries and the retraction of the 8 before, as a tick takes
-the answers of the tick before back. It prints each node's median a tick;
+the answers of the tick before back. It prints each node's median a tick,
+and beside it the values a tick that the native hash handed back to Python
+(``hash_fallback_calls_total``, ``docs/observability.md``);
 ``--profile Flatten`` (any node class) also prints cProfile's view of that
 class's ``process``.
 
@@ -47,21 +49,26 @@ def _node_classes(cls=Node):
         yield from _node_classes(sub)
 
 
-def time_nodes(spent: dict, profiled: str | None, profile: cProfile.Profile) -> None:
+def time_nodes(
+    spent: dict, fallbacks: dict, profiled: str | None, profile: cProfile.Profile
+) -> None:
     """Wrap ``process`` of every node class that defines one: a call's
-    seconds go to ``spent[(label, tick time)]``."""
+    seconds go to ``spent[(label, tick time)]``, and the values its hashing
+    gave back to Python to ``fallbacks`` under the same key."""
+    stats = fusion.FUSION_STATS
 
     def timed(process, profiled_here):
         def wrapper(self, time_, ins):
             if profiled_here:
                 profile.enable()
+            calls0 = stats["hash_fallback_calls_total"]
             t0 = time.perf_counter()
             try:
                 return process(self, time_, ins)
             finally:
-                spent[f"{type(self).__name__}#{self.node_id}", time_] += (
-                    time.perf_counter() - t0
-                )
+                key = f"{type(self).__name__}#{self.node_id}", time_
+                spent[key] += time.perf_counter() - t0
+                fallbacks[key] += stats["hash_fallback_calls_total"] - calls0
                 if profiled_here:
                     profile.disable()
 
@@ -119,25 +126,31 @@ def main() -> int:
     args = ap.parse_args()
 
     spent: dict = collections.defaultdict(float)
+    fallbacks: dict = collections.defaultdict(int)
     profile = cProfile.Profile()
-    time_nodes(spent, args.profile, profile)
+    time_nodes(spent, fallbacks, args.profile, profile)
     build(args.rows, args.dim, args.per_tick, args.ticks, args.k, args.seed)
     pw.run()
 
     by_node = collections.defaultdict(list)
+    handed_back = collections.defaultdict(int)
     # the first query ticks compile the search and have nothing to take back
     steady = {t for _, t in spent if t > 2 * 4 and t <= 2 * args.ticks}
     for (label, t), seconds in spent.items():
         if t in steady:
             by_node[label].append(seconds * 1e3)
+            handed_back[label] += fallbacks[label, t]
     print(f"{len(steady)} steady ticks of {args.per_tick} queries in and "
           f"{args.per_tick} out, k = {args.k}, over {args.rows} rows")
-    print(f"{'node':28s} {'ticks':>6s} {'median ms':>10s} {'mean ms':>9s}")
+    print(f"{'node':28s} {'ticks':>6s} {'median ms':>10s} {'mean ms':>9s} "
+          f"{'fallbacks a tick':>17s}")
     for label, ms in sorted(by_node.items(), key=lambda kv: -statistics.median(kv[1])):
         if len(ms) * 2 < len(steady):
             continue  # the documents' side: it worked once, at the start
         print(f"{label:28s} {len(ms):6d} {statistics.median(ms):10.3f} "
-              f"{statistics.fmean(ms):9.3f}")
+              f"{statistics.fmean(ms):9.3f} {handed_back[label] / len(ms):17.1f}")
+    print(f"values the native hash handed back to Python, all nodes: "
+          f"{sum(handed_back.values()) / max(len(steady), 1):.1f} a tick")
     if args.profile:
         pstats.Stats(profile).sort_stats("cumulative").print_stats(18)
     return 0
