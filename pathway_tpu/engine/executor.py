@@ -799,6 +799,9 @@ class Executor:
         #: perf_counter_ns of the first park since the last round (the
         #: open ``engine.park`` span), None while the loop has work
         self._park_t0: int | None = None
+        #: perf_counter_ns of the top of the loop iteration that is polling
+        #: its sources (the open ``engine.poll`` span), else None
+        self._poll_t0: int | None = None
         # spill-to-disk state budget (engine/spill.py): None unless
         # PATHWAY_STATE_MEMORY_BUDGET_MB is set — one None check per tick
         from . import spill as _spill
@@ -979,6 +982,7 @@ class Executor:
         self.stats.sources_connected = True
         try:
             while not self._stop_requested:
+                self._begin_poll()
                 self.stats.heartbeat()
                 # each commit batch of a source gets its own timestamp;
                 # batch j of every source shares round j's tick
@@ -999,6 +1003,7 @@ class Executor:
                             stamps[j] if j < len(stamps) else None,
                         )
                 if rounds:
+                    self._end_poll(len(realtime), rounds)
                     for j, emissions in enumerate(rounds):
                         # even wall-clock ms, strictly increasing (timestamp.rs)
                         wall = int(_time.time() * 1000) & ~1
@@ -1019,11 +1024,13 @@ class Executor:
                 else:
                     # park until data arrives (waker) or the poll interval
                     # lapses (step_or_park's timed wait)
+                    self._end_poll(len(realtime), rounds)
                     if self._park_t0 is None:
                         self._park_t0 = _time.perf_counter_ns()
                     wake.wait(0.005)
                     wake.clear()
         finally:
+            self._end_poll(len(realtime), [])
             self._end_park()
             for src in realtime:
                 src.stop()
@@ -1050,6 +1057,7 @@ class Executor:
         cycle = 0
         try:
             while True:
+                self._begin_poll()
                 self.stats.heartbeat()
                 rounds: list[list[tuple[SourceNode, Delta]]] = []
                 cycle_ingest: int | None = None
@@ -1087,6 +1095,8 @@ class Executor:
                 for p in gathered:
                     if len(p) > 5:  # mixed-version tolerance
                         agreed_ingest = _min_stamp(agreed_ingest, p[5])
+                if n_rounds:
+                    self._end_poll(len(owned), rounds)
                 for j in range(n_rounds):
                     # identical on every worker: deterministic fn of the
                     # gathered payload and the shared tick history
@@ -1113,6 +1123,7 @@ class Executor:
                     # park until owned-source data arrives or the poll
                     # interval lapses; peers' data surfaces via the next
                     # cycle's allgather either way
+                    self._end_poll(len(owned), rounds)
                     park_t0 = _time.perf_counter_ns()
                     if self._park_t0 is None:
                         self._park_t0 = park_t0
@@ -1120,6 +1131,7 @@ class Executor:
                     wake.clear()
                     self._idle_park_ns += _time.perf_counter_ns() - park_t0
         finally:
+            self._end_poll(len(owned), [])
             self._end_park()
             for src in owned:
                 src.stop()
@@ -1217,6 +1229,7 @@ class Executor:
         try:
             plane.broadcast_status({"ep": 0})
             while True:
+                self._begin_poll()
                 self.stats.heartbeat()
                 plane.drain()
                 worked = False
@@ -1244,6 +1257,8 @@ class Executor:
                                 ingest[j],
                                 stamps[j] if j < len(stamps) else None,
                             )
+                if rounds:
+                    self._end_poll(len(owned), rounds)
                 for j, emissions in enumerate(rounds):
                     clock = self._mint(clock)
                     self._next_tick_ingest_ns = _min_stamp(
@@ -1255,6 +1270,7 @@ class Executor:
                 #    is always_run, so round sweeps above already took
                 #    them) get a sweep of their own
                 if not rounds and plane.releasable():
+                    self._end_poll(len(owned), rounds)
                     clock = self._mint(clock)
                     self._next_tick_ingest_ns = plane.pending_ingest_ns()
                     self._tick(clock, [])
@@ -1354,6 +1370,7 @@ class Executor:
                                 stalled=stalled,
                             )
                             stall_logged = True
+                    self._end_poll(len(owned), rounds)
                     park_t0 = _time.perf_counter_ns()
                     if self._park_t0 is None:
                         self._park_t0 = park_t0
@@ -1380,6 +1397,7 @@ class Executor:
                     frontier=plane.tracker.local(), epochs=epoch,
                 )
         finally:
+            self._end_poll(len(owned), [])
             self._end_park()
             for src in owned:
                 src.stop()
@@ -1781,6 +1799,37 @@ class Executor:
         self.persistence.begin_recording(owned_sources(realtime, self.ctx))
         return clock
 
+    def _begin_poll(self) -> None:
+        """Top of a streaming loop's iteration: open ``engine.poll``, which
+        lasts to the iteration's first tick or to the park. Inside an open
+        park the polls are the park's; one left open (a commit wave
+        restarted the iteration) goes on."""
+        if (
+            self._park_t0 is None
+            and self._poll_t0 is None
+            and _tracing.get_tracer() is not None
+        ):
+            import time as _time
+
+            self._poll_t0 = _time.perf_counter_ns()
+
+    def _end_poll(self, sources: int, rounds: list) -> None:
+        """Close the open ``engine.poll``: the rounds are formed and the
+        first of them ticks next, or there is none and the loop parks."""
+        t0, self._poll_t0 = self._poll_t0, None
+        if t0 is not None:
+            tracer = _tracing.get_tracer()
+            if tracer is not None:
+                tracer.complete(
+                    "engine.poll",
+                    t0,
+                    {
+                        "sources": sources,
+                        "rounds": len(rounds),
+                        "rows": sum(len(d) for r in rounds for _, d in r),
+                    },
+                )
+
     def _end_park(self) -> None:
         """Close the open ``engine.park`` span: the loop found a round (or
         is ending). Consecutive 5 ms waits are one span."""
@@ -1867,19 +1916,27 @@ class Executor:
                         self.stats.output_rows += sum(
                             len(d) for d in ins if d is not None
                         )
-                    if node.error_scope is not None:
-                        # errors raised during this node's processing carry
-                        # its table's local_error_log scope (thread-local:
-                        # one worker per thread under sharding)
-                        from .error import set_current_scope
+                    # a span entered inside ``process`` names the node as
+                    # its parent and carries the tick's id
+                    with (
+                        tracer.scope(node._op_label, tick=time)
+                        if tracer is not None
+                        else _tracing._NO_SPAN
+                    ):
+                        if node.error_scope is not None:
+                            # errors raised during this node's processing
+                            # carry its table's local_error_log scope
+                            # (thread-local: one worker per thread under
+                            # sharding)
+                            from .error import set_current_scope
 
-                        set_current_scope(node.error_scope)
-                        try:
+                            set_current_scope(node.error_scope)
+                            try:
+                                out = node.process(time, ins)
+                            finally:
+                                set_current_scope(None)
+                        else:
                             out = node.process(time, ins)
-                        finally:
-                            set_current_scope(None)
-                    else:
-                        out = node.process(time, ins)
                     if out is not None and len(out):
                         out_parts.append(out)
             if self.persistence is not None and node.has_state() and (
@@ -1903,9 +1960,9 @@ class Executor:
                 # exact hot spot a trace exists to show)
                 if tracer is not None:
                     tracer.complete(
-                        f"{type(node).__name__}#{node.node_id}",
+                        node._op_label,
                         node_t0,
-                        {"rows": emitted_rows},
+                        {"rows": emitted_rows, "tick": time, "parent": "tick"},
                     )
                 if self.stats.detailed and not getattr(
                     node, "ATTRIBUTES_MEMBERS", False

@@ -18,6 +18,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from ..internals import tracing as _tracing
 from . import keys as K
 from .delta import (
     Delta,
@@ -1224,7 +1225,31 @@ class GroupByReduce(Node):
                 self._gvals[ci] = stored = stored.astype(object)
             stored[fresh_slots] = col[fresh_first_ix]
 
+    def _update_then_emit(self, path: str, n: int, update, args, emit):
+        """The two phases of either path: ``update(*args)`` folds the batch
+        into the state and gives the groups touched, ``emit`` turns them into
+        the output delta."""
+        with _tracing.span(
+            "groupby.update", rows=n, reducers=len(self._reducers), path=path
+        ) as sp:
+            touched = update(*args)
+            if sp is not None:
+                sp.args["groups"] = len(touched)
+        with _tracing.span("groupby.emit") as sp:
+            out = emit(touched)
+            if sp is not None:
+                sp.args["rows"] = 0 if out is None else len(out)
+        return out
+
     def _process_dense(self, d, n, gcols, gkeys, arg_arrays) -> Delta | None:
+        return self._update_then_emit(
+            "dense", n, self._update_dense, (d, n, gcols, gkeys, arg_arrays),
+            self._emit_dense,
+        )
+
+    def _update_dense(self, d, n, gcols, gkeys, arg_arrays) -> np.ndarray:
+        """Fold the batch into the arena; the touched slots, ascending and
+        relative to the arena's base."""
         self._reclaim_arena()
         slots, n_new = self._slots.lookup_or_insert(gkeys)
         if self._arena_base and int(slots.min()) < self._arena_base:
@@ -1296,7 +1321,11 @@ class GroupByReduce(Node):
                     self._prev[j] = self._prev[j].astype(np.float64)
                 contrib = arr.astype(acc.dtype) * d.diffs
                 acc[u_slots] += np.add.reduceat(contrib[order], starts)
+        return u_slots
 
+    def _emit_dense(self, u_slots: np.ndarray) -> Delta | None:
+        """Retractions and insertions of the touched groups whose output
+        row changed, and the emission bookkeeping."""
         new_counts = self._counts[u_slots]
         if (new_counts < 0).any():
             raise ValueError("negative multiplicity in groupby input")
@@ -1377,6 +1406,14 @@ class GroupByReduce(Node):
     # -- general path ----------------------------------------------------
 
     def _process_general(self, d, n, gcols, gkeys, time) -> Delta | None:
+        return self._update_then_emit(
+            "general", n, self._update_general, (d, n, gcols, gkeys, time),
+            self._emit_general,
+        )
+
+    def _update_general(self, d, n, gcols, gkeys, time) -> dict[int, None]:
+        """Feed every row to its group's reducers; the group keys touched,
+        in first-touch order."""
         if self._cold_set:
             self._fault_in_groups(gkeys)
         if self._budget is not None:
@@ -1419,7 +1456,11 @@ class GroupByReduce(Node):
                     continue
                 st[2][j] = red.update(st[2][j], vals, diff, row_key, time)
             affected[gk] = None
+        return affected
 
+    def _emit_general(self, affected: dict[int, None]) -> Delta | None:
+        """The touched groups' old rows retracted and new rows inserted,
+        where they differ."""
         out_keys: list[int] = []
         out_rows: list[tuple] = []
         out_diffs: list[int] = []
@@ -2501,7 +2542,7 @@ class Join(Node):
         # L_old ⋈ dR
         if self._emit_matched and self._react_to_right and right is not None:
             r_jks, r_keys, r_cols, r_diffs = right
-            for qi, lkeys, lcols, lcounts in self._cleft.probe(r_jks):
+            for qi, lkeys, lcols, lcounts in self._probe(self._cleft, r_jks):
                 emit(
                     lkeys, r_keys[qi], lcols,
                     [np.asarray(c)[qi] for c in r_cols],
@@ -2509,11 +2550,11 @@ class Join(Node):
                 )
         # apply dR
         if right is not None:
-            self._cright.apply(*right)
+            self._apply_side(self._cright, "right", right)
         # dL ⋈ R_new
         if self._emit_matched and left is not None:
             l_jks, l_keys, l_cols, l_diffs = left
-            for qi, rkeys, rcols, rcounts in self._cright.probe(l_jks):
+            for qi, rkeys, rcols, rcounts in self._probe(self._cright, l_jks):
                 emit(
                     l_keys[qi], rkeys,
                     [np.asarray(c)[qi] for c in l_cols], rcols,
@@ -2521,7 +2562,7 @@ class Join(Node):
                 )
         # apply dL
         if left is not None:
-            self._cleft.apply(*left)
+            self._apply_side(self._cleft, "left", left)
         # post-apply pad snapshots: (new pads) + the pre-apply (− old pads)
         # already in `parts` net to exactly the pad transitions
         if affected_l is not None:
@@ -2540,6 +2581,37 @@ class Join(Node):
         return concat_deltas(parts, self.column_names).consolidated(
             multiset_ok=True
         )
+
+    @staticmethod
+    def _probe(arr: _SortedSide, qjks: np.ndarray) -> list[tuple]:
+        """``arr.probe(qjks)`` taken whole before any of it is emitted: one
+        stretch of the node's time (``join.probe``)."""
+        with _tracing.span("join.probe", rows=len(qjks)) as sp:
+            found = list(arr.probe(qjks))
+            if sp is not None:
+                sp.args["matches"] = sum(len(chunk[0]) for chunk in found)
+        return found
+
+    @staticmethod
+    def _apply_side(arr: _SortedSide, side: str, delta: tuple) -> None:
+        """``arr.apply`` of one side's delta (``join.consolidate``): the run
+        sorted and merged into the tiers, where ``_SortedSide._consolidate``
+        nets a retraction against its insert; ``hashed`` counts the rows
+        whose content that hashed. (A batch of 256 rows or more is deferred
+        to the next probe, and its merge is that probe's.)"""
+        with _tracing.span(
+            "join.consolidate", side=side, rows=len(delta[0])
+        ) as sp:
+            if sp is None:
+                arr.apply(*delta)
+                return
+            from .fusion import FUSION_STATS
+
+            before = FUSION_STATS["consolidation_rows_hashed_total"]
+            arr.apply(*delta)
+            sp.args["hashed"] = (
+                FUSION_STATS["consolidation_rows_hashed_total"] - before
+            )
 
     @staticmethod
     def _affected_jks(this, other) -> np.ndarray | None:
@@ -3738,39 +3810,47 @@ class Subscribe(Node):
         d = d.consolidated()
         if self._on_batch is not None and len(d):
             self._on_batch(time, d)
-        if self._on_change is not None:
-            # one pass per tick: bulk tolist + C-speed zip transposition,
-            # vectorized diff>0, and dict-display row building for the
-            # common narrow schemas — the per-row work is exactly the
-            # dict the callback signature requires plus the call itself
-            cb = self._on_change
-            names = tuple(self.column_names)
-            cols = [np.asarray(d.data[c]).tolist() for c in names]
-            keys_l = d.keys.tolist()
-            adds = (d.diffs > 0).tolist()
-            if len(names) == 1:
-                n0 = names[0]
-                for key, add, v0 in zip(keys_l, adds, cols[0]):
-                    cb(key=key, row={n0: v0}, time=time, is_addition=add)
-            elif len(names) == 2:
-                n0, n1 = names
-                for key, add, v0, v1 in zip(keys_l, adds, cols[0], cols[1]):
-                    cb(
-                        key=key, row={n0: v0, n1: v1},
-                        time=time, is_addition=add,
-                    )
-            else:
-                rows = zip(*cols) if cols else iter([()] * len(d))
-                for key, add, row in zip(keys_l, adds, rows):
-                    cb(
-                        key=key,
-                        row=dict(zip(names, row)),
-                        time=time,
-                        is_addition=add,
-                    )
-        if self._on_time_end is not None and time != END_TIME:
-            self._on_time_end(time)
+        # the subscriber's own code: ``on_change`` a row, then the node's
+        # ``on_time_end`` (where the REST response writer resolves the tick's
+        # futures and commits their retractions)
+        with _tracing.span("subscribe.deliver", rows=len(d)):
+            if self._on_change is not None:
+                self._deliver(d, time)
+            if self._on_time_end is not None and time != END_TIME:
+                self._on_time_end(time)
         return None
+
+    def _deliver(self, d: Delta, time: int) -> None:
+        """``on_change`` for every row of the tick's consolidated delta."""
+        # one pass per tick: bulk tolist + C-speed zip transposition,
+        # vectorized diff>0, and dict-display row building for the
+        # common narrow schemas — the per-row work is exactly the
+        # dict the callback signature requires plus the call itself
+        cb = self._on_change
+        names = tuple(self.column_names)
+        cols = [np.asarray(d.data[c]).tolist() for c in names]
+        keys_l = d.keys.tolist()
+        adds = (d.diffs > 0).tolist()
+        if len(names) == 1:
+            n0 = names[0]
+            for key, add, v0 in zip(keys_l, adds, cols[0]):
+                cb(key=key, row={n0: v0}, time=time, is_addition=add)
+        elif len(names) == 2:
+            n0, n1 = names
+            for key, add, v0, v1 in zip(keys_l, adds, cols[0], cols[1]):
+                cb(
+                    key=key, row={n0: v0, n1: v1},
+                    time=time, is_addition=add,
+                )
+        else:
+            rows = zip(*cols) if cols else iter([()] * len(d))
+            for key, add, row in zip(keys_l, adds, rows):
+                cb(
+                    key=key,
+                    row=dict(zip(names, row)),
+                    time=time,
+                    is_addition=add,
+                )
 
     def on_end(self) -> Delta | None:
         if self._on_end_cb is not None:

@@ -39,16 +39,25 @@ Span taxonomy (mirrors the reference's span names where it has them):
 - ``engine.run`` — the whole executor run;
 - ``tick`` — one logical-time sweep, with the minted timestamp attached;
 - per-node events under each tick, named ``<NodeClass>#<id>``, with the
-  emitted row count — the analog of timely's event logging stream
-  (``DIFFERENTIAL_LOG_ADDR``, reference ``dataflow.rs:5540-5548``);
-- counter samples of ``EngineStats`` totals per tick, rendered by the
+  emitted row count, the ``tick`` and ``parent: "tick"`` — the analog of
+  timely's event logging stream (``DIFFERENTIAL_LOG_ADDR``, reference
+  ``dataflow.rs:5540-5548``). While a node's ``process`` runs,
+  :meth:`Tracer.scope` names it in the span context, so a span inside an
+  operator carries ``parent: "<NodeClass>#<id>"`` and the tick's id: the
+  phases ``groupby.update`` / ``groupby.emit``, ``join.probe`` /
+  ``join.consolidate`` and ``subscribe.deliver`` (``engine/operators.py``),
+  one span a call;
+- counter samples of ``EngineStats`` totals per tick (they ride the
+  tick's own append: ``complete(..., counter=...)``), rendered by the
   trace viewers as time series, one ``serve_stats`` sample of the
   ``serve/stats.py`` counters at each flush, and a ``fusion_stats`` sample
   of ``engine/fusion.py``'s at a profiler session's first span and at
   each flush;
 - the serving path: ``rest.request`` / ``rest.admit`` / ``rest.in_engine``
-  / ``rest.reply`` (``io/http/_server.py``), ``connector.window``
-  (``io/python.py``), ``engine.park`` (the streaming loops),
+  / ``rest.wake`` / ``rest.reply`` (``io/http/_server.py``),
+  ``connector.window`` (``io/python.py``), ``engine.poll`` and
+  ``engine.park`` (the streaming loops: with ``tick`` they cover the engine
+  thread from one tick to the next),
   ``index.apply`` (``engine/external_index.py``), ``index.search`` with
   ``index.embed`` / ``index.upload`` / ``index.score`` / ``index.fetch`` /
   ``index.pack`` (``ops/index_engines.py``), ``embed.tokenize`` /
@@ -95,7 +104,7 @@ __all__ = [
 
 #: the span this thread (or asyncio task) is inside of, for ``parent`` and
 #: the inherited ``req`` / ``tick`` identifier
-_current: "contextvars.ContextVar[_Span | None]" = contextvars.ContextVar(
+_current: "contextvars.ContextVar[_Span | _Scope | None]" = contextvars.ContextVar(
     "pathway_span", default=None
 )
 _ID_KEYS = ("req", "tick")
@@ -131,7 +140,7 @@ def make_flow_id(tracer: "Tracer", tag: str, *coords: Any) -> str:
     return "/".join([tracer.run_id, tag, *map(str, coords)])
 
 
-def _ids_of(outer: "_Span | None") -> dict[str, Any]:
+def _ids_of(outer: "_Span | _Scope | None") -> dict[str, Any]:
     """The ``req`` / ``tick`` a span hands down to what runs inside it."""
     if outer is None:
         return {}
@@ -173,6 +182,26 @@ class _Span:
         _current.reset(self._token)
         if self._ann is not None:
             self._ann.__exit__(*exc)
+
+
+class _Scope:
+    """What ``_current`` names while code runs on behalf of an event that
+    ``complete()`` records when it ends (a node of a tick): a span entered
+    meanwhile takes ``name`` as its ``parent`` and inherits the ids. Reads
+    no clock, enters no annotation, records nothing itself."""
+
+    __slots__ = ("name", "args", "_token")
+
+    def __init__(self, name: str, args: dict[str, Any]):
+        self.name = name
+        self.args = args
+
+    def __enter__(self) -> "_Scope":
+        self._token = _current.set(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _current.reset(self._token)
 
 
 class Tracer:
@@ -232,6 +261,12 @@ class Tracer:
     def span(self, name: str, **args: Any) -> _Span:
         """``with tracer.span("graph.build", tables=3): ...``"""
         return _Span(self, name, args)
+
+    def scope(self, name: str, **ids: Any) -> _Scope:
+        """``with tracer.scope("Join#23", tick=t): ...`` around work whose
+        own event is a ``complete()`` at its end: the spans inside name it
+        as parent."""
+        return _Scope(name, ids)
 
     def complete(
         self,
@@ -316,13 +351,6 @@ class Tracer:
         from ..engine.fusion import FUSION_STATS
 
         return self._counter_event("fusion_stats", dict(FUSION_STATS))
-
-    def counter(self, name: str, values: dict[str, float]) -> None:
-        """A counter sample (rendered as stacked time series). Callers with
-        per-worker counters must put the worker id in ``name`` — trace
-        viewers key counter tracks by (pid, name), so same-named samples
-        from different workers would interleave into one garbled series."""
-        self._append(self._counter_event(name, values))
 
     # -- cross-worker flow linkage ------------------------------------
 
@@ -437,6 +465,9 @@ class Tracer:
                     "process_id": process_id,
                     "origin_unix_ns": self.origin_unix_ns,
                     "origin_monotonic_ns": self.origin_monotonic_ns,
+                    # how long a thread may hold the interpreter lock while
+                    # another waits for it: what ``rest.wake`` is read against
+                    "switch_interval_s": sys.getswitchinterval(),
                     "clock_offsets": {
                         str(p): [off, rtt]
                         for p, (off, rtt) in sorted(

@@ -210,11 +210,13 @@ class _RestSubject(ConnectorSubject):
         self.delete_completed_queries = delete_completed_queries
         self.request_validator = request_validator
         self._futures: dict[int, asyncio.Future] = {}
-        #: request key -> perf_counter_ns at which its row went to the
-        #: engine: the open ``rest.in_engine`` span, closed from
-        #: ``_complete`` on the engine thread (kept only while spans are
-        #: recorded)
-        self._engine_t0: dict[int, int] = {}
+        #: request key -> perf_counter_ns at which the request changed
+        #: threads, the start of its span that is open across the two: the
+        #: row sent to the engine (``rest.in_engine``, closed by
+        #: ``_complete`` on the engine thread), then the future resolved
+        #: (``rest.wake``, closed by the handler when it resumes). Kept only
+        #: while spans are recorded
+        self._handoff_t0: dict[int, int] = {}
         self._rows: dict[int, dict[str, Any]] = {}
         self._names = schema.column_names()
         webserver._add_route(route, methods, self._handle)
@@ -337,7 +339,7 @@ class _RestSubject(ConnectorSubject):
                 key, _time.time_ns() + int(deadline_ms * 1e6)
             )
             if get_tracer() is not None:
-                self._engine_t0[key] = _time.perf_counter_ns()
+                self._handoff_t0[key] = _time.perf_counter_ns()
             self._next_with_key(key, **row)
             self.commit()
             remaining_s = max(0.001, deadline_ms / 1e3 - (loop.time() - t0))
@@ -345,9 +347,20 @@ class _RestSubject(ConnectorSubject):
                 result = await asyncio.wait_for(fut, timeout=remaining_s)
             except asyncio.TimeoutError:
                 self._futures.pop(key, None)
-                self._engine_t0.pop(key, None)
+                self._handoff_t0.pop(key, None)
                 serve_bump("deadline_dropped_total")
                 return web.json_response({"error": "timeout"}, status=504)
+            resolved_ns = self._handoff_t0.pop(key, None)
+            if resolved_ns is not None:
+                tracer = get_tracer()
+                if tracer is not None:
+                    # the engine thread resolved the future then; this task
+                    # runs again only now (the loop's turn, and the
+                    # interpreter lock the engine thread holds)
+                    tracer.complete(
+                        "rest.wake", resolved_ns,
+                        {"req": key, "parent": "rest.request"},
+                    )
             with span("rest.reply"):
                 if isinstance(result, Json):
                     result = result.value
@@ -370,7 +383,7 @@ class _RestSubject(ConnectorSubject):
             # client disconnected mid-flight: free the slot now, drop the
             # pending future (the engine's late answer finds nobody)
             self._futures.pop(key, None)
-            self._engine_t0.pop(key, None)
+            self._handoff_t0.pop(key, None)
             ctrl.cancel(slot)
             slot = None
             raise
@@ -380,7 +393,8 @@ class _RestSubject(ConnectorSubject):
 
     def _complete(self, key: int, value: Any) -> None:
         """Called from the engine thread by the response writer sink."""
-        t0_ns = self._engine_t0.pop(key, None)
+        t0_ns = self._handoff_t0.pop(key, None)
+        tracer = None
         if t0_ns is not None:
             from ...internals import tracing
 
@@ -397,6 +411,10 @@ class _RestSubject(ConnectorSubject):
         if fut is not None and not fut.done():
             loop = self.webserver._loop
             if loop is not None and loop.is_running():
+                if tracer is not None:
+                    import time as _time
+
+                    self._handoff_t0[key] = _time.perf_counter_ns()
                 loop.call_soon_threadsafe(
                     lambda: None if fut.done() else fut.set_result(value)
                 )

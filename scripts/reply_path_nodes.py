@@ -1,9 +1,11 @@
 #!/usr/bin/env python
 """What each node of the reply path costs a tick, on this machine's CPU.
 
-A traced chip run gives a node's time (``<NodeClass>#<id>`` events,
-``benchmark/tests/dump_spans.py``) and nothing of what is inside it. This
-script runs the same dataflow a ``/v1/retrieve`` tick runs after the search,
+On the chip's host a traced run gives a node's time and its phases
+(``benchmark/tests/dump_nodes.py``, after ``benchmark/run.py --trace 1``);
+this is the sizing tool for a sandbox with no chip, and the one that profiles
+a node function by function. It runs the same dataflow a ``/v1/retrieve`` tick
+runs after the search,
 ``DataIndex.query_as_of_now(..., number_of_matches=10, collapse_rows=True)``
 over a pre-embedded store shaped as ``DocumentStore``'s ``vector_column``
 branch shapes it (indexed over the vectors, repacked from ``text`` and
@@ -16,7 +18,9 @@ and beside it the values a tick that the native hash handed back to Python
 class's ``process``.
 
 The proportions are what carries over to the chip's host, not the
-milliseconds (PERF.md section 7). JAX runs the search here, on the CPU:
+milliseconds (PERF.md sections 5 and 7; ``Subscribe`` has no REST response
+writer here, so its ``on_time_end`` is missing). JAX runs the search here, on
+the CPU:
 
     JAX_PLATFORMS=cpu python scripts/reply_path_nodes.py [--profile Join]
 """

@@ -70,9 +70,9 @@ def collector():
 def _traced_tracer() -> Tracer:
     tracer = Tracer(None)
     with tracer.span("engine.run", worker=0):
-        with tracer.span("tick", time=42):
-            pass
-    tracer.counter("engine_rows.w0", {"input": 5.0, "output": 3.0})
+        # as the executor does it: the row counters ride the tick's append
+        with tracer.span("tick", time=42) as tick:
+            tick.counter = ("engine_rows.w0", {"input": 5.0, "output": 3.0})
     return tracer
 
 

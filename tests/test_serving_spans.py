@@ -168,7 +168,9 @@ def test_every_span_of_the_serving_path_with_shared_ids(tmp_path):
     searches = {e["args"]["tick"]: e for e in by_name["index.search"]}
     assert len(searches) == 4
     for tick_id, s in searches.items():
-        assert s["args"]["parent"] == "tick" and s["args"]["q"] == 1 and s["args"]["k"] == 3
+        # under the node that searched, which is an event of that tick
+        assert s["args"]["parent"].startswith("ExternalIndexNode#")
+        assert s["args"]["q"] == 1 and s["args"]["k"] == 3
         assert _inside(s, ticks[tick_id])
     assert [s["args"]["dirty"] for s in by_name["index.search"]] == [True, False, False, False]
     for name in (*SEARCH_SPANS, "index.upload"):

@@ -149,9 +149,11 @@ def test_event_buffer_is_bounded(tmp_path):
 
 def test_programmatic_activation(tmp_path):
     tracer = tracing.activate(str(tmp_path / "prog.json"))
-    with tracer.span("outer", k=1):
+    # a counter sample rides its span's own append, as the executor's row
+    # counters ride the tick's (``complete(..., counter=...)``)
+    with tracer.span("outer", k=1) as sp:
         tracer.instant("marker")
-    tracer.counter("c", {"v": 2.0})
+        sp.counter = ("c", {"v": 2.0})
     written = tracer.flush()
     assert written == str(tmp_path / "prog.json")
     doc = json.loads((tmp_path / "prog.json").read_text())
@@ -419,9 +421,12 @@ def test_otlp_exporter_payload_shapes():
     from pathway_tpu.internals.tracing import Tracer
 
     tracer = Tracer(None)
-    with tracer.span("graph.build", tables=2):
-        pass
-    tracer.counter("engine.rows", {"ingested": 42.0})
+    import time as _time
+
+    tracer.complete(
+        "graph.build", _time.perf_counter_ns(), {"tables": 2},
+        counter=("engine.rows", {"ingested": 42.0}),
+    )
     exp = OtlpExporter("http://127.0.0.1:1", run_id="r1")
     spans = exp.spans_payload(tracer._events, 1_000_000_000)
     span_list = spans["resourceSpans"][0]["scopeSpans"][0]["spans"]
