@@ -99,6 +99,11 @@ FUSION_STATS: dict[str, int] = {
     # the Python ladder of engine/keys.py, one a lane, wherever an object
     # column is hashed; stays 0 without the native module
     "hash_fallback_calls_total": 0,
+    # GroupByReduce's general path (engine/operators.py _update_general):
+    # rows x reducers it was fed, and of those the ones a reducer gave by
+    # column (`_MultisetReducer._entries`) and the column loop folded
+    "groupby_rows_total": 0,
+    "groupby_rows_by_column_total": 0,
 }
 
 

@@ -29,7 +29,8 @@ from .delta import (
 )
 from .error import ERROR_LOG, Error as EngineError, errors_seen, is_error
 from .executor import END_TIME, Node, SourceNode
-from .reducers import ReducerImpl
+from .fusion import FUSION_STATS
+from .reducers import ReducerImpl, _MultisetReducer
 from .state import MultiIndex, RowState
 
 CompiledExpr = Callable[[dict[str, np.ndarray], np.ndarray], np.ndarray]
@@ -1096,8 +1097,6 @@ class GroupByReduce(Node):
         if self._key_from_column is not None:
             gkeys = np.asarray(d.data[self._key_from_column], dtype=np.uint64)
         elif reuse_keys is not None and len(reuse_keys) == n:
-            from .fusion import FUSION_STATS
-
             FUSION_STATS["key_reuse_total"] += 1
             gkeys = reuse_keys
         else:
@@ -1225,12 +1224,13 @@ class GroupByReduce(Node):
                 self._gvals[ci] = stored = stored.astype(object)
             stored[fresh_slots] = col[fresh_first_ix]
 
-    def _update_then_emit(self, path: str, n: int, update, args, emit):
+    def _update_then_emit(self, path: str, n: int, update, args, emit, **how):
         """The two phases of either path: ``update(*args)`` folds the batch
         into the state and gives the groups touched, ``emit`` turns them into
-        the output delta."""
+        the output delta. ``how`` is what else the update's span says."""
         with _tracing.span(
-            "groupby.update", rows=n, reducers=len(self._reducers), path=path
+            "groupby.update", rows=n, reducers=len(self._reducers), path=path,
+            **how,
         ) as sp:
             touched = update(*args)
             if sp is not None:
@@ -1406,24 +1406,37 @@ class GroupByReduce(Node):
     # -- general path ----------------------------------------------------
 
     def _process_general(self, d, n, gcols, gkeys, time) -> Delta | None:
+        # Error-aware only when errors exist at all (the errors_seen latch
+        # trips on every Error construction/unpickle — zero-cost guard on
+        # clean pipelines, immune to ERROR_LOG.clear() and state restores).
+        # Until it trips no cell can hold an Error and a batch is folded by
+        # column; after, the row loop watches every cell. One row is folded
+        # as a row: there is nothing to share and no call to save
+        by_column = n > 1 and not errors_seen()
         return self._update_then_emit(
-            "general", n, self._update_general, (d, n, gcols, gkeys, time),
-            self._emit_general,
+            "general", n, self._update_general,
+            (d, n, gcols, gkeys, time, by_column), self._emit_general,
+            loop="column" if by_column else "row",
         )
 
-    def _update_general(self, d, n, gcols, gkeys, time) -> dict[int, None]:
+    def _update_general(
+        self, d, n, gcols, gkeys, time, by_column
+    ) -> dict[int, None]:
         """Feed every row to its group's reducers; the group keys touched,
-        in first-touch order."""
+        in first-touch order. Both loops write the same state: an entry one
+        of them put in, the other takes out."""
         if self._cold_set:
             self._fault_in_groups(gkeys)
         if self._budget is not None:
             batch = set(map(int, gkeys.tolist()))
             self._recent_hist.append(batch)
             self._recent_gks = set().union(*self._recent_hist)
+        FUSION_STATS["groupby_rows_total"] += n * len(self._reducers)
         arg_cols = [[d.data[a] for a in args] for _, _, args in self._reducers]
-        # Error-aware only when errors exist at all (the errors_seen latch
-        # trips on every Error construction/unpickle — zero-cost guard on
-        # clean pipelines, immune to ERROR_LOG.clear() and state restores)
+        if by_column:
+            return self._fold_columns(d, n, gcols, gkeys, time, arg_cols)
+        # the row loop, a row and a reducer at a time: a batch of one row,
+        # and a batch that may hold an Error, watched cell by cell
         watch_errors = errors_seen()
         affected: dict[int, None] = {}
         for i in range(n):
@@ -1455,6 +1468,52 @@ class GroupByReduce(Node):
                     errs[j] += diff
                     continue
                 st[2][j] = red.update(st[2][j], vals, diff, row_key, time)
+            affected[gk] = None
+        return affected
+
+    def _fold_columns(self, d, n, gcols, gkeys, time, arg_cols) -> dict[int, None]:
+        """The column loop: every multiset reducer gives the batch's entries
+        at once (`_MultisetReducer._entries`) and one pass over the rows folds
+        them (`_MultisetReducer.update`, inlined); the other reducers are fed
+        by the same pass through ``update``, so each sees the rows in order."""
+        gks = gkeys.tolist()
+        diffs = d.diffs.tolist()
+        row_keys = d.keys.tolist()
+        shared: dict = {}
+        folded: list[tuple[int, list]] = []
+        fed: list[tuple[int, ReducerImpl, list]] = []
+        for j, (_, red, _) in enumerate(self._reducers):
+            if isinstance(red, _MultisetReducer):
+                folded.append(
+                    (j, red._entries(arg_cols[j], row_keys, time, shared))
+                )
+            else:
+                fed.append((j, red, arg_cols[j]))
+        FUSION_STATS["groupby_rows_by_column_total"] += n * len(folded)
+        state = self._state
+        affected: dict[int, None] = {}
+        for i in range(n):
+            gk = gks[i]
+            diff = diffs[i]
+            st = state.get(gk)
+            if st is None:
+                st = [0, tuple(col[i] for col in gcols), [r.make() for _, r, _ in self._reducers], None]
+                state[gk] = st
+            st[0] += diff
+            accs = st[2]
+            for j, entries in folded:
+                acc = accs[j]
+                e = entries[i]
+                c = acc.get(e, 0) + diff
+                if c == 0:
+                    acc.pop(e, None)
+                else:
+                    acc[e] = c
+            for j, red, cols in fed:
+                accs[j] = red.update(
+                    accs[j], tuple(col[i] for col in cols), diff,
+                    row_keys[i], time,
+                )
             affected[gk] = None
         return affected
 
@@ -2149,8 +2208,6 @@ class Join(Node):
             for name, fn in preamble.items()
         }
         if reuse:
-            from .fusion import FUSION_STATS
-
             FUSION_STATS["key_reuse_total"] += 1
         out = d.replace_data(data)
         if timed:
@@ -2605,8 +2662,6 @@ class Join(Node):
             if sp is None:
                 arr.apply(*delta)
                 return
-            from .fusion import FUSION_STATS
-
             before = FUSION_STATS["consolidation_rows_hashed_total"]
             arr.apply(*delta)
             sp.args["hashed"] = (
@@ -2800,8 +2855,6 @@ class Join(Node):
         and its entry passes through as it came."""
         if self._key_mode == "pair" or delta is None or not len(delta):
             return delta
-        from .fusion import FUSION_STATS
-
         n = len(delta)
         FUSION_STATS["consolidation_rows_total"] += n
         keys_l = delta.keys.tolist()

@@ -9,6 +9,7 @@ correct next-best value — the same split the reference draws between
 
 from __future__ import annotations
 
+from itertools import repeat
 from typing import Any
 
 import numpy as np
@@ -16,12 +17,24 @@ import numpy as np
 __all__ = ["ReducerImpl", "REDUCERS", "make_reducer"]
 
 
+#: types whose values `_encode` and `_hashable` give back as they are
+_PLAIN = frozenset({str, int, float, bool, bytes, type(None)})
+
+
 def _encode(v: Any) -> Any:
     """Structural, hashable encoding of a value (multiset dict key)."""
     if isinstance(v, np.ndarray):
         return ("\x00nd", v.shape, str(v.dtype), v.tobytes())
     if isinstance(v, dict):
-        return ("\x00d", tuple(sorted((k, _encode(x)) for k, x in v.items())))
+        return (
+            "\x00d",
+            tuple(
+                sorted(
+                    (k, x if type(x) in _PLAIN else _encode(x))
+                    for k, x in v.items()
+                )
+            ),
+        )
     if isinstance(v, (list, tuple)):
         return ("\x00t", tuple(_encode(x) for x in v))
     if isinstance(v, set):
@@ -91,6 +104,34 @@ def _unwrap(v: Any) -> Any:
     return v.v if isinstance(v, _H) else v
 
 
+def _hashable_column(col: Any, shared: dict) -> list:
+    """``[_hashable(v) for v in col]``, decided once where the dtype decides
+    it: the scalars of a numeric, string or datetime column need no ladder
+    (``list`` of an array gives the ``numpy`` scalars ``col[i]`` gives), an
+    ``object`` column is asked a value at a time. ``shared`` is the batch's,
+    whose columns outlive it: reducers over the same array get the same
+    list."""
+    key = ("hashable", id(col))
+    vals = shared.get(key)
+    if vals is None:
+        if isinstance(col, np.ndarray) and col.ndim == 1 and col.dtype != object:
+            vals = list(col)
+        else:
+            vals = [v if type(v) in _PLAIN else _hashable(v) for v in col]
+        shared[key] = vals
+    return vals
+
+
+def _ranked_column(col: Any, row_keys: list, shared: dict) -> list:
+    """``[(_hashable(v), row key)]`` of a column, once a batch for every
+    reducer that orders by it (``argmin``, ``argmax``, ``tuple_by``)."""
+    key = ("ranked", id(col))
+    pairs = shared.get(key)
+    if pairs is None:
+        pairs = shared[key] = list(zip(_hashable_column(col, shared), row_keys))
+    return pairs
+
+
 class ReducerImpl:
     name = "reducer"
 
@@ -144,13 +185,21 @@ class SumReducer(ReducerImpl):
 
 
 class _MultisetReducer(ReducerImpl):
-    """Base: multiset of (value-ish entries) with counts."""
+    """Base: multiset of (value-ish entries) with counts. An entry is the
+    value itself unless a subclass says otherwise."""
 
     def make(self):
         return {}
 
     def _entry(self, values: tuple, row_key: int, time: int):
-        raise NotImplementedError
+        return _hashable(values[0])
+
+    def _entries(self, cols: list, row_keys: list, time: int, shared: dict) -> list:
+        """Column form of `_entry`: a batch's entries, equal one by one (and
+        of the same types) to ``_entry((cols[0][i], ...), row_keys[i], time)``.
+        ``GroupByReduce`` folds them itself; ``shared`` is one dict a batch,
+        for what reducers over the same columns build alike."""
+        return _hashable_column(cols[0], shared)
 
     def update(self, acc, values, diff, row_key, time):
         e = self._entry(values, row_key, time)
@@ -164,9 +213,6 @@ class _MultisetReducer(ReducerImpl):
 
 class MinReducer(_MultisetReducer):
     name = "min"
-
-    def _entry(self, values, row_key, time):
-        return _hashable(values[0])
 
     def extract(self, acc):
         return _unwrap(min(acc.keys())) if acc else None
@@ -184,6 +230,9 @@ class ArgMinReducer(_MultisetReducer):
 
     def _entry(self, values, row_key, time):
         return (_hashable(values[0]), row_key)
+
+    def _entries(self, cols, row_keys, time, shared):
+        return _ranked_column(cols[0], row_keys, shared)
 
     def _pick(self, acc):
         return min(acc.keys()) if acc else None
@@ -205,9 +254,6 @@ class UniqueReducer(_MultisetReducer):
 
     name = "unique"
 
-    def _entry(self, values, row_key, time):
-        return _hashable(values[0])
-
     def extract(self, acc):
         if not acc:
             return None
@@ -226,6 +272,9 @@ class AnyReducer(_MultisetReducer):
     def _entry(self, values, row_key, time):
         return (row_key, _hashable(values[0]))
 
+    def _entries(self, cols, row_keys, time, shared):
+        return list(zip(row_keys, _hashable_column(cols[0], shared)))
+
     def extract(self, acc):
         if not acc:
             return None
@@ -237,9 +286,6 @@ class SortedTupleReducer(_MultisetReducer):
 
     def __init__(self, skip_nones: bool = False):
         self._skip_nones = skip_nones
-
-    def _entry(self, values, row_key, time):
-        return _hashable(values[0])
 
     def extract(self, acc):
         items = []
@@ -264,6 +310,9 @@ class TupleReducer(_MultisetReducer):
     def _entry(self, values, row_key, time):
         return (row_key, _hashable(values[0]))
 
+    def _entries(self, cols, row_keys, time, shared):
+        return list(zip(row_keys, _hashable_column(cols[0], shared)))
+
     def extract(self, acc):
         items = []
         for (rk, v), c in sorted(acc.items(), key=lambda kv: kv[0][0]):
@@ -283,6 +332,14 @@ class TupleByReducer(_MultisetReducer):
 
     def _entry(self, values, row_key, time):
         return ((_hashable(values[0]), row_key), _hashable(values[1]))
+
+    def _entries(self, cols, row_keys, time, shared):
+        return list(
+            zip(
+                _ranked_column(cols[0], row_keys, shared),
+                _hashable_column(cols[1], shared),
+            )
+        )
 
     def extract(self, acc):
         items = []
@@ -304,6 +361,11 @@ class EarliestReducer(_MultisetReducer):
 
     def _entry(self, values, row_key, time):
         return (time, row_key, _hashable(values[0]))
+
+    def _entries(self, cols, row_keys, time, shared):
+        return list(
+            zip(repeat(time), row_keys, _hashable_column(cols[0], shared))
+        )
 
     def extract(self, acc):
         if not acc:

@@ -20,11 +20,34 @@ from ..engine import operators as ops
 from ..engine.executor import Executor, Node
 from ..engine.reducers import make_reducer
 from . import dtype as dt
-from .expression import ColumnExpression, ColumnReference, HiddenRef, IdReference
+from .expression import (
+    ColumnBinaryOpExpression,
+    ColumnExpression,
+    ColumnReference,
+    ColumnUnaryOpExpression,
+    HiddenRef,
+    IdReference,
+)
 from .expression_compiler import ColumnEnv, compile_expr
 from .parse_graph import G
 from .table import Table
 from .thisclass import ThisPlaceholder
+
+
+def _same_column(e: ColumnExpression) -> tuple:
+    """A key two expressions share when they are the same column of one
+    table, told by their shape (`==` builds an expression): references and
+    the operators over them; anything else, a call among them, is only
+    itself."""
+    if isinstance(e, IdReference):
+        return ("id", id(e.table))
+    if isinstance(e, ColumnReference):
+        return ("ref", id(e.table), e.name)
+    if isinstance(e, ColumnUnaryOpExpression):
+        return ("unary", e._op, _same_column(e._expr))
+    if isinstance(e, ColumnBinaryOpExpression):
+        return ("binary", e._op, _same_column(e._left), _same_column(e._right))
+    return ("itself", id(e))
 
 
 class GraphRunner:
@@ -665,9 +688,20 @@ class GraphRunner:
         all_exprs: dict[str, ColumnExpression] = {}
         for i, g in enumerate(grouping):
             all_exprs[f"gk{i}"] = g
+        # an argument written twice (the sort key of `_repack`'s four
+        # `tuple_by`) is one column of the preamble: computed once, and the
+        # same array to every reducer that names it (GroupByReduce shares
+        # what its reducers build from one array)
+        arg_names: dict[tuple, str] = {}
+        args_of: dict[str, list[str]] = {}
         for out_name, rname, rargs, rkw in reducers:
+            names = args_of[out_name] = []
             for j, a in enumerate(rargs):
-                all_exprs[f"__a_{out_name}_{j}"] = a
+                name = arg_names.setdefault(
+                    _same_column(a), f"__a_{out_name}_{j}"
+                )
+                all_exprs.setdefault(name, a)
+                names.append(name)
         node, env = self._zip_env(primary, all_exprs)
         pre = {name: compile_expr(e, env).fn for name, e in all_exprs.items()}
         pre_node = self._add(ops.Rowwise(node, pre))
@@ -686,9 +720,7 @@ class GraphRunner:
                 impl = CustomAccumulatorReducer(rkw["accumulator"])
             else:
                 impl = make_reducer(rname)
-            engine_reducers.append(
-                (out_name, impl, [f"__a_{out_name}_{j}" for j in range(len(rargs))])
-            )
+            engine_reducers.append((out_name, impl, args_of[out_name]))
         group_cols = [f"gk{i}" for i in range(len(grouping))]
         by_id = p["by_id"] and len(grouping) == 1
         gb = self._add(ops.GroupByReduce(

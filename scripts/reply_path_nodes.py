@@ -13,7 +13,10 @@ branch shapes it (indexed over the vectors, repacked from ``text`` and
 every tick 8 new queries and the retraction of the 8 before, as a tick takes
 the answers of the tick before back. It prints each node's median a tick,
 and beside it the values a tick that the native hash handed back to Python
-(``hash_fallback_calls_total``, ``docs/observability.md``);
+(``hash_fallback_calls_total``, ``docs/observability.md``) and, for a
+group-by on the general path, the share of its rows that its reducers were
+given by column (``groupby_rows_by_column_total`` over ``groupby_rows_total``:
+1.0 on the reply path);
 ``--profile Flatten`` (any node class) also prints cProfile's view of that
 class's ``process``.
 
@@ -53,26 +56,34 @@ def _node_classes(cls=Node):
         yield from _node_classes(sub)
 
 
+COUNTED = (
+    "hash_fallback_calls_total",
+    "groupby_rows_total",
+    "groupby_rows_by_column_total",
+)
+
+
 def time_nodes(
-    spent: dict, fallbacks: dict, profiled: str | None, profile: cProfile.Profile
+    spent: dict, counted: dict, profiled: str | None, profile: cProfile.Profile
 ) -> None:
     """Wrap ``process`` of every node class that defines one: a call's
-    seconds go to ``spent[(label, tick time)]``, and the values its hashing
-    gave back to Python to ``fallbacks`` under the same key."""
+    seconds go to ``spent[(label, tick time)]``, and what it added to each
+    counter of ``COUNTED`` to ``counted[(label, tick time, counter)]``."""
     stats = fusion.FUSION_STATS
 
     def timed(process, profiled_here):
         def wrapper(self, time_, ins):
             if profiled_here:
                 profile.enable()
-            calls0 = stats["hash_fallback_calls_total"]
+            before = [stats[c] for c in COUNTED]
             t0 = time.perf_counter()
             try:
                 return process(self, time_, ins)
             finally:
-                key = f"{type(self).__name__}#{self.node_id}", time_
-                spent[key] += time.perf_counter() - t0
-                fallbacks[key] += stats["hash_fallback_calls_total"] - calls0
+                label = f"{type(self).__name__}#{self.node_id}"
+                spent[label, time_] += time.perf_counter() - t0
+                for c, was in zip(COUNTED, before):
+                    counted[label, time_, c] += stats[c] - was
                 if profiled_here:
                     profile.disable()
 
@@ -130,31 +141,39 @@ def main() -> int:
     args = ap.parse_args()
 
     spent: dict = collections.defaultdict(float)
-    fallbacks: dict = collections.defaultdict(int)
+    counted: dict = collections.defaultdict(int)
     profile = cProfile.Profile()
-    time_nodes(spent, fallbacks, args.profile, profile)
+    time_nodes(spent, counted, args.profile, profile)
     build(args.rows, args.dim, args.per_tick, args.ticks, args.k, args.seed)
     pw.run()
 
     by_node = collections.defaultdict(list)
-    handed_back = collections.defaultdict(int)
+    # a node's counters over the steady ticks, in COUNTED's order
+    totals = collections.defaultdict(lambda: [0] * len(COUNTED))
     # the first query ticks compile the search and have nothing to take back
     steady = {t for _, t in spent if t > 2 * 4 and t <= 2 * args.ticks}
     for (label, t), seconds in spent.items():
         if t in steady:
             by_node[label].append(seconds * 1e3)
-            handed_back[label] += fallbacks[label, t]
+            for i, c in enumerate(COUNTED):
+                totals[label][i] += counted[label, t, c]
     print(f"{len(steady)} steady ticks of {args.per_tick} queries in and "
           f"{args.per_tick} out, k = {args.k}, over {args.rows} rows")
     print(f"{'node':28s} {'ticks':>6s} {'median ms':>10s} {'mean ms':>9s} "
-          f"{'fallbacks a tick':>17s}")
+          f"{'fallbacks a tick':>17s} {'rows by column':>15s}")
     for label, ms in sorted(by_node.items(), key=lambda kv: -statistics.median(kv[1])):
         if len(ms) * 2 < len(steady):
             continue  # the documents' side: it worked once, at the start
+        handed_back, rows, by_column = totals[label]
+        share = f"{by_column / rows:15.3f}" if rows else f"{'':15s}"
         print(f"{label:28s} {len(ms):6d} {statistics.median(ms):10.3f} "
-              f"{statistics.fmean(ms):9.3f} {handed_back[label] / len(ms):17.1f}")
+              f"{statistics.fmean(ms):9.3f} {handed_back / len(ms):17.1f} {share}")
+    handed_back, rows, by_column = map(sum, zip(*totals.values()))
     print(f"values the native hash handed back to Python, all nodes: "
-          f"{sum(handed_back.values()) / max(len(steady), 1):.1f} a tick")
+          f"{handed_back / max(len(steady), 1):.1f} a tick")
+    print(f"rows x reducers the group-bys' general path was fed: "
+          f"{rows / max(len(steady), 1):.1f} a tick, "
+          f"{by_column / rows if rows else 0.0:.3f} of them by column")
     if args.profile:
         pstats.Stats(profile).sort_stats("cumulative").print_stats(18)
     return 0
