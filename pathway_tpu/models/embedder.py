@@ -13,6 +13,8 @@ pretrained MiniLM-class weights is a straight param-tree mapping.
 from __future__ import annotations
 
 import dataclasses
+import threading
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any
 
 from ..utils import jaxcfg  # noqa: F401  (configures jax before first use)
@@ -86,6 +88,7 @@ def _layernorm(x, scale, bias, eps=1e-6):
 
 
 def _block(x, layer, cfg: EmbedderConfig, mask):
+    # ``mask`` broadcasts to [batch, heads, query, key]: which keys a query sees
     # attention — bf16 matmuls land on the MXU; softmax in f32
     h = _layernorm(x, layer["ln1_scale"], layer["ln1_bias"])
     b, s, d = h.shape
@@ -97,7 +100,7 @@ def _block(x, layer, cfg: EmbedderConfig, mask):
 
     q, kk, v = heads(q), heads(kk), heads(v)
     scores = (q @ kk.transpose(0, 1, 3, 2)).astype(jnp.float32) / np.sqrt(cfg.head_dim)
-    scores = jnp.where(mask[:, None, None, :], scores, -1e30)
+    scores = jnp.where(mask, scores, -1e30)
     att = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
     out = (att @ v).transpose(0, 2, 1, 3).reshape(b, s, d)
     x = x + out @ layer["proj"].astype(cfg.dtype)
@@ -123,7 +126,7 @@ def _bert_block(x, layer, cfg: EmbedderConfig, mask):
 
     q, kk, v = heads(dense(x, "q")), heads(dense(x, "k")), heads(dense(x, "v"))
     scores = (q @ kk.transpose(0, 1, 3, 2)).astype(jnp.float32) / np.sqrt(cfg.head_dim)
-    scores = jnp.where(mask[:, None, None, :], scores, -1e30)
+    scores = jnp.where(mask, scores, -1e30)
     att = jax.nn.softmax(scores, axis=-1).astype(dt)
     out = (att @ v).transpose(0, 2, 1, 3).reshape(b, s, d)
     x = _layernorm(
@@ -137,29 +140,78 @@ def _bert_block(x, layer, cfg: EmbedderConfig, mask):
     return x
 
 
-def embed_tokens(params: dict, token_ids: jax.Array, cfg: EmbedderConfig) -> jax.Array:
+def embed_tokens(
+    params: dict, token_ids: jax.Array, cfg: EmbedderConfig, *,
+    segments: jax.Array | None = None, positions: jax.Array | None = None,
+    texts: int | None = None, scan: bool = False,
+) -> jax.Array:
     """token_ids int32 [batch, seq] (0 = pad) -> f32 [batch, dim], L2-normed
-    (mean pooling + normalize — the sentence-transformers MiniLM head)."""
-    mask = token_ids > 0
-    s = token_ids.shape[1]
-    x = params["tok_emb"].astype(cfg.dtype)[token_ids] + params["pos_emb"].astype(
-        cfg.dtype
-    )[:s][None, :, :]
-    if cfg.arch == "bert":
+    (mean pooling + normalize — the sentence-transformers MiniLM head).
+
+    With ``segments`` a row holds several texts one after another along the
+    token axis: ``segments`` [batch, seq] names the text of each position
+    (0 .. ``texts`` - 1 over the whole batch; padding carries ``texts``, a
+    segment of its own), ``positions`` [batch, seq] restart at every text.
+    Attention stays inside a segment and the pool and the norm are taken for
+    each: -> f32 [``texts``, dim], a text that is not there a zero vector.
+
+    The layers are laid out one after another, each reading its own float32
+    matrices as they are kept (the compiler fetches a layer's while the one
+    before it runs). ``scan`` loops over one traced layer instead, its
+    matrices cast and stacked ahead of the loop (``_stacked``): a program then
+    costs a layer's compile and code, not a model's, and a second pass over
+    the parameters a call. Chip runs, PR 40, a program of [8, 16] at 24 x 1024:
+    1.84 ms laid out and 4.35 ms looped (0.60 and 1.08 at 12 x 768), which a
+    program bound by one read of its parameters cannot afford; at [5, 512] the
+    loop is 2.5 of 17.8 ms, and a laid-out program takes 6-13 s to compile
+    and 35-53 MB of device memory where a looped one takes 2 s and 2-5 MB."""
+    if segments is None:
+        keep = token_ids > 0
+        mask = keep[:, None, None, :]
+        pos = params["pos_emb"].astype(cfg.dtype)[: token_ids.shape[1]][None, :, :]
+    else:
+        mask = (segments[:, :, None] == segments[:, None, :])[:, None, :, :]
+        pos = params["pos_emb"].astype(cfg.dtype)[positions]
+    # the table is cast and then looked up: looked up in float32, the compiler
+    # copies the whole table twice a call (0.23 ms at 30,522 x 768; chip run)
+    x = params["tok_emb"].astype(cfg.dtype)[token_ids] + pos
+    bert = cfg.arch == "bert"
+    if bert:
         x = x + params["type_emb"].astype(cfg.dtype)[0][None, None, :]
         x = _layernorm(
             x, params["emb_ln_scale"], params["emb_ln_bias"], cfg.ln_eps
         )
-        for layer in params["layers"]:
-            x = _bert_block(x, layer, cfg, mask)
+    block = _bert_block if bert else _block
+    if scan:
+        x, _ = jax.lax.scan(lambda h, layer: (block(h, layer, cfg, mask), None), x,
+                            _stacked(params["layers"], cfg.dtype))
     else:
         for layer in params["layers"]:
-            x = _block(x, layer, cfg, mask)
+            x = block(x, layer, cfg, mask)
+    if not bert:
         x = _layernorm(x, params["ln_f_scale"], params["ln_f_bias"])
-    # masked mean pool
-    m = mask[:, :, None].astype(jnp.float32)
-    pooled = (x.astype(jnp.float32) * m).sum(1) / jnp.maximum(m.sum(1), 1.0)
+    x = x.astype(jnp.float32)
+    if segments is None:
+        # masked mean pool
+        m = keep[:, :, None].astype(jnp.float32)
+        pooled = (x * m).sum(1) / jnp.maximum(m.sum(1), 1.0)
+    else:
+        # a text's positions lie in one row: summed there (a product with the
+        # row's membership matrix, at full float32), and the other rows add
+        # exact zeros, so its sum does not depend on which row it is in
+        member = (segments[:, :, None] == jnp.arange(texts)).astype(jnp.float32)
+        sums = jnp.einsum("rlt,rld->rtd", member, x,
+                          precision=jax.lax.Precision.HIGHEST).sum(0)
+        pooled = sums / jnp.maximum(member.sum((0, 1)), 1.0)[:, None]
     return pooled / jnp.linalg.norm(pooled, axis=-1, keepdims=True).clip(1e-9)
+
+
+def _stacked(layers: list[dict], dtype: Any) -> dict:
+    """The layers' tensors stacked along a leading layer axis, the form a scan
+    runs over; matrices and their biases in the compute dtype, where the
+    blocks cast them anyway, layernorm parameters as they are."""
+    return {k: jnp.stack([layer[k] if k.startswith("ln") else layer[k].astype(dtype)
+                          for layer in layers]) for k in layers[0]}
 
 
 def _np(v) -> np.ndarray:
@@ -229,8 +281,68 @@ def load_hf_state_dict(
     return params, cfg
 
 
+#: texts one dispatch carries at the most: every program has this many outputs
+TEXTS_PER_DISPATCH = 32
+#: programs compiled at a time when the declared set is warmed
+WARM_THREADS = 4
+#: The programs the served path runs, as {row length: row counts}: a call's
+#: texts are laid out in ``R`` rows of ``L`` tokens, and ``(R, L)`` is always
+#: one of these (``declared_shapes`` fits them to a model's positions), the
+#: texts packed along the token axis into the rows. Lengths are few
+#: because a text shorter than its row shares it with others; row counts at
+#: the longest length are fine enough that rounding up costs a tick of eight
+#: passages a seventh at the most, the others powers of two, and a length's
+#: counts end where the next length holds as many tokens in fewer rows.
+#: Nothing else is compiled while serving.
+SHAPES = {16: (1, 2, 4, 8, 16), 128: (1, 2, 4, 8), 512: (1, 2, 3, 4, 5, 6, 8, 10, 12, 16)}
+#: a stored text's row is at least this long (then the power of two that holds it)
+STORED_ROW_MIN = 16
+
+
+def _rows_by_length(max_len: int) -> dict[int, tuple[int, ...]]:
+    """``SHAPES`` for a model of ``max_len`` positions: the lengths under
+    ``max_len``, then ``max_len`` itself with the row counts of the first
+    declared length that holds it (of the longest, for a model of more
+    positions than any)."""
+    lengths = sorted(SHAPES)
+    last = next((n for n in lengths if n >= max_len), lengths[-1])
+    return {**{n: SHAPES[n] for n in lengths if n < max_len}, max_len: SHAPES[last]}
+
+
+def declared_shapes(max_len: int) -> tuple[tuple[int, int], ...]:
+    """Every (R, L) a model of ``max_len`` positions is served at, by length."""
+    return tuple((r, n) for n, rows in _rows_by_length(max_len).items() for r in rows)
+
+
+def _blank_ids(rows: int, length: int) -> np.ndarray:
+    """int32 [3, R, L] (tokens, segments, positions) of a program that holds
+    no text yet: every position padding, in the padding's own segment."""
+    ids = np.zeros((3, rows, length), np.int32)
+    ids[1] = TEXTS_PER_DISPATCH
+    return ids
+
+
+def _pack(lengths: np.ndarray, row: int) -> list[list[int]]:
+    """First fit, longest first: the texts (by index) of each row of ``row``
+    tokens."""
+    rows: list[list[int]] = []
+    free: list[int] = []
+    for i in np.argsort(-lengths, kind="stable").tolist():
+        n = int(lengths[i])
+        for r, room in enumerate(free):
+            if room >= n:
+                free[r] -= n
+                rows[r].append(i)
+                break
+        else:
+            free.append(row - n)
+            rows.append([i])
+    return rows
+
+
 class Embedder:
-    """Host-facing embedder with a cached jitted forward per shape bucket."""
+    """Host-facing embedder: one jitted forward on the served path, run at
+    the shapes of ``self.shapes`` and at no other."""
 
     def __init__(self, cfg: EmbedderConfig | None = None, seed: int = 0,
                  params: dict | None = None, tokenizer: Any = None):
@@ -238,14 +350,40 @@ class Embedder:
         self.params = params if params is not None else init_params(self.cfg, seed)
         self.tokenizer = tokenizer
         cfg = self.cfg
+        #: every (rows, length) the served forward is run at, by length
+        self.shapes = declared_shapes(cfg.max_len)
+        self._rows_of = _rows_by_length(cfg.max_len)
+        question = self.shapes[0][1]
 
-        def forward(params, token_ids):
-            return embed_tokens(params, token_ids, cfg=cfg)
+        def forward(params, ids):
+            # ids int32 [3, R, L]: tokens, segments, positions. Rows of question
+            # length are bound by one read of the parameters, so their few
+            # programs lay the layers out, as a search's program always did;
+            # the passage programs are bound by their products, and loop over
+            # one layer: cheap to compile and to keep (``embed_tokens``)
+            return embed_tokens(params, ids[0], cfg, segments=ids[1],
+                                positions=ids[2], texts=TEXTS_PER_DISPATCH,
+                                scan=ids.shape[2] > question)
 
-        # a named function, not a bare functools.partial: a device trace
-        # then shows the program as ``jit_embed_tokens``, not ``jit__unknown``
+        def token_rows(params, token_ids):
+            return embed_tokens(params, token_ids, cfg)
+
+        def take(vectors, rows):
+            return vectors[:rows]
+
+        # named functions, not bare functools.partial: a device trace then
+        # shows the programs as ``jit_embed_tokens`` and ``jit_embed_take``,
+        # not ``jit__unknown``
         forward.__name__ = forward.__qualname__ = "embed_tokens"
+        take.__name__ = take.__qualname__ = "embed_take"
+        token_rows.__name__ = token_rows.__qualname__ = "embed_tokens"
         self._fwd = jax.jit(forward)
+        self._take = jax.jit(take, static_argnums=1)
+        self._token_rows = jax.jit(token_rows)
+        self._warm_lock = threading.Lock()
+        self._count_lock = threading.Lock()
+        self._warm = False
+        self._compiled = 0
 
     @classmethod
     def from_pretrained(
@@ -287,27 +425,53 @@ class Embedder:
         return cls(cfg, params=params, tokenizer=tokenizer)
 
     def __call__(self, token_ids: np.ndarray) -> np.ndarray:
-        return np.asarray(self._fwd(self.params, jnp.asarray(token_ids, jnp.int32)))
+        """Rows of token ids (0 = pad), one text a row, at the caller's own
+        shape: a program apart from the served ones."""
+        return np.asarray(self._token_rows(self.params, jnp.asarray(token_ids, jnp.int32)))
 
-    def embed_texts_device(self, texts: list[str], max_len: int = 128) -> jax.Array:
-        """Embeddings as a device-resident array (no host fetch): consumers
-        that feed another device computation (the KNN scorer) pipeline the
-        dispatches and pay ONE blocking fetch for the whole chain.
+    def warm(self) -> None:
+        """Compile (or load from the compile cache) every program the served
+        path can run: the forward at each of ``self.shapes`` and the cut of
+        its result to 1 .. ``TEXTS_PER_DISPATCH`` vectors, several at a time.
+        The embedder's first call does it, a query's or an ingest's, where
+        the owner has not; after it no served call compiles."""
+        if self._warm:
+            return
+        with self._warm_lock:
+            if self._warm:
+                return
+            with ThreadPoolExecutor(WARM_THREADS) as pool:
+                out = list(pool.map(lambda shape: self._forward(_blank_ids(*shape)),
+                                    self.shapes))
+            for n in range(1, TEXTS_PER_DISPATCH + 1):
+                self._take(out[0], n)
+            jax.block_until_ready(out)
+            self._warm = True
 
-        The sequence is bucketed to the smallest power of two covering the
-        longest REAL token run (min 16): pad columns are masked out of
-        attention and the mean pool, so truncating them is numerically
-        equivalent (differences ~1e-4 from the finite -1e9 attention mask
-        vs absent columns), and a 4-token serve query pays a 16-token
-        forward instead of a ``max_len`` one (the dominant slice of REST
-        p50 off-TPU). One jit cache entry per bucket."""
+    def _forward(self, ids: np.ndarray) -> jax.Array:
+        from ..serve.stats import bump
+
+        out = self._fwd(self.params, jnp.asarray(ids))
+        with self._count_lock:
+            compiled = self._fwd._cache_size()
+            if compiled != self._compiled:
+                bump("embed_shapes_compiled_total", compiled - self._compiled)
+                self._compiled = compiled
+        return out
+
+    def _tokenize(self, texts: list[str], max_len: int | None):
+        """Token rows [n, width] (0 = pad), each text's length and the limit
+        they were cut at: ``max_len``, the model's positions where the caller
+        states none or more."""
         from ..internals.tracing import span
         from ..serve.stats import bump
 
-        max_len = min(max_len, self.cfg.max_len)  # position-table bound
-        with span("embed.tokenize", q=len(texts)):
+        limit = self.cfg.max_len if max_len is None else min(max_len, self.cfg.max_len)
+        with span("embed.tokenize", q=len(texts)) as sp:
+            # one token over the limit is asked for, so that a text that is
+            # cut can be told from one that just fits
             if self.tokenizer is not None:
-                toks = self.tokenizer.encode_batch(texts, max_len)
+                toks = self.tokenizer.encode_batch(texts, limit + 1)
             else:
                 if self.cfg.arch == "bert":
                     raise RuntimeError(
@@ -316,52 +480,148 @@ class Embedder:
                         "checkpoint was never trained on — load with a "
                         "vocab.txt (WordPiece) or pass tokenizer="
                     )
-                toks = tokenize_batch(texts, self.cfg.vocab_size, max_len)
-            toks = np.asarray(toks, dtype=np.int32)
-        n, width = toks.shape
-        if n == 0:
-            return self._fwd(self.params, jnp.asarray(toks))
-        # PER-TEXT buckets: each text's embedding is a pure function of
-        # (text, its own bucket) — never of the other texts in the batch
-        # (batch-derived buckets would make a re-embedded document's
-        # vector drift with batch composition and churn the maintained
-        # index; review finding). Texts group by bucket and each group
-        # runs one forward; results reassemble device-side.
-        lengths = (toks > 0).sum(axis=1)
-        buckets = np.maximum(
-            16, 2 ** np.ceil(np.log2(np.maximum(lengths, 1))).astype(np.int64)
-        )
-        buckets = np.minimum(buckets, width)
-        # useful work over attempted work in the forward
-        bump("embed_real_tokens_total", int(lengths.sum()))
-        bump("embed_padded_tokens_total", int(buckets.sum()))
-        uniq = np.unique(buckets)
-        if len(uniq) == 1:
-            b = int(uniq[0])
-            with span("embed.dispatch", bucket=b, rows=n):
-                return self._fwd(self.params, jnp.asarray(toks[:, :b]))
-        out = None
-        for b in uniq.tolist():
-            ix = np.flatnonzero(buckets == b)
-            with span("embed.dispatch", bucket=b, rows=len(ix)):
-                part = self._fwd(self.params, jnp.asarray(toks[ix, :b]))
-            if out is None:
-                out = jnp.zeros((n, part.shape[1]), part.dtype)
-            out = out.at[jnp.asarray(ix)].set(part)
-        return out
+                toks = tokenize_batch(texts, self.cfg.vocab_size, limit + 1)
+            toks = np.array(toks, dtype=np.int32)  # a copy: a cut text is closed in place
+            lengths = (toks > 0).sum(axis=1)
+            cut = np.flatnonzero(lengths > limit)
+            if len(cut):
+                if self.tokenizer is not None:
+                    # a tokenizer's last token closes the text ([SEP]): kept
+                    toks[cut, limit - 1] = toks[cut, limit]
+                toks[cut, limit:] = 0
+                lengths[cut] = limit
+                bump("embed_truncated_texts_total", len(cut))
+            if sp is not None:
+                sp.args["tokens"] = int(lengths.sum())
+        return toks, lengths, limit
 
-    def embed_texts(self, texts: list[str], max_len: int = 128) -> np.ndarray:
-        return np.asarray(self.embed_texts_device(texts, max_len))
+    def _plan(self, lengths: np.ndarray):
+        """(R, L, rows) for the texts of these lengths packed into one
+        program: the shortest declared length that holds the longest text and
+        whose row counts hold the packing. None where no program holds them."""
+        if len(lengths) > TEXTS_PER_DISPATCH:
+            return None
+        longest = int(lengths.max(initial=0))
+        for length, counts in self._rows_of.items():
+            if length < longest:
+                continue
+            rows = _pack(lengths, length)
+            fit = next((r for r in counts if r >= len(rows)), None)
+            if fit is not None:
+                return fit, length, rows
+        return None
+
+    def _dispatch(self, toks, lengths, texts: list[int], plan) -> jax.Array:
+        """One program over ``texts`` (indices into ``toks``), laid out as
+        ``plan`` says: -> [TEXTS_PER_DISPATCH, dim] on the device, the vector
+        of ``texts[j]`` at ``j``."""
+        n_rows, length, rows = plan
+        ids = _blank_ids(n_rows, length)
+        for r, members in enumerate(rows):
+            at = 0
+            for j in members:
+                n = int(lengths[texts[j]])
+                ids[0, r, at:at + n] = toks[texts[j], :n]
+                ids[1, r, at:at + n] = j
+                ids[2, r, at:at + n] = np.arange(n)
+                at += n
+        with self._dispatching(length, n_rows, len(texts), int(lengths[texts].sum())):
+            return self._forward(ids)
+
+    @staticmethod
+    def _dispatching(length: int, rows: int, texts: int, real: int):
+        """The span and the counters of one forward handed to the device:
+        useful work (``real`` tokens) over attempted work (rows x length)."""
+        from ..internals.tracing import span
+        from ..serve.stats import bump
+
+        bump("embed_dispatches_total")
+        bump("embed_real_tokens_total", real)
+        bump("embed_padded_tokens_total", rows * length)
+        return span("embed.dispatch", bucket=length, rows=rows, texts=texts,
+                    tokens=real, computed=rows * length)
+
+    def _packed(self, toks, lengths, texts: list[int]) -> list[tuple[jax.Array, int]]:
+        """The dispatches that embed ``texts``, as (vectors, how many of them
+        count): one, unless no program holds them all."""
+        plan = self._plan(lengths[texts])
+        if plan is not None:
+            return [(self._dispatch(toks, lengths, texts, plan), len(texts))]
+        half = len(texts) // 2
+        return (self._packed(toks, lengths, texts[:half])
+                + self._packed(toks, lengths, texts[half:]))
+
+    def embed_texts_device(self, texts: list[str], max_len: int | None = None,
+                           rows: int | None = None) -> jax.Array:
+        """Embeddings as a device-resident array (no host fetch): consumers
+        that feed another device computation (the KNN scorer) pipeline the
+        dispatches and pay ONE blocking fetch for the whole chain. This is
+        the served path, for texts that are asked and never stored.
+
+        A text is embedded whole up to the model's own positions
+        (``max_len`` may state less); a longer one is cut there and counted
+        (``embed_truncated_texts_total``). The call's texts are packed along
+        the token axis into the rows of one program of ``self.shapes``
+        (attention, positions, pool and norm per text), so a call is one
+        dispatch whatever lengths it mixes; only a call that no program
+        holds (over ``TEXTS_PER_DISPATCH`` texts, or more tokens than the
+        largest shape) is split. ``rows`` pads the result with zero vectors
+        to that many rows. Every program is compiled before the first call
+        returns (``warm``)."""
+        toks, lengths, _ = self._tokenize(texts, max_len)
+        self.warm()
+        rows = len(texts) if rows is None else rows
+        if not len(texts):
+            return jnp.zeros((rows, self.cfg.dim), jnp.float32)
+        parts = self._packed(toks, lengths, list(range(len(texts))))
+        if len(parts) == 1 and rows <= TEXTS_PER_DISPATCH:
+            return self._take(parts[0][0], rows)
+        out = jnp.concatenate([self._take(v, n) for v, n in parts])
+        return out if rows == len(texts) else jnp.pad(out, ((0, rows - len(texts)), (0, 0)))
+
+    def embed_texts(self, texts: list[str], max_len: int | None = None) -> np.ndarray:
+        """Embeddings on the host, for texts whose vectors are stored: a
+        stored vector is a function of its text alone, never of the texts
+        embedded with it (a re-embedded document must not drift with its
+        batch and churn the maintained index). So stored texts are not
+        packed: each has a row to itself, as long as the power of two that
+        holds it (``STORED_ROW_MIN`` at the least, the limit at the most),
+        and the texts of one length go to the device together, as one
+        forward of exactly their rows. Ingest has no deadline: its programs
+        are compiled as its lengths and counts are met, not declared, and
+        the served set is compiled here too (``warm``), so that a store fed
+        with texts has its queries' programs before its first query, which
+        has a deadline (a sharded server's gather gives a shard 5 s)."""
+        toks, lengths, limit = self._tokenize(texts, max_len)
+        self.warm()
+        out = np.zeros((len(texts), self.cfg.dim), np.float32)
+        if not len(texts):
+            return out
+        own = np.minimum(limit, np.maximum(
+            STORED_ROW_MIN, 2 ** np.ceil(np.log2(np.maximum(lengths, 1))).astype(np.int64)))
+        sent = []
+        for length in np.unique(own).tolist():
+            of_length = np.flatnonzero(own == length)
+            ids = np.zeros((len(of_length), length), np.int32)
+            width = min(length, toks.shape[1])
+            ids[:, :width] = toks[of_length, :width]
+            with self._dispatching(length, len(of_length), len(of_length),
+                                   int(lengths[of_length].sum())):
+                sent.append((of_length, self._token_rows(self.params, jnp.asarray(ids))))
+        # every program is on its way before the first result is waited for
+        for of_length, vectors in sent:
+            out[of_length] = np.asarray(vectors)
+        return out
 
 
 def tokenize_batch(texts: list[str], vocab_size: int, max_len: int) -> np.ndarray:
     """Deterministic hashing tokenizer (feature-hashing — a self-contained
     stand-in for a learned vocab; swap with a real WordPiece for pretrained
-    weights)."""
-    out = np.zeros((len(texts), max_len), dtype=np.int32)
-    for i, t in enumerate(texts):
-        words = t.lower().split()[: max_len]
-        for j, w in enumerate(words):
+    weights): int32 [batch, longest text], at most ``max_len`` wide, 0 = pad."""
+    words = [t.lower().split()[:max_len] for t in texts]
+    out = np.zeros((len(texts), max((len(w) for w in words), default=0)), dtype=np.int32)
+    for i, ws in enumerate(words):
+        for j, w in enumerate(ws):
             out[i, j] = (hash_word(w) % (vocab_size - 2)) + 2
     return out
 

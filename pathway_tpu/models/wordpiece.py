@@ -154,10 +154,13 @@ class WordPieceTokenizer:
         ids.append(self.sep_id)
         return ids
 
-    def encode_batch(self, texts: list[str], max_len: int = 128) -> np.ndarray:
-        """int32 [batch, max_len], right-padded with pad_id."""
-        out = np.full((len(texts), max_len), self.pad_id, dtype=np.int32)
-        for i, t in enumerate(texts):
-            ids = self.encode(t, max_len)
+    def encode_batch(self, texts: list[str], max_len: int | None = None) -> np.ndarray:
+        """int32 [batch, longest text of the batch], each text cut at
+        ``max_len`` tokens, right-padded with pad_id: no wider than the batch
+        needs, so short questions do not carry a model's whole positions."""
+        rows = [self.encode(t, max_len) for t in texts]
+        out = np.full((len(rows), max(map(len, rows), default=0)), self.pad_id,
+                      dtype=np.int32)
+        for i, ids in enumerate(rows):
             out[i, : len(ids)] = ids
         return out

@@ -224,10 +224,12 @@ class BruteForceKnnEngine:
         self._stage(slot)
 
     def add_batch(self, keys: list[int], datas: list[Any], filters: list[Any]) -> None:
-        """Bulk insertion: all string payloads of one tick are embedded in a
-        single batched device call (one MXU forward + one roundtrip instead
-        of one per document) — the ingest-path analog of the device-resident
-        query fusion. Called by ExternalIndexNode when available.
+        """Bulk insertion: all string payloads of one tick are embedded in
+        one batched call (``Embedder.embed_texts``: one MXU forward for each
+        power-of-two length its texts fall into, all on their way before the
+        first is fetched, instead of one per document) — the ingest-path
+        analog of the device-resident query fusion. Called by
+        ExternalIndexNode when available.
 
         When every payload is already a vector and this is a plain
         brute-force engine (no subclass bucketing hooks), insertion is one
@@ -512,8 +514,10 @@ class BruteForceKnnEngine:
             from .knn import topk_scores
 
             # a search that carries a filter is padded along its query axis
-            # to a power of two, its last query over again: so few programs
-            # serve whatever batches the ticks form, and all are met early
+            # to a power of two: so few programs serve whatever batches the
+            # ticks form, and all are met early. What is padded is the
+            # vectors (the embedder's zero rows, the last vector again where
+            # the host has them), never the texts: a query is embedded once
             pad = 0
             if filtered:
                 pad = max(SCOPED_BATCH_MIN, 1 << (len(queries) - 1).bit_length()) - len(queries)
@@ -524,7 +528,7 @@ class BruteForceKnnEngine:
                 # top_k pipelines as queued device work with a single blocking
                 # fetch at _pack time
                 with span("index.embed", q=len(queries)):
-                    q = dev_embed(list(queries) + list(queries[-1:]) * pad)
+                    q = dev_embed(list(queries), rows=len(queries) + pad)
             else:
                 q = np.stack([self._vec(x) for x in queries])
                 q = np.concatenate([q, np.repeat(q[-1:], pad, axis=0)])

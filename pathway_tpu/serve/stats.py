@@ -79,10 +79,16 @@ SERVE_STATS: dict[str, int] = {
     "index_rows_removed_total": 0,
     #: commit windows a python connector closed into a delta
     "connector_windows_total": 0,
-    #: tokens the embed forward was asked for: real ones / with the
-    #: padding of their length bucket (useful over attempted work)
+    #: tokens the embed forward was asked for: real ones / the positions
+    #: its programs computed, rows x row length (useful over attempted work)
     "embed_real_tokens_total": 0,
     "embed_padded_tokens_total": 0,
+    #: forwards dispatched (one a call on the served path) / texts longer
+    #: than the length limit, cut there / programs of the declared shape set
+    #: compiled or loaded (all of them by ``Embedder.warm``: the first served call)
+    "embed_dispatches_total": 0,
+    "embed_truncated_texts_total": 0,
+    "embed_shapes_compiled_total": 0,
 }
 
 _lock = threading.Lock()

@@ -58,12 +58,14 @@ class TpuEmbedder(BaseEmbedder):
     ingestion still hits the MXU with real batches."""
 
     def __init__(self, embedder: Any = None, *, model_path: str | None = None,
-                 max_len: int = 128, **kwargs: Any):
+                 max_len: int | None = None, **kwargs: Any):
         """``model_path``: local directory with a MiniLM-class HF checkpoint
         (``pytorch_model.bin`` + ``vocab.txt``) — loads pretrained weights
         and the real WordPiece tokenizer (``models/embedder.py``
         ``Embedder.from_pretrained``). Default: deterministic-init encoder
-        (self-contained, no checkpoint needed)."""
+        (self-contained, no checkpoint needed). ``max_len``: tokens a text is
+        cut at; the encoder's own positions where none is given, as
+        sentence-transformers' ``max_seq_length``."""
         super().__init__(**kwargs)
         if embedder is None:
             from ...models.embedder import Embedder

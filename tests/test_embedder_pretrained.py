@@ -50,9 +50,15 @@ def test_wordpiece_truncation_and_batch():
     ids = tok.encode("the quick brown fox", max_len=4)
     assert len(ids) == 4 and ids[0] == tok.cls_id and ids[-1] == tok.sep_id
     batch = tok.encode_batch(["the dog", "hello world jumps"], max_len=8)
-    assert batch.shape == (2, 8)
+    # as wide as the batch's longest text, not as the limit
+    longest = tok.encode("hello world jumps")
+    assert batch.shape == (2, len(longest)) and len(longest) < 8
+    assert batch[1].tolist() == longest
     assert batch[0, 0] == tok.cls_id
-    assert (batch[:, -1] == tok.pad_id).all()  # right-padded
+    assert batch[0, -1] == tok.pad_id  # right-padded
+    cut = tok.encode_batch(["the dog", "hello world jumps"], max_len=4)
+    assert cut.shape == (2, 4) and (cut[:, -1] == tok.sep_id).all()
+    assert tok.encode_batch([], max_len=4).shape == (0, 0)
 
 
 def _tiny_hf_bert():

@@ -182,9 +182,13 @@ def test_every_span_of_the_serving_path_with_shared_ids(tmp_path):
         for e in by_name[name]:
             assert e["args"]["parent"] == "index.embed"
             assert _inside(e, embeds[e["args"]["tick"]]), name
+    # one four-word question: one text of four real tokens in a program of
+    # one row of sixteen, and the tokenizer says how many tokens it made
     assert by_name["embed.dispatch"][0]["args"] == {
-        "bucket": 16, "rows": 1, "parent": "index.embed",
+        "bucket": 16, "rows": 1, "texts": 1, "tokens": 4, "computed": 16,
+        "parent": "index.embed",
         "tick": by_name["embed.dispatch"][0]["args"]["tick"]}
+    assert by_name["embed.tokenize"][0]["args"]["tokens"] == 4
     assert by_name["index.upload"][0]["args"]["bytes"] == ROWS * DIM * 4
     # the write path: one index.apply per fed block, inside its tick
     applies = by_name["index.apply"]
@@ -278,6 +282,11 @@ def test_each_counter_equals_the_calls_made():
         ROWS // BLOCK + 2 * 5 - 1)
     # "what is row <i>": four tokens in a 16-token bucket, three times
     assert got["embed_real_tokens_total"] == 12 and got["embed_padded_tokens_total"] == 48
+    # a dispatch a search, nothing cut; the whole declared set of programs
+    # (a model of 32 positions: five of sixteen tokens a row, four of 32) was
+    # compiled by the first
+    assert got["embed_dispatches_total"] == 3 and got["embed_truncated_texts_total"] == 0
+    assert got["embed_shapes_compiled_total"] == 9
     assert got["queries_total"] == 4
 
 
