@@ -10,10 +10,28 @@ against ``transformers.BertTokenizer`` over a shared vocab in
 ``models/embedder.py`` shipped before pretrained weights existed
 (reference: ``python/pathway/xpacks/llm/embedders.py:217``
 SentenceTransformerEmbedder's underlying tokenizer).
+
+Two routes to the same ids. ``encode`` is the exact path: it asks
+``unicodedata`` about every character (accents, CJK, Unicode punctuation and
+controls). ``encode_batch`` sends a text for which ``str.isascii()`` holds down
+the ASCII lane instead, where those classes are decidable without
+``unicodedata``: the controls BERT deletes go, the rest is lower-cased and cut
+at white space and punctuation, a word is looked up whole in the vocabulary
+first (the first candidate of the greedy longest match, so the answer whenever
+it is there), and the greedy match runs only for a word that misses. The lane
+is made of calls that run in C over a whole text (``str.lower``,
+``str.translate``, ``str.split``, one compiled ``re`` where the text holds
+punctuation). Any other text of the batch takes ``encode``, text by text.
+Which route a text took is counted (``embed_tokenize_texts_total``,
+``embed_tokenize_fast_texts_total`` in ``serve/stats.py``). Nothing is
+remembered between calls: no table from a text, or from anything longer than a
+word, to ids; the vocabulary is the only table
+(``tests/test_wordpiece_fast.py`` holds the lane to ``encode``).
 """
 
 from __future__ import annotations
 
+import re
 import unicodedata
 
 import numpy as np
@@ -42,6 +60,16 @@ def _is_control(ch: str) -> bool:
     if ch in ("\t", "\n", "\r"):
         return False
     return unicodedata.category(ch).startswith("C")
+
+
+#: the ASCII characters ``_clean`` deletes: the controls (category Cc) but
+#: \t \n \r. \x1c-\x1f are among them, which ``str.split`` would otherwise
+#: take for white space
+_ASCII_DROPPED = dict.fromkeys((*range(0, 9), 11, 12, *range(14, 32), 127))
+#: what is left of an ASCII text after that: white space, runs of letters and
+#: digits, and BERT's ASCII punctuation (33-47, 58-64, 91-96, 123-126: every
+#: other printable character), each a token of its own
+_ASCII_TOKENS = re.compile(r"[A-Za-z0-9]+|[^A-Za-z0-9\s]")
 
 
 class WordPieceTokenizer:
@@ -154,13 +182,47 @@ class WordPieceTokenizer:
         ids.append(self.sep_id)
         return ids
 
+    def _encode_ascii(self, text: str, max_len: int | None) -> list[int]:
+        """``encode`` of a text for which ``str.isascii()`` holds, by calls
+        that run over the whole text: the same ids."""
+        if self.lowercase:
+            text = text.lower()
+        text = text.translate(_ASCII_DROPPED)
+        tokens = text.split()
+        if not "".join(tokens).isalnum():  # punctuation somewhere: split it off
+            tokens = _ASCII_TOKENS.findall(text)
+        ids = list(map(self.vocab.get, tokens))
+        if None in ids or max(map(len, tokens), default=0) > self.max_chars_per_word:
+            hits, ids = ids, []
+            for token, hit in zip(tokens, hits):
+                if hit is None or len(token) > self.max_chars_per_word:
+                    ids.extend(self.wordpiece(token))
+                else:
+                    ids.append(hit)
+        ids.insert(0, self.cls_id)
+        limit = (max_len - 1) if max_len is not None else len(ids) + 1
+        ids = ids[:limit]
+        ids.append(self.sep_id)
+        return ids
+
     def encode_batch(self, texts: list[str], max_len: int | None = None) -> np.ndarray:
         """int32 [batch, longest text of the batch], each text cut at
         ``max_len`` tokens, right-padded with pad_id: no wider than the batch
-        needs, so short questions do not carry a model's whole positions."""
-        rows = [self.encode(t, max_len) for t in texts]
-        out = np.full((len(rows), max(map(len, rows), default=0)), self.pad_id,
-                      dtype=np.int32)
+        needs, so short questions do not carry a model's whole positions.
+        An ASCII text takes the lane, any other the exact path (module
+        docstring); both are counted."""
+        from ..serve.stats import bump
+
+        rows, fast = [], 0
+        for text in texts:
+            if text.isascii():
+                rows.append(self._encode_ascii(text, max_len))
+                fast += 1
+            else:
+                rows.append(self.encode(text, max_len))
+        out = np.full((len(rows), max(map(len, rows), default=0)), self.pad_id, dtype=np.int32)
         for i, ids in enumerate(rows):
             out[i, : len(ids)] = ids
+        bump("embed_tokenize_texts_total", len(texts))
+        bump("embed_tokenize_fast_texts_total", fast)
         return out

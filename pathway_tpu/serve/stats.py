@@ -89,6 +89,11 @@ SERVE_STATS: dict[str, int] = {
     "embed_dispatches_total": 0,
     "embed_truncated_texts_total": 0,
     "embed_shapes_compiled_total": 0,
+    #: texts ``WordPieceTokenizer.encode_batch`` was handed / those of them
+    #: that took its ASCII lane (the rest, texts that are not ASCII, were
+    #: tokenized a character at a time in Python)
+    "embed_tokenize_texts_total": 0,
+    "embed_tokenize_fast_texts_total": 0,
 }
 
 _lock = threading.Lock()

@@ -131,6 +131,26 @@ def test_a_text_of_the_models_positions_is_whole_and_a_longer_one_is_cut_and_cou
     np.testing.assert_allclose(emb.embed_texts([longer]), whole, atol=1e-5, rtol=0)
 
 
+def test_the_tokenizers_lanes_are_counted(built):
+    emb, ref, index, words = built
+    rng = np.random.default_rng([SEED, 7])
+    texts = [_text(rng, words, t) for t in (5, 30, 64)]
+    keys = ("embed_tokenize_texts_total", "embed_tokenize_fast_texts_total")
+    before = [SERVE_STATS[k] for k in keys]
+    ascii_only = np.asarray(emb.embed_texts_device(texts))
+    # ASCII texts: both counters alike, the share is 1.0
+    assert [SERVE_STATS[k] - b for k, b in zip(keys, before)] == [3, 3]
+    np.testing.assert_allclose(ascii_only, _reference(ref, index, texts), atol=1e-5, rtol=0)
+    # one text that is not ASCII takes the exact path: the counters part,
+    # and the accent is stripped there, so the vectors are the same
+    accented = [texts[0], texts[1].replace("a", "\u00e1", 1), texts[2]]
+    assert not accented[1].isascii()
+    before = [SERVE_STATS[k] for k in keys]
+    mixed = np.asarray(emb.embed_texts_device(accented))
+    assert [SERVE_STATS[k] - b for k, b in zip(keys, before)] == [3, 2]
+    np.testing.assert_allclose(mixed, ascii_only, atol=0, rtol=0)
+
+
 def test_no_served_call_site_cuts_below_the_models_positions():
     import inspect
 

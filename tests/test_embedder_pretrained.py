@@ -26,23 +26,52 @@ def _tokenizer() -> WordPieceTokenizer:
     return WordPieceTokenizer({t: i for i, t in enumerate(VOCAB)})
 
 
-def test_wordpiece_matches_transformers_bert_tokenizer(tmp_path):
+CASES = [
+    "The quick brown fox jumps over the lazy dog!",
+    "streaming engines process words",        # ##ing / ##s pieces
+    "hello, world.",                           # punctuation splitting
+    "HELLO WoRLD",                             # lowercasing
+    "unknownword the",                         # [UNK] fallback
+    "  spaced\tout\n text ",
+    "caf\u00e9 hello",                          # accent stripping
+    # the corners of ASCII, where ``encode_batch`` takes its lane of
+    # whole-string calls and Python's string methods differ from BERT's rules
+    "",
+    " \t\r\n ",
+    "the\x00dog hel\x00lo",                     # \x00 is deleted: the halves join
+    "the\x7fdog wor\x7fld",
+    "the\x1cquick\x1dbrown\x1efox\x1f",          # controls to BERT, white space to str.split
+    "the \x1c dog",
+    "the\x0bdog\x0cfox \x08hello\x1b",
+    "the\tquick\nbrown\rfox\r\njumps",
+    "!!!",                                     # only punctuation
+    "hello,world.'the'!dog",                   # punctuation inside and around words
+    "jumps-over [the] {lazy} dog_fox ~ ` ^ $ + = | < > \\ / @ # % & * ( ) ; : ? \"",
+    "##s ##ing jump##ed",                      # a literal ## is punctuation
+    "JUMPED Jumps jumpING",
+    "word" * 25 + " the",                      # 100 characters: the greedy match is tried
+    "word" * 25 + "s the",                     # 101: [UNK] whole
+    "the " + "x" * 250 + " dog",
+    "word" * 20 + "." + "word" * 20,           # split before the length is judged
+    "count 10 words 2024",
+]
+
+
+@pytest.fixture(scope="module")
+def bert_tokenizers(tmp_path_factory):
     transformers = pytest.importorskip("transformers")
-    vocab_file = tmp_path / "vocab.txt"
+    vocab_file = tmp_path_factory.mktemp("vocab") / "vocab.txt"
     vocab_file.write_text("\n".join(VOCAB) + "\n")
-    theirs = transformers.BertTokenizer(vocab_file=str(vocab_file))
-    ours = WordPieceTokenizer.from_vocab_file(str(vocab_file))
-    cases = [
-        "The quick brown fox jumps over the lazy dog!",
-        "streaming engines process words",        # ##ing / ##s pieces
-        "hello, world.",                           # punctuation splitting
-        "HELLO WoRLD",                             # lowercasing
-        "unknownword the",                         # [UNK] fallback
-        "  spaced\tout\n text ",
-        "café hello",                          # accent stripping
-    ]
-    for text in cases:
-        assert ours.encode(text) == theirs.encode(text), text
+    return (WordPieceTokenizer.from_vocab_file(str(vocab_file)),
+            transformers.BertTokenizer(vocab_file=str(vocab_file)))
+
+
+@pytest.mark.parametrize("text", CASES)
+def test_wordpiece_matches_transformers_bert_tokenizer(bert_tokenizers, text):
+    ours, theirs = bert_tokenizers
+    want = theirs.encode(text)
+    assert ours.encode(text) == want
+    assert ours.encode_batch([text, "the dog"])[0, :len(want)].tolist() == want
 
 
 def test_wordpiece_truncation_and_batch():
