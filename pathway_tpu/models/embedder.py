@@ -143,7 +143,7 @@ def _bert_block(x, layer, cfg: EmbedderConfig, mask):
 def embed_tokens(
     params: dict, token_ids: jax.Array, cfg: EmbedderConfig, *,
     segments: jax.Array | None = None, positions: jax.Array | None = None,
-    texts: int | None = None, scan: bool = False,
+    texts: int | None = None,
 ) -> jax.Array:
     """token_ids int32 [batch, seq] (0 = pad) -> f32 [batch, dim], L2-normed
     (mean pooling + normalize — the sentence-transformers MiniLM head).
@@ -155,16 +155,15 @@ def embed_tokens(
     Attention stays inside a segment and the pool and the norm are taken for
     each: -> f32 [``texts``, dim], a text that is not there a zero vector.
 
-    The layers are laid out one after another, each reading its own float32
-    matrices as they are kept (the compiler fetches a layer's while the one
-    before it runs). ``scan`` loops over one traced layer instead, its
-    matrices cast and stacked ahead of the loop (``_stacked``): a program then
-    costs a layer's compile and code, not a model's, and a second pass over
-    the parameters a call. Chip runs, PR 40, a program of [8, 16] at 24 x 1024:
-    1.84 ms laid out and 4.35 ms looped (0.60 and 1.08 at 12 x 768), which a
-    program bound by one read of its parameters cannot afford; at [5, 512] the
-    loop is 2.5 of 17.8 ms, and a laid-out program takes 6-13 s to compile
-    and 35-53 MB of device memory where a looped one takes 2 s and 2-5 MB."""
+    ``params["layers"]`` is a list of layers, laid out one after another
+    (the compiler fetches a layer's matrices while the one before it runs),
+    or the same tensors stacked along a leading layer axis
+    (``stack_layers``), which the program loops over with one traced layer:
+    it then costs a layer's compile and code, not a model's. Every matrix,
+    bias and table is cast to ``cfg.dtype`` where it is read, so float32
+    masters (``init_params``, ``load_hf_state_dict``) run as they are; the
+    ``Embedder`` keeps its parameters in that dtype (``resident_params``),
+    where the casts do nothing and the vectors are the same to the bit."""
     if segments is None:
         keep = token_ids > 0
         mask = keep[:, None, None, :]
@@ -182,11 +181,11 @@ def embed_tokens(
             x, params["emb_ln_scale"], params["emb_ln_bias"], cfg.ln_eps
         )
     block = _bert_block if bert else _block
-    if scan:
-        x, _ = jax.lax.scan(lambda h, layer: (block(h, layer, cfg, mask), None), x,
-                            _stacked(params["layers"], cfg.dtype))
+    layers = params["layers"]
+    if isinstance(layers, dict):
+        x, _ = jax.lax.scan(lambda h, layer: (block(h, layer, cfg, mask), None), x, layers)
     else:
-        for layer in params["layers"]:
+        for layer in layers:
             x = block(x, layer, cfg, mask)
     if not bert:
         x = _layernorm(x, params["ln_f_scale"], params["ln_f_bias"])
@@ -206,12 +205,42 @@ def embed_tokens(
     return pooled / jnp.linalg.norm(pooled, axis=-1, keepdims=True).clip(1e-9)
 
 
-def _stacked(layers: list[dict], dtype: Any) -> dict:
-    """The layers' tensors stacked along a leading layer axis, the form a scan
-    runs over; matrices and their biases in the compute dtype, where the
-    blocks cast them anyway, layernorm parameters as they are."""
-    return {k: jnp.stack([layer[k] if k.startswith("ln") else layer[k].astype(dtype)
-                          for layer in layers]) for k in layers[0]}
+def stack_layers(layers: list[dict]) -> dict:
+    """The layers' tensors stacked along a leading layer axis: the form
+    ``embed_tokens`` loops over."""
+    return {k: jnp.stack([layer[k] for layer in layers]) for k in layers[0]}
+
+
+def _is_norm(name: str) -> bool:
+    """A layernorm scale or bias: read in float32 (``_layernorm``)."""
+    return name.startswith(("ln", "emb_ln"))
+
+
+def _placed(name: str, value: Any, dtype: Any) -> jax.Array:
+    """One tensor on the device as the programs read it: a layernorm
+    parameter as it is, anything else cast to ``dtype`` there, the same
+    rounding as the cast inside a program."""
+    value = jnp.asarray(value)
+    return value if _is_norm(name) else value.astype(dtype)
+
+
+def resident_params(params: dict, cfg: EmbedderConfig) -> dict:
+    """The parameters as the ``Embedder``'s programs read them, placed on
+    the device one tensor at a time, so that a float32 set is never there
+    whole beside them: the matrices, their biases and the token, position
+    and type tables in ``cfg.dtype``, cast once here; layernorm scales and
+    biases as they are (float32). The layers are kept twice, as a list for
+    the programs that lay them out (``layers``) and stacked for those that
+    loop over them (``stacked``): a program of a few rows is bound by one
+    read of its parameters, and read from a stack, even by static slices,
+    it waits for each matrix where from a list the compiler fetches the next
+    layer's while one runs (chip readings: ``PERF.md`` section 6)."""
+    dt = cfg.dtype
+    out = {k: _placed(k, v, dt) for k, v in params.items() if k != "layers"}
+    out["layers"] = [{k: _placed(k, v, dt) for k, v in layer.items()}
+                     for layer in params["layers"]]
+    out["stacked"] = stack_layers(out["layers"])
+    return out
 
 
 def _np(v) -> np.ndarray:
@@ -229,7 +258,9 @@ def load_hf_state_dict(
     ``xpacks/llm/embedders.py:217`` wraps the same family) onto the
     TPU encoder. HF Linear weights are (out, in) — transposed here to the
     (in, out) matmul layout. Accepts torch tensors or arrays; tolerates the
-    ``bert.``-prefixed naming some exports use."""
+    ``bert.``-prefixed naming some exports use. The float32 masters stay on
+    the host: an ``Embedder`` places them in the dtype it computes in,
+    tensor by tensor (``resident_params``)."""
     sd = {k.removeprefix("bert."): v for k, v in state_dict.items()}
     tok = _np(sd["embeddings.word_embeddings.weight"])
     pos = _np(sd["embeddings.position_embeddings.weight"])
@@ -253,11 +284,11 @@ def load_hf_state_dict(
         max_len=pos.shape[0], arch="bert", ln_eps=1e-12,
     )
     params: dict = {
-        "tok_emb": jnp.asarray(tok),
-        "pos_emb": jnp.asarray(pos),
-        "type_emb": jnp.asarray(_np(sd["embeddings.token_type_embeddings.weight"])),
-        "emb_ln_scale": jnp.asarray(_np(sd["embeddings.LayerNorm.weight"])),
-        "emb_ln_bias": jnp.asarray(_np(sd["embeddings.LayerNorm.bias"])),
+        "tok_emb": tok,
+        "pos_emb": pos,
+        "type_emb": _np(sd["embeddings.token_type_embeddings.weight"]),
+        "emb_ln_scale": _np(sd["embeddings.LayerNorm.weight"]),
+        "emb_ln_bias": _np(sd["embeddings.LayerNorm.bias"]),
         "layers": [],
     }
     for i in range(n_layers):
@@ -271,12 +302,12 @@ def load_hf_state_dict(
             ("mlp_in", "intermediate.dense"),
             ("mlp_out", "output.dense"),
         ):
-            layer[f"{ours}_w"] = jnp.asarray(_np(sd[p + theirs + ".weight"]).T)
-            layer[f"{ours}_b"] = jnp.asarray(_np(sd[p + theirs + ".bias"]))
-        layer["ln1_scale"] = jnp.asarray(_np(sd[p + "attention.output.LayerNorm.weight"]))
-        layer["ln1_bias"] = jnp.asarray(_np(sd[p + "attention.output.LayerNorm.bias"]))
-        layer["ln2_scale"] = jnp.asarray(_np(sd[p + "output.LayerNorm.weight"]))
-        layer["ln2_bias"] = jnp.asarray(_np(sd[p + "output.LayerNorm.bias"]))
+            layer[f"{ours}_w"] = _np(sd[p + theirs + ".weight"]).T
+            layer[f"{ours}_b"] = _np(sd[p + theirs + ".bias"])
+        layer["ln1_scale"] = _np(sd[p + "attention.output.LayerNorm.weight"])
+        layer["ln1_bias"] = _np(sd[p + "attention.output.LayerNorm.bias"])
+        layer["ln2_scale"] = _np(sd[p + "output.LayerNorm.weight"])
+        layer["ln2_bias"] = _np(sd[p + "output.LayerNorm.bias"])
         params["layers"].append(layer)
     return params, cfg
 
@@ -346,24 +377,33 @@ class Embedder:
 
     def __init__(self, cfg: EmbedderConfig | None = None, seed: int = 0,
                  params: dict | None = None, tokenizer: Any = None):
+        """``params``: float32 masters (``init_params``'s tree, or
+        ``load_hf_state_dict``'s on the host), kept as ``resident_params``
+        makes them; the caller's own copy is not touched."""
+        from ..serve.stats import bump
+
         self.cfg = cfg or EmbedderConfig()
-        self.params = params if params is not None else init_params(self.cfg, seed)
+        #: every form of the parameters the programs read, and nothing else
+        #: of them on the device: setting it to None frees the encoder
+        self.params = resident_params(
+            params if params is not None else init_params(self.cfg, seed), self.cfg)
+        bump("embed_param_bytes_total",
+             sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.params)))
         self.tokenizer = tokenizer
         cfg = self.cfg
         #: every (rows, length) the served forward is run at, by length
         self.shapes = declared_shapes(cfg.max_len)
         self._rows_of = _rows_by_length(cfg.max_len)
-        question = self.shapes[0][1]
+        self._question = self.shapes[0][1]
 
         def forward(params, ids):
             # ids int32 [3, R, L]: tokens, segments, positions. Rows of question
             # length are bound by one read of the parameters, so their few
             # programs lay the layers out, as a search's program always did;
             # the passage programs are bound by their products, and loop over
-            # one layer: cheap to compile and to keep (``embed_tokens``)
+            # one layer: cheap to compile and to keep (``_handed``)
             return embed_tokens(params, ids[0], cfg, segments=ids[1],
-                                positions=ids[2], texts=TEXTS_PER_DISPATCH,
-                                scan=ids.shape[2] > question)
+                                positions=ids[2], texts=TEXTS_PER_DISPATCH)
 
         def token_rows(params, token_ids):
             return embed_tokens(params, token_ids, cfg)
@@ -427,7 +467,16 @@ class Embedder:
     def __call__(self, token_ids: np.ndarray) -> np.ndarray:
         """Rows of token ids (0 = pad), one text a row, at the caller's own
         shape: a program apart from the served ones."""
-        return np.asarray(self._token_rows(self.params, jnp.asarray(token_ids, jnp.int32)))
+        return np.asarray(self._token_rows(self._handed(looped=False),
+                                           jnp.asarray(token_ids, jnp.int32)))
+
+    def _handed(self, looped: bool) -> dict:
+        """The parameters one program is handed: the tables with the layers
+        stacked, for a program that loops over them, or as a list, for one
+        that lays them out; never the form it does not read."""
+        p = self.params
+        tables = {k: v for k, v in p.items() if k not in ("layers", "stacked")}
+        return {**tables, "layers": p["stacked"] if looped else p["layers"]}
 
     def warm(self) -> None:
         """Compile (or load from the compile cache) every program the served
@@ -451,7 +500,8 @@ class Embedder:
     def _forward(self, ids: np.ndarray) -> jax.Array:
         from ..serve.stats import bump
 
-        out = self._fwd(self.params, jnp.asarray(ids))
+        out = self._fwd(self._handed(looped=ids.shape[2] > self._question),
+                        jnp.asarray(ids))
         with self._count_lock:
             compiled = self._fwd._cache_size()
             if compiled != self._compiled:
@@ -607,7 +657,8 @@ class Embedder:
             ids[:, :width] = toks[of_length, :width]
             with self._dispatching(length, len(of_length), len(of_length),
                                    int(lengths[of_length].sum())):
-                sent.append((of_length, self._token_rows(self.params, jnp.asarray(ids))))
+                sent.append((of_length, self._token_rows(self._handed(looped=False),
+                                                         jnp.asarray(ids))))
         # every program is on its way before the first result is waited for
         for of_length, vectors in sent:
             out[of_length] = np.asarray(vectors)

@@ -89,6 +89,9 @@ SERVE_STATS: dict[str, int] = {
     "embed_dispatches_total": 0,
     "embed_truncated_texts_total": 0,
     "embed_shapes_compiled_total": 0,
+    #: bytes of parameters an ``Embedder`` placed on the device, every form
+    #: its programs read (``models/embedder.py::resident_params``), at load
+    "embed_param_bytes_total": 0,
     #: texts ``WordPieceTokenizer.encode_batch`` was handed / those of them
     #: that took its ASCII lane (the rest, texts that are not ASCII, were
     #: tokenized a character at a time in Python)

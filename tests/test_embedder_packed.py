@@ -5,7 +5,8 @@ positions, pool and norm. Held to the benchmark's plain float32 reference
 imports the benchmark's tests) and to the forward of each text alone: whole
 queries up to the model's own positions, nothing compiled after the warm, the
 stored path's rule, a served 300-token query, a filtered search that
-pads vectors and not texts, short questions at sixteen tokens a row."""
+pads vectors and not texts, short questions at sixteen tokens a row, and the
+parameters resident in the compute dtype with the float32 masters' bits."""
 
 import contextlib
 import http.client
@@ -228,12 +229,19 @@ def test_the_first_served_call_compiles_the_whole_declared_set():
 
 def test_question_programs_lay_the_layers_out_and_passage_programs_loop(built):
     emb = built[0]
-    layers = emb.params["layers"]  # kept as loaded: a list, float32, one copy
-    assert isinstance(layers, list) and len(layers) == MODEL["num_hidden_layers"]
+    # kept twice, in the compute dtype: a list for the programs that lay the
+    # layers out and the same tensors stacked for those that loop
+    layers, stacked = emb.params["layers"], emb.params["stacked"]
+    n = MODEL["num_hidden_layers"]
+    assert isinstance(layers, list) and len(layers) == n
+    assert set(stacked) == set(layers[0])
+    for k, v in stacked.items():
+        assert v.shape == (n, *layers[0][k].shape) and v.dtype == layers[0][k].dtype
 
     def loops(length):
         ids = jnp.asarray(embedder_mod._blank_ids(2, length))
-        return emb._fwd.lower(emb.params, ids).as_text().count("stablehlo.while")
+        return emb._fwd.lower(emb._handed(looped=length > 16), ids).as_text().count(
+            "stablehlo.while")
 
     # a passage program holds one layer's code, whatever the depth; a question
     # program is laid out layer by layer, as a search's program always was
@@ -241,9 +249,160 @@ def test_question_programs_lay_the_layers_out_and_passage_programs_loop(built):
     # and the two forms agree
     rows = np.asarray(built[3][:40]).reshape(4, 10)
     ids = np.asarray([emb.tokenizer.encode(" ".join(r)) for r in rows], np.int32)
-    a = embedder_mod.embed_tokens(emb.params, jnp.asarray(ids), emb.cfg)
-    b = embedder_mod.embed_tokens(emb.params, jnp.asarray(ids), emb.cfg, scan=True)
+    a = embedder_mod.embed_tokens(emb._handed(looped=False), jnp.asarray(ids), emb.cfg)
+    b = embedder_mod.embed_tokens(emb._handed(looped=True), jnp.asarray(ids), emb.cfg)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6, rtol=0)
+
+
+# -- (g) the parameters stay on the device in the compute dtype ---------------
+
+PRELN = embedder_mod.EmbedderConfig(vocab_size=1200, dim=32, n_layers=2, n_heads=4,
+                                    max_len=MAX_LEN)
+
+
+def _resident(arch: str, seed: int = SEED + 4):
+    """(bfloat16 embedder, its float32 masters, words): the pretrained
+    encoder from a state dict, or the self-contained pre-layernorm one."""
+    if arch == "bert":
+        lines, words = datagen.make_vocab(seed, MODEL["vocab_size"])
+        state = datagen.make_state_dict(seed, MODEL)
+        emb = Embedder.from_pretrained(
+            state, n_heads=MODEL["num_attention_heads"],
+            tokenizer=WordPieceTokenizer({w: i for i, w in enumerate(lines)}),
+            dtype=jnp.bfloat16)
+        masters = embedder_mod.load_hf_state_dict(
+            state, n_heads=MODEL["num_attention_heads"])[0]
+        return emb, masters, words
+    words = [f"w{i}" for i in range(300)]
+    return (Embedder(PRELN, seed=seed), embedder_mod.init_params(PRELN, seed), words)
+
+
+class _Spy:
+    """Stands for a jitted program: notes what each call is handed."""
+
+    def __init__(self, program):
+        self.program, self.calls = program, []
+
+    def __call__(self, params, ids):
+        self.calls.append((params, ids))
+        return self.program(params, ids)
+
+    def _cache_size(self):
+        return self.program._cache_size()
+
+
+def _spied(emb, monkeypatch):
+    fwd, rows = _Spy(emb._fwd), _Spy(emb._token_rows)
+    monkeypatch.setattr(emb, "_fwd", fwd)
+    monkeypatch.setattr(emb, "_token_rows", rows)
+    return fwd, rows
+
+
+@pytest.mark.parametrize("arch", ["bert", "preln"])
+def test_resident_parameters_give_the_float32_masters_bits(arch, monkeypatch):
+    """A question program, a passage program and a stored batch over the
+    resident parameters give the bits of ``embed_tokens`` over the float32
+    masters, laid out and looped alike: the cast at load rounds as the cast
+    in the program does."""
+    import jax
+
+    emb, masters, words = _resident(arch)
+    emb.warm()
+    fwd, rows = _spied(emb, monkeypatch)
+    cfg = emb.cfg
+    tables = {k: v for k, v in masters.items() if k != "layers"}
+    laid_out = {**tables, "layers": masters["layers"]}
+    looped = {**tables, "layers": embedder_mod.stack_layers(masters["layers"])}
+    served = jax.jit(lambda p, ids: embedder_mod.embed_tokens(
+        p, ids[0], cfg, segments=ids[1], positions=ids[2], texts=TEXTS_PER_DISPATCH))
+    stored = jax.jit(lambda p, ids: embedder_mod.embed_tokens(p, ids, cfg))
+    rng = np.random.default_rng([SEED, 10])
+
+    def texts(tokens):
+        return [" ".join(words[i] for i in rng.integers(0, len(words), size=t - 2))
+                for t in tokens]
+
+    question = texts((5, 9, 14))
+    got = np.asarray(emb.embed_texts_device(question))
+    ids = fwd.calls[-1][1]
+    assert ids.shape[2] == 16
+    assert (got == np.asarray(served(laid_out, ids))[:3]).all()
+    passages = texts((40, 60, 25, 64))
+    got = np.asarray(emb.embed_texts_device(passages))
+    ids = fwd.calls[-1][1]
+    assert ids.shape[2] == MAX_LEN
+    assert (got == np.asarray(served(looped, ids))[:4]).all()
+    batch = question + passages
+    got = emb.embed_texts(batch)
+    assert len(rows.calls) == 3  # lengths 16, 32 and 64
+    want = np.zeros_like(got)
+    for _, ids in rows.calls:
+        own = [i for i, t in enumerate(batch) if _stored_row(t) == ids.shape[1]]
+        want[own] = np.asarray(stored(laid_out, ids))
+    assert (got == want).all()
+
+
+@pytest.mark.parametrize("arch", ["bert", "preln"])
+def test_no_leaf_a_served_program_is_handed_is_float32_but_a_layernorms(arch, monkeypatch):
+    import jax
+
+    emb, _, words = _resident(arch)
+    emb.warm()
+    fwd, rows = _spied(emb, monkeypatch)
+    emb.embed_texts_device([" ".join(words[:8])])           # a question program
+    emb.embed_texts_device([" ".join(words[:50])] * 2)      # a passage program
+    emb.embed_texts([" ".join(words[:8]), " ".join(words[:50])])  # stored
+    emb(np.asarray([[2, 7, 9, 3]], np.int32))               # the caller's shape
+    assert len(fwd.calls) == 2 and len(rows.calls) == 3
+    forms = {}
+    for params, ids in fwd.calls + rows.calls:
+        leaves = jax.tree_util.tree_flatten_with_path(params)[0]
+        for path, leaf in leaves:
+            name = path[-1].key
+            want = jnp.float32 if embedder_mod._is_norm(name) else jnp.bfloat16
+            assert leaf.dtype == want, (path, leaf.dtype)
+        # a program is handed the form it reads, not both
+        assert "stacked" not in params
+        forms[ids.ndim == 3 and ids.shape[2] > 16] = type(params["layers"])
+    assert forms == {True: dict, False: list}
+
+
+@pytest.mark.parametrize("arch", ["bert", "preln"])
+def test_the_parameter_bytes_are_counted_at_load(arch):
+    import jax
+
+    before = SERVE_STATS["embed_param_bytes_total"]
+    emb, masters, _ = _resident(arch)
+    held = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(emb.params))
+    assert SERVE_STATS["embed_param_bytes_total"] - before == held
+
+    def resident(name, master):  # half a float32 master's bytes in bfloat16
+        return np.asarray(master).nbytes // (1 if embedder_mod._is_norm(name) else 2)
+
+    # the tables once and the layers twice, as a list and as a stack
+    assert held == (sum(resident(k, v) for k, v in masters.items() if k != "layers")
+                    + 2 * sum(resident(k, v) for layer in masters["layers"]
+                              for k, v in layer.items()))
+
+
+@pytest.mark.parametrize("arch", ["bert", "preln"])
+def test_setting_the_parameters_to_none_frees_the_encoder(arch):
+    import gc
+
+    import jax
+
+    def live():
+        gc.collect()
+        return sum(a.nbytes for a in jax.live_arrays())
+
+    base = live()
+    emb, masters, words = _resident(arch)
+    del masters
+    emb.embed_texts_device([" ".join(words[:8]), " ".join(words[:50])])
+    held = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(emb.params))
+    assert live() - base >= held
+    emb.params = None
+    assert live() == base
 
 
 # -- (d) a stored vector is a function of its text alone -----------------------

@@ -151,6 +151,31 @@ def test_bert_arch_matches_transformers_forward():
         Embedder.from_pretrained(model.state_dict())
 
 
+def test_a_state_dict_loads_on_the_host_and_serves_resident_in_bfloat16():
+    """The float32 masters stay on the host; the embedder places each tensor
+    in bfloat16 (layernorm parameters in float32), and its forward gives the
+    bits of the forward over the masters, which casts them where it reads
+    them."""
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.models.embedder import _is_norm, embed_tokens
+
+    model = _tiny_hf_bert()
+    masters, cfg = load_hf_state_dict(model.state_dict(), n_heads=4)
+    assert all(isinstance(leaf, np.ndarray) and leaf.dtype == np.float32
+               for leaf in jax.tree_util.tree_leaves(masters))
+    emb = Embedder.from_pretrained(model.state_dict(), dtype=jnp.bfloat16, n_heads=4)
+    for path, leaf in jax.tree_util.tree_flatten_with_path(emb.params)[0]:
+        assert isinstance(leaf, jax.Array)
+        assert leaf.dtype == (jnp.float32 if _is_norm(path[-1].key) else jnp.bfloat16)
+    ids = np.random.default_rng(4).integers(1, 64, size=(3, 12)).astype(np.int32)
+    ids[1, 5:] = 0
+    cfg = emb.cfg
+    want = jax.jit(lambda p, t: embed_tokens(p, t, cfg))(masters, jnp.asarray(ids))
+    assert (emb(ids) == np.asarray(want)).all()
+
+
 def test_from_pretrained_directory_with_vocab(tmp_path):
     import json
 
