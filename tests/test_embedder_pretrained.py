@@ -200,3 +200,59 @@ def test_from_pretrained_directory_with_vocab(tmp_path):
     # a different batch shape still lands within bf16 tolerance
     solo = emb.embed_texts(["the quick fox"], max_len=16)
     np.testing.assert_allclose(vecs[0], solo[0], atol=5e-3)
+
+
+def _gelu_reference(name: str):
+    if name == "jax":
+        import jax
+
+        return lambda x: np.asarray(jax.nn.gelu(x, approximate=False))
+    import torch
+
+    return lambda x: torch.nn.functional.gelu(torch.from_numpy(x)).numpy()
+
+
+@pytest.mark.parametrize("reference", ["jax", "torch"])
+def test_the_bert_gelu_is_the_exact_gelu(reference):
+    """``_gelu`` (through ``erf``) is ``jax.nn.gelu(approximate=False)``
+    (through ``erfc``) and PyTorch's ``gelu``, the function of HF BERT's
+    ``"gelu"``, to a few float32 ulps over the range a layer's
+    pre-activations take."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.models.embedder import _gelu
+
+    grid = np.linspace(-12.0, 12.0, 240_001, dtype=np.float32)
+    draws = np.random.default_rng(11).normal(0.0, 3.0, 1_000_000).astype(np.float32)
+    for x in (grid, draws):
+        ours = np.asarray(_gelu(jnp.asarray(x)))
+        assert ours.dtype == np.float32
+        np.testing.assert_allclose(ours, _gelu_reference(reference)(x), atol=4e-6, rtol=0)
+
+
+@pytest.mark.parametrize("program", ["question", "passage", "stored"])
+def test_every_encoder_program_computes_the_gelu_through_erf(program):
+    """Each kind of program the encoder runs holds ``erf`` and no ``erfc``:
+    the GELU of ``jax.nn.gelu(approximate=False)`` is ``erfc``, which the
+    TPU compiler fuses into the operand of the ``mlp_out`` product."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.models.embedder import _blank_ids
+
+    emb = Embedder.from_pretrained(_tiny_hf_bert().state_dict(), dtype=jnp.bfloat16,
+                                   n_heads=4)
+    question, passage = emb.shapes[0], emb.shapes[-1]
+    looped = program == "passage"
+    if program == "stored":
+        fn, ids = emb._token_rows, jnp.ones((3, 12), jnp.int32)
+    else:
+        rows, length = question if program == "question" else passage
+        assert (length > question[1]) == looped
+        fn, ids = emb._fwd, jnp.asarray(_blank_ids(rows, length))
+    text = str(jax.make_jaxpr(fn)(emb._handed(looped=looped), ids))
+    # one GELU a layer: laid out, each layer's; looped, the one traced layer's
+    assert len(re.findall(r"\berf\b", text)) == (1 if looped else emb.cfg.n_layers)
+    assert not re.search(r"\berfc\b", text)
