@@ -5,8 +5,8 @@
 drives the system's main path once through the entry points a user calls —
 ``examples/rag_server/serve.py``'s ``DocumentStoreServer`` at the full
 MiniLM-L6 widths of the default ``EmbedderConfig`` (6 layers, 384 wide, 12
-heads, 1536 feed-forward, vocabulary 30,528, bf16; random weights from a
-seed) — and checks that what comes out is right. It is the quickest proof
+heads, 1536 feed-forward, vocabulary 30,522, bf16; the BERT block with
+weights from a seed and the generated WordPiece vocabulary) — and checks that what comes out is right. It is the quickest proof
 that the system still starts on the chip. It measures nothing: the wall
 times it prints are smoke timings, not metrics.
 

@@ -6,8 +6,13 @@ encoder that runs on the MXU in bf16: mean-pooled, L2-normalized sentence
 embeddings. Weights can be tensor-parallel sharded over a mesh "model" axis
 (attention heads + MLP hidden split), with batch data-parallel over "data".
 
-Deterministic init (seeded) so the framework is self-contained; loading
-pretrained MiniLM-class weights is a straight param-tree mapping.
+One encoder: the published BERT block (post-layernorm, biases, exact GELU),
+numerically HF ``BertModel``, at all-MiniLM-L6-v2's widths by default. Its
+parameters come from a checkpoint (``load_hf_state_dict``) or from a seed
+(``init_params``, as BERT initialises them) in one layout, and its tokenizer is
+WordPiece: the checkpoint's vocabulary, or for a seeded encoder a vocabulary
+built in code (``WordPieceTokenizer.generated``), so the framework runs
+self-contained.
 """
 
 from __future__ import annotations
@@ -23,64 +28,60 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .wordpiece import WordPieceTokenizer
+
 
 @dataclasses.dataclass(frozen=True)
 class EmbedderConfig:
-    vocab_size: int = 30528
+    """The encoder's widths; the defaults are all-MiniLM-L6-v2's published
+    ``config.json``."""
+
+    vocab_size: int = 30522
     dim: int = 384
     n_layers: int = 6
     n_heads: int = 12
     mlp_ratio: int = 4
     max_len: int = 512
     dtype: Any = jnp.bfloat16
-    #: "preln" — the self-contained deterministic-init encoder;
-    #: "bert" — post-layernorm with biases, numerically matching HF
-    #: BertModel so MiniLM-class pretrained checkpoints load verbatim
-    arch: str = "preln"
-    ln_eps: float = 1e-6
+    ln_eps: float = 1e-12
 
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
 
 
+#: BERT's token type vocabulary (``type_vocab_size``): a single text is type 0
+TYPE_VOCAB = 2
+
+
 def init_params(cfg: EmbedderConfig, seed: int = 0) -> dict:
-    """Initialize a parameter pytree (dense f32 master weights)."""
-    key = jax.random.PRNGKey(seed)
-    keys = jax.random.split(key, 4 + 8 * cfg.n_layers)
-    k = iter(keys)
+    """Seeded float32 masters on the host in ``load_hf_state_dict``'s layout,
+    valued as BERT initialises them: matrices and tables normal at 0.02,
+    biases zero, layernorm scales one."""
+    rng = np.random.default_rng(seed)
 
-    def dense(kk, fan_in, shape):
-        return (jax.random.normal(kk, shape, jnp.float32) / np.sqrt(fan_in)).astype(
-            jnp.float32
-        )
+    def normal(*shape):
+        return rng.standard_normal(shape, dtype=np.float32) * np.float32(0.02)
 
-    params: dict = {
-        "tok_emb": dense(next(k), cfg.dim, (cfg.vocab_size, cfg.dim)),
-        "pos_emb": dense(next(k), cfg.dim, (cfg.max_len, cfg.dim)),
-        "ln_f_scale": jnp.ones((cfg.dim,), jnp.float32),
-        "ln_f_bias": jnp.zeros((cfg.dim,), jnp.float32),
-        "layers": [],
-    }
-    hidden = cfg.dim * cfg.mlp_ratio
+    def norm(prefix):
+        return {f"{prefix}_scale": np.ones(cfg.dim, np.float32),
+                f"{prefix}_bias": np.zeros(cfg.dim, np.float32)}
+
+    d, hidden = cfg.dim, cfg.dim * cfg.mlp_ratio
+    products = {"q": (d, d), "k": (d, d), "v": (d, d), "proj": (d, d),
+                "mlp_in": (d, hidden), "mlp_out": (hidden, d)}
+    params: dict = {"tok_emb": normal(cfg.vocab_size, d), "pos_emb": normal(cfg.max_len, d),
+                    "type_emb": normal(TYPE_VOCAB, d), **norm("emb_ln"), "layers": []}
     for _ in range(cfg.n_layers):
-        layer = {
-            "qkv": dense(next(k), cfg.dim, (cfg.dim, 3 * cfg.dim)),
-            "proj": dense(next(k), cfg.dim, (cfg.dim, cfg.dim)),
-            "mlp_in": dense(next(k), cfg.dim, (cfg.dim, hidden)),
-            "mlp_out": dense(next(k), hidden, (hidden, cfg.dim)),
-            "ln1_scale": jnp.ones((cfg.dim,), jnp.float32),
-            "ln1_bias": jnp.zeros((cfg.dim,), jnp.float32),
-            "ln2_scale": jnp.ones((cfg.dim,), jnp.float32),
-            "ln2_bias": jnp.zeros((cfg.dim,), jnp.float32),
-        }
-        params["layers"].append(layer)
-        for _ in range(4):
-            next(k, None)
+        layer = {}
+        for name, shape in products.items():
+            layer[f"{name}_w"] = normal(*shape)
+            layer[f"{name}_b"] = np.zeros(shape[1], np.float32)
+        params["layers"].append({**layer, **norm("ln1"), **norm("ln2")})
     return params
 
 
-def _layernorm(x, scale, bias, eps=1e-6):
+def _layernorm(x, scale, bias, eps):
     x32 = x.astype(jnp.float32)
     mu = x32.mean(-1, keepdims=True)
     var = x32.var(-1, keepdims=True)
@@ -91,57 +92,31 @@ def _gelu(h):
     """Exact GELU, h·Φ(h), as ``0.5·h·(1 + erf(h/√2))``: PyTorch's ``gelu``
     and so HF BERT's ``"gelu"``, the formula of the published checkpoints.
 
-    Not ``jax.nn.gelu(approximate=False)``, which is the same function through
-    ``erfc(-h/√2)``: XLA expands a float32 ``erfc`` into both of its branches
-    and a select, and on the TPU fuses that into the operand of the product
-    that reads the activation (``mlp_out``), which then runs at about half
-    its rate. ``erf`` stays one operation and lands in the epilogue of the
-    product that makes ``h`` (``mlp_in``)."""
+    Not JAX's own exact ``gelu`` (``approximate=False``), the same function
+    through ``erfc(-h/√2)``: XLA expands a float32 ``erfc`` into both of its
+    branches and a select, and on the TPU fuses that into the operand of the
+    product that reads the activation (``mlp_out``), which then runs at about
+    half its rate. ``erf`` stays one operation and lands in the epilogue of
+    the product that makes ``h`` (``mlp_in``)."""
     return 0.5 * h * (1.0 + jax.lax.erf(h * np.float32(np.sqrt(0.5))))
 
 
-def _block(x, layer, cfg: EmbedderConfig, mask):
-    # ``mask`` broadcasts to [batch, heads, query, key]: which keys a query sees
-    # attention — bf16 matmuls land on the MXU; softmax in f32
-    h = _layernorm(x, layer["ln1_scale"], layer["ln1_bias"])
-    b, s, d = h.shape
-    qkv = h @ layer["qkv"].astype(cfg.dtype)
-    q, kk, v = jnp.split(qkv, 3, axis=-1)
-
-    def heads(t):
-        return t.reshape(b, s, cfg.n_heads, cfg.head_dim).transpose(0, 2, 1, 3)
-
-    q, kk, v = heads(q), heads(kk), heads(v)
-    scores = (q @ kk.transpose(0, 1, 3, 2)).astype(jnp.float32) / np.sqrt(cfg.head_dim)
-    scores = jnp.where(mask, scores, -1e30)
-    att = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-    out = (att @ v).transpose(0, 2, 1, 3).reshape(b, s, d)
-    x = x + out @ layer["proj"].astype(cfg.dtype)
-    # MLP
-    h = _layernorm(x, layer["ln2_scale"], layer["ln2_bias"])
-    h = jax.nn.gelu(h @ layer["mlp_in"].astype(cfg.dtype))
-    x = x + h @ layer["mlp_out"].astype(cfg.dtype)
-    return x
-
-
-def _bert_block(x, layer, cfg: EmbedderConfig, mask):
+def _bert_block(x, layer, cfg: EmbedderConfig, attend):
     """Post-layernorm encoder block matching HF BertLayer exactly (dense
     biases, residual-then-LN, exact erf GELU). bf16/f32 matmuls on the MXU,
-    softmax + layernorm statistics in f32."""
-    b, s, d = x.shape
+    softmax + layernorm statistics in f32.
+
+    ``attend(project)`` is the block's attention, the one thing the forwards
+    vary: ``project(name)`` is ``x``'s [b, s, d] projection by ``q``, ``k`` or
+    ``v``, and it returns the attended values, [b, s, d]. It asks for each
+    projection where it first reads it, so a program's operations come in the
+    order of a block written out in one piece."""
     dt = cfg.dtype
 
     def dense(t, name):
         return t @ layer[f"{name}_w"].astype(dt) + layer[f"{name}_b"].astype(dt)
 
-    def heads(t):
-        return t.reshape(b, s, cfg.n_heads, cfg.head_dim).transpose(0, 2, 1, 3)
-
-    q, kk, v = heads(dense(x, "q")), heads(dense(x, "k")), heads(dense(x, "v"))
-    scores = (q @ kk.transpose(0, 1, 3, 2)).astype(jnp.float32) / np.sqrt(cfg.head_dim)
-    scores = jnp.where(mask, scores, -1e30)
-    att = jax.nn.softmax(scores, axis=-1).astype(dt)
-    out = (att @ v).transpose(0, 2, 1, 3).reshape(b, s, d)
+    out = attend(lambda name: dense(x, name))
     x = _layernorm(
         x + dense(out, "proj"), layer["ln1_scale"], layer["ln1_bias"], cfg.ln_eps
     )
@@ -151,6 +126,38 @@ def _bert_block(x, layer, cfg: EmbedderConfig, mask):
         layer["ln2_scale"], layer["ln2_bias"], cfg.ln_eps,
     )
     return x
+
+
+def _masked_attention(mask, cfg: EmbedderConfig):
+    """Softmax attention in which a query sees the keys ``mask`` lets it see
+    (``mask`` broadcasts to [batch, heads, query, key])."""
+
+    def attend(project):
+        q = project("q")
+        b, s, d = q.shape
+
+        def heads(t):
+            return t.reshape(b, s, cfg.n_heads, cfg.head_dim).transpose(0, 2, 1, 3)
+
+        q, kk, v = heads(q), heads(project("k")), heads(project("v"))
+        scores = (q @ kk.transpose(0, 1, 3, 2)).astype(jnp.float32) / np.sqrt(cfg.head_dim)
+        scores = jnp.where(mask, scores, -1e30)
+        att = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+        return (att @ v).transpose(0, 2, 1, 3).reshape(b, s, d)
+
+    return attend
+
+
+def _embeddings(params: dict, token_ids: jax.Array, pos: jax.Array,
+               cfg: EmbedderConfig) -> jax.Array:
+    """The first layer's input: each token's row plus its position's row
+    (``pos``, looked up by the caller) plus type 0's, through the embedding
+    layernorm."""
+    # the table is cast and then looked up: looked up in float32, the compiler
+    # copies the whole table twice a call (0.23 ms at 30,522 x 768; chip run)
+    x = params["tok_emb"].astype(cfg.dtype)[token_ids] + pos
+    x = x + params["type_emb"].astype(cfg.dtype)[0][None, None, :]
+    return _layernorm(x, params["emb_ln_scale"], params["emb_ln_bias"], cfg.ln_eps)
 
 
 def embed_tokens(
@@ -184,24 +191,15 @@ def embed_tokens(
     else:
         mask = (segments[:, :, None] == segments[:, None, :])[:, None, :, :]
         pos = params["pos_emb"].astype(cfg.dtype)[positions]
-    # the table is cast and then looked up: looked up in float32, the compiler
-    # copies the whole table twice a call (0.23 ms at 30,522 x 768; chip run)
-    x = params["tok_emb"].astype(cfg.dtype)[token_ids] + pos
-    bert = cfg.arch == "bert"
-    if bert:
-        x = x + params["type_emb"].astype(cfg.dtype)[0][None, None, :]
-        x = _layernorm(
-            x, params["emb_ln_scale"], params["emb_ln_bias"], cfg.ln_eps
-        )
-    block = _bert_block if bert else _block
+    x = _embeddings(params, token_ids, pos, cfg)
+    attend = _masked_attention(mask, cfg)
     layers = params["layers"]
     if isinstance(layers, dict):
-        x, _ = jax.lax.scan(lambda h, layer: (block(h, layer, cfg, mask), None), x, layers)
+        x, _ = jax.lax.scan(
+            lambda h, layer: (_bert_block(h, layer, cfg, attend), None), x, layers)
     else:
         for layer in layers:
-            x = block(x, layer, cfg, mask)
-    if not bert:
-        x = _layernorm(x, params["ln_f_scale"], params["ln_f_bias"])
+            x = _bert_block(x, layer, cfg, attend)
     x = x.astype(jnp.float32)
     if segments is None:
         # masked mean pool
@@ -294,7 +292,7 @@ def load_hf_state_dict(
     cfg = EmbedderConfig(
         vocab_size=tok.shape[0], dim=dim, n_layers=n_layers,
         n_heads=n_heads, mlp_ratio=max(1, inter // dim),
-        max_len=pos.shape[0], arch="bert", ln_eps=1e-12,
+        max_len=pos.shape[0], ln_eps=1e-12,
     )
     params: dict = {
         "tok_emb": tok,
@@ -390,9 +388,11 @@ class Embedder:
 
     def __init__(self, cfg: EmbedderConfig | None = None, seed: int = 0,
                  params: dict | None = None, tokenizer: Any = None):
-        """``params``: float32 masters (``init_params``'s tree, or
-        ``load_hf_state_dict``'s on the host), kept as ``resident_params``
-        makes them; the caller's own copy is not touched."""
+        """``params``: float32 masters (``load_hf_state_dict``'s tree on the
+        host; ``init_params(cfg, seed)``'s where none are given), kept as
+        ``resident_params`` makes them; the caller's own copy is not touched.
+        ``tokenizer``: a ``WordPieceTokenizer``; a seeded encoder given none
+        gets ``WordPieceTokenizer.generated``, one given ``params`` none."""
         from ..serve.stats import bump
 
         self.cfg = cfg or EmbedderConfig()
@@ -402,6 +402,8 @@ class Embedder:
             params if params is not None else init_params(self.cfg, seed), self.cfg)
         bump("embed_param_bytes_total",
              sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.params)))
+        if tokenizer is None and params is None:
+            tokenizer = WordPieceTokenizer.generated(self.cfg.vocab_size)
         self.tokenizer = tokenizer
         cfg = self.cfg
         #: every (rows, length) the served forward is run at, by length
@@ -467,8 +469,6 @@ class Embedder:
                     n_heads = int(_json.load(f)["num_attention_heads"])
             vocab_file = os.path.join(path, "vocab.txt")
             if tokenizer is None and os.path.exists(vocab_file):
-                from .wordpiece import WordPieceTokenizer
-
                 tokenizer = WordPieceTokenizer.from_vocab_file(vocab_file)
         else:
             state_dict = source
@@ -533,24 +533,20 @@ class Embedder:
         with span("embed.tokenize", q=len(texts)) as sp:
             # one token over the limit is asked for, so that a text that is
             # cut can be told from one that just fits
-            if self.tokenizer is not None:
-                toks = self.tokenizer.encode_batch(texts, limit + 1)
-            else:
-                if self.cfg.arch == "bert":
-                    raise RuntimeError(
-                        "pretrained (arch='bert') embedder has no tokenizer: "
-                        "the hashing stand-in would feed token ids the "
-                        "checkpoint was never trained on — load with a "
-                        "vocab.txt (WordPiece) or pass tokenizer="
-                    )
-                toks = tokenize_batch(texts, self.cfg.vocab_size, limit + 1)
+            if self.tokenizer is None:
+                raise RuntimeError(
+                    "pretrained embedder has no tokenizer: ids of another "
+                    "vocabulary would feed the checkpoint tokens it was never "
+                    "trained on — load with a vocab.txt (WordPiece) or pass "
+                    "tokenizer="
+                )
+            toks = self.tokenizer.encode_batch(texts, limit + 1)
             toks = np.array(toks, dtype=np.int32)  # a copy: a cut text is closed in place
             lengths = (toks > 0).sum(axis=1)
             cut = np.flatnonzero(lengths > limit)
             if len(cut):
-                if self.tokenizer is not None:
-                    # a tokenizer's last token closes the text ([SEP]): kept
-                    toks[cut, limit - 1] = toks[cut, limit]
+                # the tokenizer's last token closes the text ([SEP]): kept
+                toks[cut, limit - 1] = toks[cut, limit]
                 toks[cut, limit:] = 0
                 lengths[cut] = limit
                 bump("embed_truncated_texts_total", len(cut))
@@ -676,22 +672,3 @@ class Embedder:
         for of_length, vectors in sent:
             out[of_length] = np.asarray(vectors)
         return out
-
-
-def tokenize_batch(texts: list[str], vocab_size: int, max_len: int) -> np.ndarray:
-    """Deterministic hashing tokenizer (feature-hashing — a self-contained
-    stand-in for a learned vocab; swap with a real WordPiece for pretrained
-    weights): int32 [batch, longest text], at most ``max_len`` wide, 0 = pad."""
-    words = [t.lower().split()[:max_len] for t in texts]
-    out = np.zeros((len(texts), max((len(w) for w in words), default=0)), dtype=np.int32)
-    for i, ws in enumerate(words):
-        for j, w in enumerate(ws):
-            out[i, j] = (hash_word(w) % (vocab_size - 2)) + 2
-    return out
-
-
-def hash_word(w: str) -> int:
-    h = 2166136261
-    for ch in w.encode("utf-8"):
-        h = ((h ^ ch) * 16777619) & 0xFFFFFFFF
-    return h

@@ -31,12 +31,17 @@ from .embedder import EmbedderConfig, embed_tokens, init_params
 
 
 def param_shardings(mesh: Mesh, params: dict) -> dict:
-    """Tensor-parallel layout: split QKV/MLP hidden over the model axis."""
+    """Tensor-parallel layout: the query, key, value and MLP-in products
+    split by column over the model axis (their biases with them), the
+    products that read those columns (``proj``, ``mlp_out``) by row; the
+    rest replicated."""
 
-    def spec_for(path: str):
-        if path in ("qkv", "mlp_in"):
+    def spec_for(name: str):
+        if name in ("q_w", "k_w", "v_w", "mlp_in_w"):
             return P(None, "model")
-        if path in ("proj", "mlp_out"):
+        if name in ("q_b", "k_b", "v_b", "mlp_in_b"):
+            return P("model")
+        if name in ("proj_w", "mlp_out_w"):
             return P("model", None)
         return P()
 

@@ -115,38 +115,6 @@ def ring_attention(
     return inner(q, k, v, mask)
 
 
-def ring_encoder_block(
-    x: jax.Array,
-    mask: jax.Array,
-    layer: dict[str, Any],
-    cfg: Any,
-    mesh: Mesh,
-    axis: str,
-) -> jax.Array:
-    """One transformer encoder block with sequence-parallel attention —
-    the long-context variant of ``models.embedder._block`` (same params)."""
-    from .embedder import _layernorm
-
-    h = _layernorm(x, layer["ln1_scale"], layer["ln1_bias"])
-    b, s, d = h.shape
-    qkv = h @ layer["qkv"].astype(cfg.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-
-    def heads(t):
-        return t.reshape(b, s, cfg.n_heads, cfg.head_dim)
-
-    att = ring_attention(
-        heads(q), heads(k), heads(v), mask, mesh, axis,
-        scale=1.0 / float(cfg.head_dim) ** 0.5,
-    )
-    out = att.reshape(b, s, d).astype(cfg.dtype)
-    x = x + out @ layer["proj"].astype(cfg.dtype)
-    h = _layernorm(x, layer["ln2_scale"], layer["ln2_bias"])
-    h = jax.nn.gelu(h @ layer["mlp_in"].astype(cfg.dtype))
-    x = x + h @ layer["mlp_out"].astype(cfg.dtype)
-    return x
-
-
 def embed_tokens_long(
     params: dict,
     token_ids: jax.Array,
@@ -154,21 +122,31 @@ def embed_tokens_long(
     mesh: Mesh,
     axis: str = "data",
 ) -> jax.Array:
-    """Long-context embedding forward: the sequence dimension is sharded
-    over `axis`, attention runs as a ring, pooling reduces with a psum-style
-    global mean. token_ids int32 [B, S] (0 = pad), S % mesh.shape[axis] == 0.
-    Positions use modular position embeddings for S beyond cfg.max_len."""
-    from .embedder import _layernorm
+    """Long-context embedding forward: the encoder of
+    ``models.embedder.embed_tokens`` (same parameters, same block) with the
+    sequence dimension sharded over `axis`, attention run as a ring, and a
+    global masked mean pool. token_ids int32 [B, S] (0 = pad),
+    S % mesh.shape[axis] == 0. Positions use modular position embeddings for
+    S beyond cfg.max_len."""
+    from .embedder import _bert_block, _embeddings
 
     mask = token_ids > 0
     s = token_ids.shape[1]
-    pos = jnp.arange(s) % params["pos_emb"].shape[0]
-    x = params["tok_emb"].astype(cfg.dtype)[token_ids] + params["pos_emb"].astype(
-        cfg.dtype
-    )[pos][None, :, :]
+    pos = params["pos_emb"].astype(cfg.dtype)[jnp.arange(s) % params["pos_emb"].shape[0]]
+    x = _embeddings(params, token_ids, pos[None, :, :], cfg)
+
+    def attend(project):
+        def heads(t):
+            return t.reshape(*t.shape[:2], cfg.n_heads, cfg.head_dim)
+
+        att = ring_attention(
+            heads(project("q")), heads(project("k")), heads(project("v")), mask,
+            mesh, axis, scale=1.0 / float(cfg.head_dim) ** 0.5,
+        )
+        return att.reshape(*att.shape[:2], cfg.dim).astype(cfg.dtype)
+
     for layer in params["layers"]:
-        x = ring_encoder_block(x, mask, layer, cfg, mesh, axis)
-    x = _layernorm(x, params["ln_f_scale"], params["ln_f_bias"])
+        x = _bert_block(x, layer, cfg, attend)
     m = mask[:, :, None].astype(jnp.float32)
     pooled = (x.astype(jnp.float32) * m).sum(1) / jnp.maximum(m.sum(1), 1.0)
     return pooled / jnp.linalg.norm(pooled, axis=-1, keepdims=True).clip(1e-9)

@@ -6,9 +6,10 @@ accent stripping / punctuation splitting / CJK spacing, then greedy
 longest-match-first WordPiece with ``##`` continuations) so pretrained
 checkpoints see exactly the token ids they were trained with. Verified
 against ``transformers.BertTokenizer`` over a shared vocab in
-``tests/test_embedder_pretrained.py``. Replaces the hashing stand-in that
-``models/embedder.py`` shipped before pretrained weights existed
-(reference: ``python/pathway/xpacks/llm/embedders.py:217``
+``tests/test_embedder_pretrained.py``. The only tokenizer of
+``models/embedder.py``: a seeded encoder reads ids of a vocabulary built in
+code (``WordPieceTokenizer.generated``), a checkpoint those of its own
+``vocab.txt`` (reference: ``python/pathway/xpacks/llm/embedders.py:217``
 SentenceTransformerEmbedder's underlying tokenizer).
 
 Two routes to the same ids. ``encode`` is the exact path: it asks
@@ -32,6 +33,7 @@ word, to ids; the vocabulary is the only table
 from __future__ import annotations
 
 import re
+import string
 import unicodedata
 
 import numpy as np
@@ -101,6 +103,20 @@ class WordPieceTokenizer:
                 if tok:
                     vocab[tok] = i
         return cls(vocab, **kwargs)
+
+    @classmethod
+    def generated(cls, vocab_size: int) -> "WordPieceTokenizer":
+        """A vocabulary built in code, for an encoder seeded rather than
+        loaded: ``[PAD]`` (id 0), ``[UNK]``, ``[CLS]``, ``[SEP]``, ``[MASK]``,
+        the lower-case ASCII letters and digits, the 32 ASCII punctuation
+        marks (split off by the basic tokenizer, so never continued) and the
+        ``##`` forms of the letters and digits: 109 ids, the first
+        ``vocab_size`` of them kept. A word of printable ASCII costs an id a
+        character, and only one over ``max_chars_per_word`` is ``[UNK]``."""
+        chars = string.ascii_lowercase + string.digits
+        tokens = ["[PAD]", "[UNK]", "[CLS]", "[SEP]", "[MASK]", *chars,
+                  *string.punctuation, *(f"##{c}" for c in chars)]
+        return cls({t: i for i, t in enumerate(tokens[:vocab_size])})
 
     # -- basic tokenization (BERT BasicTokenizer) --------------------------
 

@@ -62,8 +62,10 @@ class TpuEmbedder(BaseEmbedder):
         """``model_path``: local directory with a MiniLM-class HF checkpoint
         (``pytorch_model.bin`` + ``vocab.txt``) — loads pretrained weights
         and the real WordPiece tokenizer (``models/embedder.py``
-        ``Embedder.from_pretrained``). Default: deterministic-init encoder
-        (self-contained, no checkpoint needed). ``max_len``: tokens a text is
+        ``Embedder.from_pretrained``). Default: the same BERT encoder at
+        all-MiniLM-L6-v2's widths with weights from a seed and a vocabulary
+        built in code (``WordPieceTokenizer.generated``, an id a character):
+        self-contained, no checkpoint needed. ``max_len``: tokens a text is
         cut at; the encoder's own positions where none is given, as
         sentence-transformers' ``max_seq_length``."""
         super().__init__(**kwargs)

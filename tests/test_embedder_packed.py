@@ -256,14 +256,11 @@ def test_question_programs_lay_the_layers_out_and_passage_programs_loop(built):
 
 # -- (g) the parameters stay on the device in the compute dtype ---------------
 
-PRELN = embedder_mod.EmbedderConfig(vocab_size=1200, dim=32, n_layers=2, n_heads=4,
-                                    max_len=MAX_LEN)
-
-
-def _resident(arch: str, seed: int = SEED + 4):
-    """(bfloat16 embedder, its float32 masters, words): the pretrained
-    encoder from a state dict, or the self-contained pre-layernorm one."""
-    if arch == "bert":
+def _resident(kind: str, seed: int = SEED + 4):
+    """(bfloat16 embedder, its float32 masters, words): the encoder from a
+    state dict, or from a seed with the generated vocabulary (whose letters,
+    digits and punctuation marks are words of one token each)."""
+    if kind == "pretrained":
         lines, words = datagen.make_vocab(seed, MODEL["vocab_size"])
         state = datagen.make_state_dict(seed, MODEL)
         emb = Embedder.from_pretrained(
@@ -273,8 +270,13 @@ def _resident(arch: str, seed: int = SEED + 4):
         masters = embedder_mod.load_hf_state_dict(
             state, n_heads=MODEL["num_attention_heads"])[0]
         return emb, masters, words
-    words = [f"w{i}" for i in range(300)]
-    return (Embedder(PRELN, seed=seed), embedder_mod.init_params(PRELN, seed), words)
+    cfg = embedder_mod.EmbedderConfig(
+        vocab_size=MODEL["vocab_size"], dim=MODEL["hidden_size"],
+        n_layers=MODEL["num_hidden_layers"], n_heads=MODEL["num_attention_heads"],
+        mlp_ratio=MODEL["intermediate_size"] // MODEL["hidden_size"], max_len=MAX_LEN)
+    emb = Embedder(cfg, seed=seed)
+    words = [w for w in emb.tokenizer.vocab if len(w) == 1]
+    return emb, embedder_mod.init_params(cfg, seed), words
 
 
 class _Spy:
@@ -298,15 +300,15 @@ def _spied(emb, monkeypatch):
     return fwd, rows
 
 
-@pytest.mark.parametrize("arch", ["bert", "preln"])
-def test_resident_parameters_give_the_float32_masters_bits(arch, monkeypatch):
+@pytest.mark.parametrize("kind", ["pretrained", "seeded"])
+def test_resident_parameters_give_the_float32_masters_bits(kind, monkeypatch):
     """A question program, a passage program and a stored batch over the
     resident parameters give the bits of ``embed_tokens`` over the float32
     masters, laid out and looped alike: the cast at load rounds as the cast
     in the program does."""
     import jax
 
-    emb, masters, words = _resident(arch)
+    emb, masters, words = _resident(kind)
     emb.warm()
     fwd, rows = _spied(emb, monkeypatch)
     cfg = emb.cfg
@@ -342,11 +344,11 @@ def test_resident_parameters_give_the_float32_masters_bits(arch, monkeypatch):
     assert (got == want).all()
 
 
-@pytest.mark.parametrize("arch", ["bert", "preln"])
-def test_no_leaf_a_served_program_is_handed_is_float32_but_a_layernorms(arch, monkeypatch):
+@pytest.mark.parametrize("kind", ["pretrained", "seeded"])
+def test_no_leaf_a_served_program_is_handed_is_float32_but_a_layernorms(kind, monkeypatch):
     import jax
 
-    emb, _, words = _resident(arch)
+    emb, _, words = _resident(kind)
     emb.warm()
     fwd, rows = _spied(emb, monkeypatch)
     emb.embed_texts_device([" ".join(words[:8])])           # a question program
@@ -367,12 +369,12 @@ def test_no_leaf_a_served_program_is_handed_is_float32_but_a_layernorms(arch, mo
     assert forms == {True: dict, False: list}
 
 
-@pytest.mark.parametrize("arch", ["bert", "preln"])
-def test_the_parameter_bytes_are_counted_at_load(arch):
+@pytest.mark.parametrize("kind", ["pretrained", "seeded"])
+def test_the_parameter_bytes_are_counted_at_load(kind):
     import jax
 
     before = SERVE_STATS["embed_param_bytes_total"]
-    emb, masters, _ = _resident(arch)
+    emb, masters, _ = _resident(kind)
     held = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(emb.params))
     assert SERVE_STATS["embed_param_bytes_total"] - before == held
 
@@ -385,8 +387,8 @@ def test_the_parameter_bytes_are_counted_at_load(arch):
                               for k, v in layer.items()))
 
 
-@pytest.mark.parametrize("arch", ["bert", "preln"])
-def test_setting_the_parameters_to_none_frees_the_encoder(arch):
+@pytest.mark.parametrize("kind", ["pretrained", "seeded"])
+def test_setting_the_parameters_to_none_frees_the_encoder(kind):
     import gc
 
     import jax
@@ -396,7 +398,7 @@ def test_setting_the_parameters_to_none_frees_the_encoder(arch):
         return sum(a.nbytes for a in jax.live_arrays())
 
     base = live()
-    emb, masters, words = _resident(arch)
+    emb, masters, words = _resident(kind)
     del masters
     emb.embed_texts_device([" ".join(words[:8]), " ".join(words[:50])])
     held = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(emb.params))

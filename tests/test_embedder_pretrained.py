@@ -1,6 +1,6 @@
 """Pretrained-embedder path: WordPiece tokenizer parity with
-``transformers.BertTokenizer`` and numerical parity of the BERT-arch JAX
-encoder with ``transformers.BertModel`` over a loaded HF state dict.
+``transformers.BertTokenizer`` and numerical parity of the JAX encoder with
+``transformers.BertModel`` over a loaded HF state dict.
 
 Everything runs offline: the HF model is random-initialized from a config
 (no download), its state dict loaded through ``load_hf_state_dict``, and
@@ -120,7 +120,7 @@ def test_bert_arch_matches_transformers_forward():
     emb = Embedder.from_pretrained(
         model.state_dict(), dtype=jnp.float32, n_heads=4
     )
-    assert emb.cfg.arch == "bert" and emb.cfg.n_layers == 2
+    assert emb.cfg.n_layers == 2
     rng = np.random.default_rng(3)
     ids = rng.integers(1, 64, size=(3, 10)).astype(np.int32)
     ids[0, 7:] = 0  # padding on one row
@@ -149,6 +149,36 @@ def test_bert_arch_matches_transformers_forward():
     # head count is required for raw state dicts (not derivable from shapes)
     with pytest.raises(ValueError, match="n_heads"):
         Embedder.from_pretrained(model.state_dict())
+
+
+def test_seeded_parameters_are_laid_out_as_a_loaded_checkpoints():
+    """``init_params`` gives ``load_hf_state_dict``'s tree at the same widths:
+    the same keys, shapes and float32 host arrays, so a seeded encoder and a
+    loaded one run the same programs. The default widths are
+    all-MiniLM-L6-v2's published ``config.json``."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.models.embedder import EmbedderConfig, init_params
+
+    loaded, cfg = load_hf_state_dict(_tiny_hf_bert().state_dict(), n_heads=4)
+    seeded = init_params(cfg, seed=3)
+    (want, want_tree), (got, got_tree) = (
+        jax.tree_util.tree_flatten_with_path(t) for t in (loaded, seeded))
+    assert got_tree == want_tree
+    for (path, a), (_, b) in zip(want, got):
+        assert isinstance(b, np.ndarray), path
+        assert (b.shape, b.dtype) == (a.shape, a.dtype), path
+    # valued as BERT initialises them: normal at 0.02, biases zero, norms one
+    layer = seeded["layers"][1]
+    assert abs(float(layer["mlp_in_w"].std()) - 0.02) < 2e-3
+    assert not layer["q_b"].any() and (layer["ln2_scale"] == 1).all()
+    assert not (seeded["tok_emb"] == init_params(cfg, seed=4)["tok_emb"]).all()
+    assert dataclasses.asdict(EmbedderConfig()) == dict(
+        vocab_size=30522, dim=384, n_layers=6, n_heads=12, mlp_ratio=4,
+        max_len=512, dtype=jnp.bfloat16, ln_eps=1e-12)
 
 
 def test_a_state_dict_loads_on_the_host_and_serves_resident_in_bfloat16():

@@ -1,5 +1,6 @@
 """Ring attention (sequence parallelism): exact agreement with full
-attention on the 8-device CPU mesh, and the long-context embedder forward."""
+attention on the 8-device CPU mesh, and the long-context embedder forward:
+the encoder's own block with the ring for its attention."""
 
 from __future__ import annotations
 
@@ -9,7 +10,7 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from pathway_tpu.models.embedder import EmbedderConfig, init_params  # noqa: E402
+from pathway_tpu.models.embedder import EmbedderConfig, embed_tokens, init_params  # noqa: E402
 from pathway_tpu.models.ring_attention import (  # noqa: E402
     embed_tokens_long,
     full_attention,
@@ -68,3 +69,20 @@ def test_long_context_embedding(mesh):
         mesh1 = Mesh(np.array(jax.devices()[:1]), ("seq",))
     emb1 = np.asarray(embed_tokens_long(params, jnp.asarray(tokens), cfg, mesh1, "seq"))
     np.testing.assert_allclose(emb, emb1, rtol=5e-5, atol=5e-5)
+
+
+def test_the_ring_forward_is_the_encoders_forward_within_its_positions(mesh):
+    """Up to the model's positions the ring forward over eight devices gives
+    ``embed_tokens``' vectors: the same block, parameters and pool, only the
+    attention computed another way."""
+    cfg = EmbedderConfig(
+        vocab_size=512, dim=32, n_layers=2, n_heads=4, max_len=64,
+        dtype=jnp.float32,
+    )
+    params = init_params(cfg, 5)
+    tokens = np.random.default_rng(2).integers(1, cfg.vocab_size, (3, cfg.max_len)).astype(np.int32)
+    tokens[1, 40:] = 0
+    tokens[2, 9:] = 0
+    want = embed_tokens(params, jnp.asarray(tokens), cfg)
+    got = embed_tokens_long(params, jnp.asarray(tokens), cfg, mesh, "seq")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-5, rtol=0)

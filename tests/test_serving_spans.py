@@ -182,13 +182,14 @@ def test_every_span_of_the_serving_path_with_shared_ids(tmp_path):
         for e in by_name[name]:
             assert e["args"]["parent"] == "index.embed"
             assert _inside(e, embeds[e["args"]["tick"]]), name
-    # one four-word question: one text of four real tokens in a program of
-    # one row of sixteen, and the tokenizer says how many tokens it made
+    # one question, "what is row 0": under the generated vocabulary a token a
+    # character and [CLS] and [SEP], twelve real tokens in a program of one
+    # row of sixteen, and the tokenizer says how many tokens it made
     assert by_name["embed.dispatch"][0]["args"] == {
-        "bucket": 16, "rows": 1, "texts": 1, "tokens": 4, "computed": 16,
+        "bucket": 16, "rows": 1, "texts": 1, "tokens": 12, "computed": 16,
         "parent": "index.embed",
         "tick": by_name["embed.dispatch"][0]["args"]["tick"]}
-    assert by_name["embed.tokenize"][0]["args"]["tokens"] == 4
+    assert by_name["embed.tokenize"][0]["args"]["tokens"] == 12
     assert by_name["index.upload"][0]["args"]["bytes"] == ROWS * DIM * 4
     # the write path: one index.apply per fed block, inside its tick
     applies = by_name["index.apply"]
@@ -280,8 +281,9 @@ def test_each_counter_equals_the_calls_made():
     assert 2 * 4 - 1 <= got["connector_windows_total"] <= 2 * 4 + 1
     assert SERVE_STATS["connector_windows_total"] - at_start["connector_windows_total"] >= (
         ROWS // BLOCK + 2 * 5 - 1)
-    # "what is row <i>": four tokens in a 16-token bucket, three times
-    assert got["embed_real_tokens_total"] == 12 and got["embed_padded_tokens_total"] == 48
+    # "what is row <i>": twelve tokens (ten characters, [CLS], [SEP]) in a
+    # 16-token bucket, three times
+    assert got["embed_real_tokens_total"] == 36 and got["embed_padded_tokens_total"] == 48
     # a dispatch a search, nothing cut; the whole declared set of programs
     # (a model of 32 positions: five of sixteen tokens a row, four of 32) was
     # compiled by the first

@@ -161,3 +161,25 @@ def test_an_empty_batch_counts_nothing():
     tok = WordPieceTokenizer({t: i for i, t in enumerate(VOCAB)})
     got, n, fast = _counted(tok, [], 16)
     assert got.shape == (0, 0) and (n, fast) == (0, 0)
+
+
+def test_the_generated_vocabulary():
+    """The vocabulary of an encoder seeded rather than loaded: [PAD] at 0,
+    every id under the encoder's vocabulary, a token a character, no [UNK]
+    for printable ASCII, and the lane gives ``encode``'s ids."""
+    for size in (64, 109, 128, 30522):
+        vocab = WordPieceTokenizer.generated(size).vocab
+        assert vocab["[PAD]"] == 0 and sorted(vocab.values()) == list(range(min(size, 109)))
+    tok = WordPieceTokenizer.generated(30522)
+    v = tok.vocab
+    assert tok.pad_id == 0
+    assert tok.encode("hello") == [v["[CLS]"], v["h"], v["##e"], v["##l"], v["##l"], v["##o"],
+                                   v["[SEP]"]]
+    rng = random.Random("generated")
+    printable = [chr(c) for c in range(32, 127)] + SPACES
+    texts = ["".join(rng.choices(printable, k=rng.randint(0, 400))) for _ in range(200)]
+    texts += ["Hello, World! 2024-10-16", string.punctuation, string.ascii_letters]
+    got, n, fast = _counted(tok, texts, None)
+    assert n == fast == len(texts)
+    assert got.tolist() == _exact(tok, texts, None).tolist()
+    assert tok.unk_id not in got
