@@ -40,7 +40,7 @@ for p in (ROOT, BENCH):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from lib import check, datagen, peaks, spans as spans_mod, traffic  # noqa: E402
+from lib import check, datagen, files, peaks, spans as spans_mod, traffic  # noqa: E402
 
 NO_CHIP_EXIT = 3
 #: a scoped warm-up ends after this many sweeps even if the last still compiled
@@ -134,28 +134,28 @@ class GcPauses:
 
 
 class Deployment:
-    """The data of one run, all from the seed: vocabulary, pool, weights, rows."""
+    """The data of one run, all from the seed: vocabulary, pool, weights, rows.
+    What is a model's comes from its family (``fam``, the file ``run.py``
+    found for the configuration's ``model_type``)."""
 
     def __init__(self, spec: dict):
         self.spec = spec
         cfg, mix, seed = spec["config"], spec["mix"], spec["seed"]
         self.cfg, self.mix, self.seed = cfg, mix, seed
-        self.model = {k: cfg[k] for k in (
-            "hidden_size", "num_hidden_layers", "num_attention_heads",
-            "intermediate_size", "vocab_size", "max_position_embeddings",
-            "type_vocab_size", "layer_norm_eps", "initializer_range")}
+        self.fam = fam = files.load_module(spec["family"])
+        self.model = {k: cfg[k] for k in fam.MODEL_KEYS}
         self.dim = cfg["hidden_size"]
         self.chunks = cfg["chunks_per_doc"]
         self.n_rows = cfg["rows"]
         self.docs = self.n_rows // self.chunks
         self.k = cfg["k"]
-        #: tokens of the mix's longest query, [CLS] and [SEP] with it: the width
-        #: the reference pads to (padding is masked, so any width that holds
-        #: the longest gives the same vectors)
-        self.query_width = int(mix["query"]["words_max"]) + 2
-        self.vocab_lines, self.words = datagen.make_vocab(seed, cfg["vocab_size"])
+        #: tokens of the mix's longest query, framed as its family frames it:
+        #: the width the reference pads to (padding is masked, so any width
+        #: that holds the longest gives the same vectors)
+        self.query_width = int(mix["query"]["words_max"]) + fam.FRAMING
+        self.vocab_lines, self.words = datagen.vocabulary(seed, cfg["vocab_size"], fam)
         self.vocab_index = {w: i for i, w in enumerate(self.vocab_lines)}
-        self.pool = traffic.QueryPool(seed, self.words, mix["query"])
+        self.pool = traffic.QueryPool(seed, self.words, mix["query"], fam.FRAMING)
         #: the probe text is the candidate whose embedding stands farthest off
         #: the cone's axis (``plant`` chooses; the parent is told when ready)
         self.probe_candidates = datagen.make_texts(seed, 9, self.words, 16, 6, 6, 6)
@@ -179,14 +179,14 @@ class Deployment:
             if mix.get("scope") else None
         )
         self.rows: np.ndarray | None = None
-        self.state_dict: dict | None = None
+        self.weights: dict | None = None
         self.u = self.w = None
         self._version_cache: dict[int, np.ndarray] = {}
 
     def make_weights(self) -> None:
         """On the host, in threads (numpy frees the interpreter lock), while
         the main thread imports JAX."""
-        self.state_dict = datagen.make_state_dict(self.seed, self.model)
+        self.weights = self.fam.make_weights(self.seed, self.model)
 
     def make_rows(self) -> None:
         """The base rows, likewise, while the reference embeds the pool."""
@@ -200,8 +200,8 @@ class Deployment:
         from lib import reference
 
         texts = self.pool.texts + self.probe_candidates
-        ids = reference.tokenize(texts, self.vocab_index, self.query_width)
-        self._emb = reference.encode(ref_params, ids, self.model)
+        ids = reference.token_ids(texts, self.vocab_index, self.query_width, self.fam)
+        self._emb = self.fam.encode(ref_params, ids, self.model)
 
     def plant(self) -> None:
         rng = datagen.stream(self.seed, 30)
@@ -257,17 +257,12 @@ def build_server(dep: Deployment, port: int, state: dict):
     import jax.numpy as jnp
 
     import pathway_tpu as pw
-    from pathway_tpu.models.embedder import Embedder
-    from pathway_tpu.models.wordpiece import WordPieceTokenizer
     from pathway_tpu.stdlib.indexing.nearest_neighbors import BruteForceKnnFactory
     from pathway_tpu.xpacks.llm.document_store import DocumentStore
     from pathway_tpu.xpacks.llm.servers import DocumentStoreServer
 
     dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[dep.cfg["compute_dtype"]]
-    embedder = Embedder.from_pretrained(
-        dep.state_dict, n_heads=dep.model["num_attention_heads"],
-        tokenizer=WordPieceTokenizer(dict(dep.vocab_index)), dtype=dtype,
-    )
+    embedder = dep.fam.build_embedder(dep.weights, dep.model, dep.vocab_index, dtype)
 
     class Feed(pw.io.python.ConnectorSubject):
         """The source: the base rows in blocks, then the writer's commits on
@@ -359,7 +354,7 @@ def wrap_layers(engine, embedder, rec: spans_mod.Spans) -> None:
     embedder.embed_texts_device = spanned_embed
 
 
-def plant_fault(name: str, engine, embedder) -> None:
+def plant_fault(name: str, engine, embedder, fam) -> None:
     """Tests only (``benchmark/tests/test_faults.py``): break the timed path
     underneath, so that a test can see ``correct`` come out false."""
     if name == "answer":  # an answer altered where it is produced
@@ -372,12 +367,15 @@ def plant_fault(name: str, engine, embedder) -> None:
 
         engine.search = swapped
     elif name == "token":  # a token altered where it is produced
-        tok = embedder.tokenizer
+        # each text's first word, where its family frames it, becomes another
+        # word of the vocabulary
+        tok, first = embedder.tokenizer, fam.FIRST_WORD_ID
+        at = fam.frame([first]).index(first)
         encode = tok.encode_batch
 
         def altered(texts, max_len):
             ids = np.asarray(encode(texts, max_len)).copy()
-            ids[:, 1] = np.where(ids[:, 1] > 0, ids[:, 1] % 1000 + 1000, 0)
+            ids[:, at] = np.where(ids[:, at] > 0, ids[:, at] % first + first, 0)
             return ids
 
         tok.encode_batch = altered
@@ -546,9 +544,9 @@ def run_reference(dep: Deployment, sample: list[dict], precision: str) -> dict:
     from lib import reference
 
     texts = sorted({r["query"] for r in sample})
-    ids = reference.tokenize(texts, dep.vocab_index, dep.query_width)
-    params = reference.to_device(dep.state_dict)
-    vec = dict(zip(texts, reference.encode(params, ids, dep.model, precision)))
+    ids = reference.token_ids(texts, dep.vocab_index, dep.query_width, dep.fam)
+    params = dep.fam.reference_params(dep.weights)
+    vec = dict(zip(texts, dep.fam.encode(params, ids, dep.model, precision)))
     del params
     live = np.ones(dep.n_rows, bool)
     unstable = sorted(dep.plan.touched) if dep.plan else []
@@ -629,16 +627,15 @@ def main() -> int:
     compiles = Compiles(jax)
     pauses = GcPauses()
     rec = spans_mod.Spans(jax.profiler.TraceAnnotation)
-    from lib import reference
 
     weights.result()
-    ref_params = reference.to_device(dep.state_dict)
+    ref_params = dep.fam.reference_params(dep.weights)
     dep.embed_pool(ref_params)
     del ref_params
     rows.result()
     pool.shutdown()
     log(f"data made: {dep.n_rows} x {dep.dim} rows, "
-        f"{sum(a.size for a in dep.state_dict.values())} parameters; pool embedded")
+        f"{sum(a.size for a in dep.weights.values())} parameters; pool embedded")
     dep.plant()
     log(f"planted {len(dep.passage_rows)} passages; a text's own share of its "
         f"embedding is {dep.own_share:.3f} (median norm off the cone's axis), "
@@ -666,7 +663,7 @@ def main() -> int:
             raise RuntimeError(f"expected one index engine, found {len(engines)}")
         engine = engines.pop()
         # a test's fault is in the path before the warm-up times it
-        plant_fault(spec.get("fault", ""), engine, embedder)
+        plant_fault(spec.get("fault", ""), engine, embedder, dep.fam)
         shapes = warm_up(dep, engine, embedder, spec["port"], compiles)
         wrap_layers(engine, embedder, rec)
         resident = device_memory(jax)

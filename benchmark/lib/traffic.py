@@ -67,9 +67,10 @@ def fixed_lengths(count: int, lo: int, hi: int, mean: float) -> np.ndarray:
 
 class QueryPool:
     """``pool`` distinct query texts of whole vocabulary words, with a Zipf
-    popularity over a seeded order."""
+    popularity over a seeded order; a text's ``tokens`` are its words and the
+    ``framing`` tokens its model's family adds (``FRAMING``)."""
 
-    def __init__(self, seed: int, words: list[str], q: dict):
+    def __init__(self, seed: int, words: list[str], q: dict, framing: int):
         rng = datagen.stream(seed, 10)
         lengths = rng.permutation(
             fixed_lengths(q["pool"], q["words_min"], q["words_max"], q["words_mean"])
@@ -83,7 +84,7 @@ class QueryPool:
                     break
             seen.add(t)
             self.texts.append(t)
-        self.tokens = lengths + 2  # [CLS] and [SEP]
+        self.tokens = lengths + framing
         self.weights = datagen.zipf_weights(q["pool"], q["zipf_s"])
         self.seed = seed
 

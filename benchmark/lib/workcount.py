@@ -1,17 +1,11 @@
 """Operations and bytes that a result NEEDS, computed from shapes, whatever
 implements it — so a later PR that changes storage or fuses the scan cannot
-make the count stale."""
+make the count stale. A model's work per token is its family's
+(``models/<model_type>.py::flops_per_token``), handed in as ``per_token``."""
 
 from __future__ import annotations
 
-
-def encoder_flops_per_token(model: dict, seq: int) -> float:
-    """Per token, all layers: 2·d·3d (qkv) + 2·d·d (proj) + 4·d·h (mlp) +
-    4·s·d (attention), the standard accounting (copied from
-    ``bench.py::_embed_throughput``)."""
-    d, h = model["hidden_size"], model["intermediate_size"]
-    per_layer = 2 * d * 3 * d + 2 * d * d + 4 * d * h + 4 * seq * d
-    return float(model["num_hidden_layers"] * per_layer)
+from typing import Callable
 
 
 def topk_scores_work(q: int, n: int, d: int, k: int) -> tuple[float, float]:
@@ -42,15 +36,17 @@ def least_time(flops: float, nbytes: float, peaks: dict) -> tuple[float, str]:
     return (t_c, "compute") if t_c >= t_m else (t_m, "memory")
 
 
-def retrieve_flops(tokens: int, n: int, d: int, model: dict) -> float:
-    """One request: the encoder over its REAL tokens (no bucket padding) plus
-    2·n·d for its scan over the live rows (not the capacity)."""
-    return tokens * encoder_flops_per_token(model, tokens) + 2.0 * n * d
+def retrieve_flops(tokens: int, n: int, d: int, per_token: Callable[[int], float]) -> float:
+    """One request: the model over its REAL tokens (no bucket padding),
+    ``per_token(tokens)`` each (the family's ``flops_per_token`` of the model),
+    plus 2·n·d for its scan over the live rows (not the capacity)."""
+    return tokens * per_token(tokens) + 2.0 * n * d
 
 
-def scoped_retrieve_flops(tokens: int, scope_rows: float, d: int, model: dict) -> float:
-    """One request confined to a scope: the encoder over its real tokens plus
+def scoped_retrieve_flops(tokens: int, scope_rows: float, d: int,
+                          per_token: Callable[[int], float]) -> float:
+    """One request confined to a scope: the model over its real tokens plus
     2·d for each live row of its scope (of the store, for a request with no
     filter), and for no row outside it: ``retrieve_flops`` with the scope in
     the store's place."""
-    return retrieve_flops(tokens, scope_rows, d, model)
+    return retrieve_flops(tokens, scope_rows, d, per_token)

@@ -10,7 +10,9 @@ last the numbers compared, each beside its limit. Everything else goes to
 standard error or into ``benchmark/out/``.
 
 A cell, a configuration, a traffic mix, a per-layer metric and a cell's limits
-are files found by name (``find``); ``BENCHMARK.json`` names them.
+are files found by name (``lib/files.py``); ``BENCHMARK.json`` names them. A model
+family is a file found by the ``model_type`` its configuration names
+(``lib/family.py``); one that has none stops the run here, before the child.
 A configuration may put its documents in folders (``metadata``) and a mix may
 confine its requests to them (``scope``): ``lib/traffic.py`` has the keys.
 
@@ -26,7 +28,6 @@ from __future__ import annotations
 
 import argparse
 import gc
-import importlib.util
 import json
 import os
 import signal
@@ -45,7 +46,7 @@ if HERE not in sys.path:
 
 import numpy as np  # noqa: E402
 
-from lib import check, datagen, loadgen, traffic  # noqa: E402
+from lib import check, datagen, family, files, loadgen, traffic  # noqa: E402
 
 REFUSED_EXIT = 4
 DEADLINE_EXIT = 5
@@ -124,15 +125,6 @@ class Life:
         raise Ending(DEADLINE_EXIT, f"the run met its deadline ({self.armed})")
 
 
-def find(kind: str, name: str, ext: str, manifest_dir: str) -> str:
-    """``<kind>/<name><ext>`` beside the manifest, else under ``benchmark/``."""
-    for base in (manifest_dir, HERE):
-        path = os.path.join(base, kind, name + ext)
-        if os.path.isfile(path):
-            return path
-    raise FileNotFoundError(f"no {kind}/{name}{ext} beside the manifest or in benchmark/")
-
-
 def load_json(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
@@ -150,11 +142,16 @@ def load_cell(manifest_path: str, workload: str) -> dict:
     cfg_path = os.path.join(mdir, cfg_file)
     if not os.path.isfile(cfg_path):
         cfg_path = os.path.join(ROOT, cfg_file)
+    config = load_json(cfg_path)
+    try:
+        fam = family.load(config, mdir)
+    except family.NoFamily as e:
+        raise SystemExit(str(e)) from None
     return {
         "manifest": manifest, "manifest_dir": mdir, "cell": cell,
-        "config": load_json(cfg_path),
-        "mix": load_json(find("traffic", cell["traffic"], ".json", mdir)),
-        "limits": load_json(find("limits", cell["name"], ".json", mdir)),
+        "config": config, "family": fam,
+        "mix": load_json(files.find("traffic", cell["traffic"], ".json", mdir)),
+        "limits": load_json(files.find("limits", cell["name"], ".json", mdir)),
     }
 
 
@@ -164,11 +161,7 @@ def metrics_of(manifest: dict, group: str, cell_name: str) -> list[dict]:
 
 
 def layer_reader(name: str, manifest_dir: str):
-    path = find("layers", name, ".py", manifest_dir)
-    spec = importlib.util.spec_from_file_location(f"bench_layer_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return files.load_module(files.find("layers", name, ".py", manifest_dir)).read
 
 
 def free_port() -> int:
@@ -319,7 +312,8 @@ def main() -> int:
     os.makedirs(out_dir, exist_ok=True)
     port = free_port()
     spec = {
-        "cell": name, "config": cfg, "mix": mix, "seed": seed,
+        "cell": name, "config": cfg, "family": loaded["family"].__file__,
+        "mix": mix, "seed": seed,
         "seconds": args.seconds, "trace": args.trace, "control": args.control,
         "chips": cell["chips"], "port": port, "out_dir": out_dir,
         "keep_trace": args.keep_trace, "fault": args.fault,
@@ -372,15 +366,15 @@ def main() -> int:
 def drive(args, loaded: dict, spec: dict, child: Child, life: Life) -> tuple[dict, list[str]]:
     """The run from the child's start to its verdict: the result line's object,
     and the numbers compared, each beside its limit, as lines."""
-    manifest, cell, cfg, mix, limits = (
-        loaded[k] for k in ("manifest", "cell", "config", "mix", "limits"))
+    manifest, cell, cfg, fam, mix, limits = (
+        loaded[k] for k in ("manifest", "cell", "config", "family", "mix", "limits"))
     name, seed, seconds, port = cell["name"], args.seed, args.seconds, spec["port"]
     out_dir = spec["out_dir"]
     k, chunks = cfg["k"], cfg["chunks_per_doc"]
 
     # the same plan the child derives, from the same seed
-    _, words = datagen.make_vocab(seed, cfg["vocab_size"])
-    pool = traffic.QueryPool(seed, words, mix["query"])
+    _, words = datagen.vocabulary(seed, cfg["vocab_size"], fam)
+    pool = traffic.QueryPool(seed, words, mix["query"], fam.FRAMING)
     plan = (traffic.WriterPlan(seed, mix["writer"], cfg["rows"] // chunks, chunks, seconds)
             if mix.get("writer") else None)
     folder_of_doc = datagen.doc_folders(seed, cfg["rows"] // chunks, cfg.get("metadata"))
@@ -561,7 +555,7 @@ def drive(args, loaded: dict, spec: dict, child: Child, life: Life) -> tuple[dic
         live_rows = facts["rows_expected"]
         traced = [r for r in answered + [p for p in probe_records if p["rows"] is not None]
                   if tw and tw["t0"] <= r["recv"] < tw["t1"]]
-        probe_tokens = len(probe_text.split()) + 2
+        probe_tokens = len(probe_text.split()) + fam.FRAMING
         tokens = [int(pool.tokens[r["qid"]]) if r["qid"] >= 0 else probe_tokens
                   for r in traced]
         counts = {
@@ -579,8 +573,8 @@ def drive(args, loaded: dict, spec: dict, child: Child, life: Life) -> tuple[dic
             counts["traced_scope_rows"] = [
                 live_rows if r["scope"] is None else int(scope.rows_in[r["scope"]])
                 for r in traced]
-        cell_facts = {"name": name, "config": cfg, "mix": mix, "chip": facts["chip"],
-                      "window": [t0, t_end], "trace_window": tw}
+        cell_facts = {"name": name, "config": cfg, "family": fam, "mix": mix,
+                      "chip": facts["chip"], "window": [t0, t_end], "trace_window": tw}
         for m in metrics_of(manifest, "per_layer", name):
             read = layer_reader(m["name"], loaded["manifest_dir"])
             value = read(trace, facts["spans"], counts, cell_facts)

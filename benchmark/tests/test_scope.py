@@ -13,7 +13,7 @@ import harness
 import numpy as np
 import pytest
 import run as bench_run
-from lib import check, datagen, loadgen, traffic
+from lib import check, datagen, family, loadgen, traffic
 
 SEED = 2147483659
 REHEARSAL = os.path.join(harness.TESTS, "rehearsal")
@@ -30,8 +30,9 @@ def _load(kind, name):
 
 def _pool(seed=SEED):
     cfg, mix = _load("configs", "tiny-bert"), _load("traffic", "read-c4")
-    _, words = datagen.make_vocab(seed, cfg["vocab_size"])
-    return traffic.QueryPool(seed, words, mix["query"])
+    fam = family.load(cfg)
+    _, words = datagen.vocabulary(seed, cfg["vocab_size"], fam)
+    return traffic.QueryPool(seed, words, mix["query"], fam.FRAMING)
 
 
 def _scope(seed=SEED, plan=None, **changed):
@@ -185,3 +186,12 @@ def test_a_scope_with_fewer_than_k_live_rows_is_judged_on_all_of_them():
     gap, _, _ = check.compare_sample(
         [dict(reply, scope=None)], vec, top, lambda d, c, v: rows.get((d, c)), set())
     assert gap == pytest.approx(0.4)
+
+
+# The model family's cases (``family_cases.py``: the BERT family's file against
+# golden values, a family added as one file) are taken in here, so that the
+# tier-1 tests count them through ``tests/test_benchmark_scope.py``.
+import family_cases  # noqa: E402
+
+globals().update(
+    {name: case for name, case in vars(family_cases).items() if name.startswith("test_")})

@@ -1,10 +1,14 @@
-"""``workcount`` against counts worked out by hand at the cells' shapes."""
+"""``workcount`` against counts worked out by hand at the cells' shapes, with
+the BERT family's work per token (``models/bert.py::flops_per_token``)."""
+
+import functools
 
 import harness  # noqa: F401  (puts benchmark/ on the path)
 import pytest
-from lib import peaks, workcount
+from lib import family, peaks, workcount
 
 V5E = peaks.peaks_for("TPU v5 lite")
+BERT = family.load({"model_type": "bert"})
 
 
 def test_topk_scores_at_the_read_cells_shape():
@@ -32,13 +36,14 @@ def test_topk_scores_at_the_live_cells_shape():
      12 * (6 * 768**2 + 2 * 768**2 + 4 * 768 * 3072 + 4 * 16 * 768)),
 ])
 def test_encoder_flops_per_token(model, seq, expect):
-    assert workcount.encoder_flops_per_token(model, seq) == expect
+    assert BERT.flops_per_token(model, seq) == expect
 
 
 def test_retrieve_flops_is_encoder_over_real_tokens_plus_scan():
     model = dict(hidden_size=1024, intermediate_size=4096, num_hidden_layers=24)
-    got = workcount.retrieve_flops(8, 1_200_000, 1024, model)
-    assert got == 8 * workcount.encoder_flops_per_token(model, 8) + 2 * 1_200_000 * 1024
+    per_token = functools.partial(BERT.flops_per_token, model)
+    got = workcount.retrieve_flops(8, 1_200_000, 1024, per_token)
+    assert got == 8 * BERT.flops_per_token(model, 8) + 2 * 1_200_000 * 1024
     # 8 tokens: 4.84 GFLOP of encoder, 2.46 GFLOP of scan
     assert got == pytest.approx(7.30e9, rel=5e-3)
 
@@ -53,10 +58,11 @@ def test_scoped_counts_by_hand_at_one_small_shape():
     assert workcount.scoped_topk_scores_work(3, 3 * 1000, 1000, 64, 10) == \
         workcount.topk_scores_work(3, 1000, 64, 10) == (384_000.0, 129_008.0)
     model = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2)
-    assert workcount.scoped_retrieve_flops(8, 100, 64, model) == \
-        8 * workcount.encoder_flops_per_token(model, 8) + 2 * 100 * 64
-    assert workcount.scoped_retrieve_flops(8, 1000, 64, model) == \
-        workcount.retrieve_flops(8, 1000, 64, model)
+    per_token = functools.partial(BERT.flops_per_token, model)
+    assert workcount.scoped_retrieve_flops(8, 100, 64, per_token) == \
+        8 * BERT.flops_per_token(model, 8) + 2 * 100 * 64
+    assert workcount.scoped_retrieve_flops(8, 1000, 64, per_token) == \
+        workcount.retrieve_flops(8, 1000, 64, per_token)
 
 
 @pytest.mark.parametrize("program_reads", ["every row under a mask", "only the scopes"])

@@ -2,15 +2,18 @@
 of the embed forward against spans and a trace written by hand: what they read
 from the packed forward, and that a program that lacks it gives them nothing."""
 
+import functools
 import json
 import os
 
 import harness  # noqa: F401  (puts benchmark/ on the path)
 import pytest
 import run as bench_run
-from lib import peaks, program_spans as ps, workcount, workcount_embed
+from lib import family, peaks, program_spans as ps, workcount_embed
 
-LARGE = dict(hidden_size=1024, intermediate_size=4096, num_hidden_layers=24)
+LARGE = dict(model_type="bert", hidden_size=1024, intermediate_size=4096, num_hidden_layers=24)
+BERT = family.load(LARGE)
+PER_TOKEN = functools.partial(BERT.flops_per_token, LARGE)
 V5E = peaks.peaks_for("TPU v5 lite")
 CELL = "e5-large-v2-longq-1200k.read-long-c16"
 
@@ -19,19 +22,19 @@ def test_embed_flops_by_hand_at_the_long_cells_shape():
     # a query of 512 tokens: 24 layers of 24 d^2 (qkv 6, proj 2, mlp 16) +
     # 4 s d of attention over its own 512 positions, a token
     one = 24 * (24 * 1024**2 + 4 * 512 * 1024)
-    assert workcount.encoder_flops_per_token(LARGE, 512) == one == 654_311_424
-    assert workcount_embed.embed_flops([512], LARGE) == 512 * one
+    assert BERT.flops_per_token(LARGE, 512) == one == 654_311_424
+    assert workcount_embed.embed_flops([512], PER_TOKEN) == 512 * one
     # a text's attention sees its own length, not its neighbour's: two texts
     # are two sums, and short ones cost less a token
     short = 24 * (24 * 1024**2 + 4 * 32 * 1024)
-    assert workcount_embed.embed_flops([512, 32], LARGE) == 512 * one + 32 * short
+    assert workcount_embed.embed_flops([512, 32], PER_TOKEN) == 512 * one + 32 * short
     # the mix's mean tick: eight queries, 2,077 tokens, about 1.3 TFLOP,
     # 6.6 ms at the bfloat16 peak
     tick = [227] * 8 + [261]
     assert sum(tick) == 2077
-    assert workcount_embed.embed_flops(tick, LARGE) / V5E["bf16_flops"] == \
+    assert workcount_embed.embed_flops(tick, PER_TOKEN) / V5E["bf16_flops"] == \
         pytest.approx(6.6e-3, rel=0.03)
-    assert workcount_embed.embed_flops([], LARGE) == 0.0
+    assert workcount_embed.embed_flops([], PER_TOKEN) == 0.0
 
 
 def _reader(name):
@@ -48,7 +51,7 @@ def _span_file(tmp_path, monkeypatch, events):
         *({"ph": "X", **e} for e in events)]}
     with open(os.path.join(ps.spans_dir(), "1.json"), "w") as f:
         json.dump(doc, f)
-    return {"trace_window": {"t0": 0.0, "t1": 1.0}, "config": LARGE,
+    return {"trace_window": {"t0": 0.0, "t1": 1.0}, "config": LARGE, "family": BERT,
             "chip": V5E, "mix": {}}
 
 
@@ -91,9 +94,9 @@ def test_no_span_file_gives_every_span_reader_none(tmp_path, monkeypatch):
 
 def test_embed_roofline_is_needed_work_over_the_programs_device_time():
     read = _reader("embed_roofline")
-    cell = {"config": LARGE, "chip": V5E}
+    cell = {"config": LARGE, "family": BERT, "chip": V5E}
     tokens = [512, 512, 227, 32]
-    needed_s = workcount_embed.embed_flops(tokens, LARGE) / V5E["bf16_flops"]
+    needed_s = workcount_embed.embed_flops(tokens, PER_TOKEN) / V5E["bf16_flops"]
     trace = {"stand_in": False, "programs": {
         "embed_tokens": {"calls": 2, "total_s": 4 * needed_s, "median_s": 2 * needed_s},
         "embed_take": {"calls": 2, "total_s": 1.0, "median_s": 0.5},
@@ -105,7 +108,7 @@ def test_embed_roofline_is_needed_work_over_the_programs_device_time():
                 [], {"traced_tokens": tokens}, cell) is None
     assert read(trace, [], {"traced_tokens": []}, cell) is None
     assert read({**trace, "stand_in": True}, [], {"traced_tokens": tokens}, cell) is None
-    assert read(trace, [], {"traced_tokens": tokens}, {"config": LARGE, "chip": None}) is None
+    assert read(trace, [], {"traced_tokens": tokens}, {"config": LARGE, "family": BERT, "chip": None}) is None
     assert read(None, [], {"traced_tokens": tokens}, cell) is None
 
 
