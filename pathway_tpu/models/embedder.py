@@ -6,13 +6,19 @@ encoder that runs on the MXU in bf16: mean-pooled, L2-normalized sentence
 embeddings. Weights can be tensor-parallel sharded over a mesh "model" axis
 (attention heads + MLP hidden split), with batch data-parallel over "data".
 
-One encoder: the published BERT block (post-layernorm, biases, exact GELU),
-numerically HF ``BertModel``, at all-MiniLM-L6-v2's widths by default. Its
-parameters come from a checkpoint (``load_hf_state_dict``) or from a seed
+The encoder here is the published BERT block (post-layernorm, biases, exact
+GELU), numerically HF ``BertModel``, at all-MiniLM-L6-v2's widths by default.
+Its parameters come from a checkpoint (``load_hf_state_dict``) or from a seed
 (``init_params``, as BERT initialises them) in one layout, and its tokenizer is
 WordPiece: the checkpoint's vocabulary, or for a seeded encoder a vocabulary
 built in code (``WordPieceTokenizer.generated``), so the framework runs
 self-contained.
+
+The ``Embedder`` serves either that encoder or the LFM2-MoE decoder of
+``lfm2.py`` (``from_pretrained(..., config=)`` chooses by ``model_type``):
+tokenizing, packing, the declared program shapes, dispatch and warm-up are
+shared, and the forward, the resident parameters and the loader are the
+model's (``_model_of``).
 """
 
 from __future__ import annotations
@@ -254,6 +260,27 @@ def resident_params(params: dict, cfg: EmbedderConfig) -> dict:
     return out
 
 
+def handed(params: dict, looped: bool) -> dict:
+    """The parameters one program is handed: the tables with the layers
+    stacked, for a program that loops over them, or as a list, for one
+    that lays them out; never the form it does not read."""
+    tables = {k: v for k, v in params.items() if k not in ("layers", "stacked")}
+    return {**tables, "layers": params["stacked"] if looped else params["layers"]}
+
+
+def _model_of(cfg: Any):
+    """The module that computes ``cfg``'s model (its ``init_params``,
+    ``resident_params``, ``handed`` and ``embed_tokens``): this one for the
+    BERT encoder, ``lfm2`` for the LFM2-MoE decoder."""
+    if isinstance(cfg, EmbedderConfig):
+        import sys
+
+        return sys.modules[__name__]
+    from . import lfm2
+
+    return lfm2
+
+
 def _np(v) -> np.ndarray:
     """Tensor-library-agnostic ndarray view (torch tensors or arrays)."""
     if hasattr(v, "detach"):
@@ -386,22 +413,30 @@ class Embedder:
     """Host-facing embedder: one jitted forward on the served path, run at
     the shapes of ``self.shapes`` and at no other."""
 
-    def __init__(self, cfg: EmbedderConfig | None = None, seed: int = 0,
+    def __init__(self, cfg: Any = None, seed: int = 0,
                  params: dict | None = None, tokenizer: Any = None):
-        """``params``: float32 masters (``load_hf_state_dict``'s tree on the
-        host; ``init_params(cfg, seed)``'s where none are given), kept as
+        """``cfg``: the model's shape, an ``EmbedderConfig`` (the BERT
+        encoder) or an ``lfm2.Lfm2Config`` (the LFM2-MoE decoder); its module
+        computes the forward (``_model_of``). ``params``: float32 masters (the
+        model's ``load_hf_state_dict`` tree on the host; ``init_params(cfg,
+        seed)``'s where none are given), kept as the model's
         ``resident_params`` makes them; the caller's own copy is not touched.
-        ``tokenizer``: a ``WordPieceTokenizer``; a seeded encoder given none
+        ``tokenizer``: a ``WordPieceTokenizer``; a seeded model given none
         gets ``WordPieceTokenizer.generated``, one given ``params`` none."""
+        from ..internals.tracing import span
         from ..serve.stats import bump
 
         self.cfg = cfg or EmbedderConfig()
-        #: every form of the parameters the programs read, and nothing else
-        #: of them on the device: setting it to None frees the encoder
-        self.params = resident_params(
-            params if params is not None else init_params(self.cfg, seed), self.cfg)
-        bump("embed_param_bytes_total",
-             sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.params)))
+        model = self._model = _model_of(self.cfg)
+        masters = params if params is not None else model.init_params(self.cfg, seed)
+        with span("embed.place") as sp:
+            #: every form of the parameters the programs read, and nothing else
+            #: of them on the device: setting it to None frees the model
+            self.params = model.resident_params(masters, self.cfg)
+            placed = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.params))
+            if sp is not None:
+                sp.args["bytes"] = placed
+        bump("embed_param_bytes_total", placed)
         if tokenizer is None and params is None:
             tokenizer = WordPieceTokenizer.generated(self.cfg.vocab_size)
         self.tokenizer = tokenizer
@@ -414,14 +449,15 @@ class Embedder:
         def forward(params, ids):
             # ids int32 [3, R, L]: tokens, segments, positions. Rows of question
             # length are bound by one read of the parameters, so their few
-            # programs lay the layers out, as a search's program always did;
-            # the passage programs are bound by their products, and loop over
-            # one layer: cheap to compile and to keep (``_handed``)
-            return embed_tokens(params, ids[0], cfg, segments=ids[1],
-                                positions=ids[2], texts=TEXTS_PER_DISPATCH)
+            # programs lay the encoder's layers out, as a search's program always
+            # did; the passage programs are bound by their products, and loop
+            # over one layer: cheap to compile and to keep (``_handed``; the
+            # decoder's programs all read its one list of layers)
+            return model.embed_tokens(params, ids[0], cfg, segments=ids[1],
+                                      positions=ids[2], texts=TEXTS_PER_DISPATCH)
 
         def token_rows(params, token_ids):
-            return embed_tokens(params, token_ids, cfg)
+            return model.embed_tokens(params, token_ids, cfg)
 
         def take(vectors, rows):
             return vectors[:rows]
@@ -443,15 +479,21 @@ class Embedder:
     @classmethod
     def from_pretrained(
         cls, source: Any, *, tokenizer: Any = None, dtype: Any = None,
-        n_heads: int | None = None,
+        n_heads: int | None = None, config: dict | None = None,
+        max_len: int | None = None,
     ) -> "Embedder":
-        """Build from a pretrained MiniLM/BERT checkpoint.
+        """Build from a pretrained checkpoint: a BERT/MiniLM encoder, or an
+        LFM2-MoE decoder (HF ``model_type`` ``lfm2_moe``).
 
-        ``source``: a HF state dict (pass ``n_heads=`` — the head partition
-        is not derivable from tensor shapes), or a local directory with
-        ``pytorch_model.bin`` + ``config.json`` (``num_attention_heads`` is
-        read from it) and optionally ``vocab.txt``, which becomes the
-        WordPiece tokenizer. No network access is attempted."""
+        ``source``: a HF state dict, or a local directory with
+        ``pytorch_model.bin`` + ``config.json`` and optionally ``vocab.txt``,
+        which becomes the WordPiece tokenizer. ``config``: the checkpoint's
+        ``config.json`` as a dict; its ``model_type`` chooses the model (BERT
+        where there is none), and a BERT state dict needs it or ``n_heads=``
+        (the head partition is not derivable from tensor shapes).
+        ``max_len``: a decoder's longest text served, in positions
+        (``lfm2.SERVED_POSITIONS`` where none is given; an encoder serves its
+        position table's). No network access is attempted."""
         import json as _json
         import os
 
@@ -464,14 +506,22 @@ class Embedder:
                 map_location="cpu", weights_only=True,
             )
             cfg_file = os.path.join(path, "config.json")
-            if n_heads is None and os.path.exists(cfg_file):
+            if config is None and os.path.exists(cfg_file):
                 with open(cfg_file) as f:
-                    n_heads = int(_json.load(f)["num_attention_heads"])
+                    config = _json.load(f)
             vocab_file = os.path.join(path, "vocab.txt")
             if tokenizer is None and os.path.exists(vocab_file):
                 tokenizer = WordPieceTokenizer.from_vocab_file(vocab_file)
         else:
             state_dict = source
+        if config is not None and config.get("model_type") == "lfm2_moe":
+            from . import lfm2
+
+            params, cfg = lfm2.load_hf_state_dict(state_dict, config, dtype=dtype,
+                                                  max_len=max_len)
+            return cls(cfg, params=params, tokenizer=tokenizer)
+        if n_heads is None and config is not None:
+            n_heads = int(config["num_attention_heads"])
         params, cfg = load_hf_state_dict(state_dict, n_heads=n_heads)
         if dtype is not None:
             cfg = dataclasses.replace(cfg, dtype=dtype)
@@ -484,12 +534,9 @@ class Embedder:
                                            jnp.asarray(token_ids, jnp.int32)))
 
     def _handed(self, looped: bool) -> dict:
-        """The parameters one program is handed: the tables with the layers
-        stacked, for a program that loops over them, or as a list, for one
-        that lays them out; never the form it does not read."""
-        p = self.params
-        tables = {k: v for k, v in p.items() if k not in ("layers", "stacked")}
-        return {**tables, "layers": p["stacked"] if looped else p["layers"]}
+        """The parameters one program is handed: the form it reads (the
+        model's ``handed``)."""
+        return self._model.handed(self.params, looped)
 
     def warm(self) -> None:
         """Compile (or load from the compile cache) every program the served
@@ -587,18 +634,25 @@ class Embedder:
         with self._dispatching(length, n_rows, len(texts), int(lengths[texts].sum())):
             return self._forward(ids)
 
-    @staticmethod
-    def _dispatching(length: int, rows: int, texts: int, real: int):
+    def _dispatching(self, length: int, rows: int, texts: int, real: int):
         """The span and the counters of one forward handed to the device:
-        useful work (``real`` tokens) over attempted work (rows x length)."""
+        useful work (``real`` tokens) over attempted work (rows x length);
+        for a model with experts, the (token, expert) assignments its real
+        tokens make over its expert layers, known here without a fetch."""
         from ..internals.tracing import span
         from ..serve.stats import bump
 
         bump("embed_dispatches_total")
         bump("embed_real_tokens_total", real)
         bump("embed_padded_tokens_total", rows * length)
+        experts = {}
+        if getattr(self.cfg, "moe_layers", 0):
+            experts = {"moe_layers": self.cfg.moe_layers,
+                       "experts_per_token": self.cfg.experts_per_token}
+            bump("embed_expert_assignments_total",
+                 real * self.cfg.moe_layers * self.cfg.experts_per_token)
         return span("embed.dispatch", bucket=length, rows=rows, texts=texts,
-                    tokens=real, computed=rows * length)
+                    tokens=real, computed=rows * length, **experts)
 
     def _packed(self, toks, lengths, texts: list[int]) -> list[tuple[jax.Array, int]]:
         """The dispatches that embed ``texts``, as (vectors, how many of them

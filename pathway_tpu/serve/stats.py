@@ -90,8 +90,12 @@ SERVE_STATS: dict[str, int] = {
     "embed_truncated_texts_total": 0,
     "embed_shapes_compiled_total": 0,
     #: bytes of parameters an ``Embedder`` placed on the device, every form
-    #: its programs read (``models/embedder.py::resident_params``), at load
+    #: its programs read (its model's ``resident_params``), at load
     "embed_param_bytes_total": 0,
+    #: (token, expert) assignments the embed forward was asked for: real
+    #: tokens x experts per token x expert layers, a dispatch at a time (a
+    #: model with no expert layer adds none)
+    "embed_expert_assignments_total": 0,
     #: texts ``WordPieceTokenizer.encode_batch`` was handed / those of them
     #: that took its ASCII lane (the rest, texts that are not ASCII, were
     #: tokenized a character at a time in Python)

@@ -1,12 +1,13 @@
 """The packed forward of ``models/embedder.py``: a call's texts share the rows
 of one program of a declared set, each text a segment with its own attention,
 positions, pool and norm. Held to the benchmark's plain float32 reference
-(``benchmark/lib/reference.py``, imported as ``tests/test_benchmark_scope.py``
-imports the benchmark's tests) and to the forward of each text alone: whole
-queries up to the model's own positions, nothing compiled after the warm, the
-stored path's rule, a served 300-token query, a filtered search that
-pads vectors and not texts, short questions at sixteen tokens a row, and the
-parameters resident in the compute dtype with the float32 masters' bits."""
+(the BERT family's, ``benchmark/models/bert.py``, loaded through
+``benchmark/lib/family.py`` as the harness loads it) and to the forward of
+each text alone: whole queries up to the model's own positions, nothing
+compiled after the warm, the stored path's rule, a served 300-token query, a
+filtered search that pads vectors and not texts, short questions at sixteen
+tokens a row, and the parameters resident in the compute dtype with the
+float32 masters' bits."""
 
 import contextlib
 import http.client
@@ -23,7 +24,7 @@ import pytest
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark"))
 
-from lib import datagen, reference  # noqa: E402
+from lib import datagen, family, reference  # noqa: E402
 
 import pathway_tpu as pw  # noqa: E402
 from pathway_tpu.models import embedder as embedder_mod  # noqa: E402
@@ -33,6 +34,7 @@ from pathway_tpu.models.wordpiece import WordPieceTokenizer  # noqa: E402
 from pathway_tpu.ops.index_engines import BruteForceKnnEngine  # noqa: E402
 from pathway_tpu.serve.stats import SERVE_STATS  # noqa: E402
 
+BERT = family.load({"model_type": "bert"})
 SEED = 40
 MODEL = dict(hidden_size=32, num_hidden_layers=2, num_attention_heads=4,
              intermediate_size=64, vocab_size=1200, max_position_embeddings=64,
@@ -43,13 +45,13 @@ MAX_LEN = MODEL["max_position_embeddings"]
 def _build(model: dict, seed: int = SEED):
     """(embedder, reference parameters, vocabulary index, whole words), all
     from the seed: random weights through ``from_pretrained``, float32."""
-    lines, words = datagen.make_vocab(seed, model["vocab_size"])
+    lines, words = datagen.vocabulary(seed, model["vocab_size"], BERT)
     index = {w: i for i, w in enumerate(lines)}
-    state = datagen.make_state_dict(seed, model)
+    state = BERT.make_weights(seed, model)
     emb = Embedder.from_pretrained(
         state, n_heads=model["num_attention_heads"],
         tokenizer=WordPieceTokenizer(dict(index)), dtype=jnp.float32)
-    return emb, reference.to_device(state), index, words
+    return emb, BERT.reference_params(state), index, words
 
 
 @pytest.fixture(scope="module")
@@ -63,8 +65,8 @@ def _text(rng, words, tokens: int) -> str:
 
 
 def _reference(ref, index, texts, model=MODEL):
-    ids = reference.tokenize(texts, index, model["max_position_embeddings"])
-    return reference.encode(ref, ids, model, batch=16)
+    ids = reference.token_ids(texts, index, model["max_position_embeddings"], BERT)
+    return BERT.encode(ref, ids, model, batch=16)
 
 
 # -- (a) against the plain reference and against each text alone ---------------
@@ -261,8 +263,8 @@ def _resident(kind: str, seed: int = SEED + 4):
     state dict, or from a seed with the generated vocabulary (whose letters,
     digits and punctuation marks are words of one token each)."""
     if kind == "pretrained":
-        lines, words = datagen.make_vocab(seed, MODEL["vocab_size"])
-        state = datagen.make_state_dict(seed, MODEL)
+        lines, words = datagen.vocabulary(seed, MODEL["vocab_size"], BERT)
+        state = BERT.make_weights(seed, MODEL)
         emb = Embedder.from_pretrained(
             state, n_heads=MODEL["num_attention_heads"],
             tokenizer=WordPieceTokenizer({w: i for i, w in enumerate(lines)}),
