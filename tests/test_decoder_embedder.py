@@ -206,6 +206,42 @@ def test_a_router_that_sends_every_token_to_one_expert_loses_no_token():
     assert (got[::2] == 0).all() and (np.abs(got[1::2]).sum(1) > 0).all()
 
 
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it, a kernel's
+    own body left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def test_a_served_program_runs_each_expert_layer_as_two_kernels():
+    """Each expert layer of a served program is ``moe_gate_up`` then
+    ``moe_down``; no ``ragged_dot`` is left, and no float32 [R·L·k, 2f]
+    product: the SwiGLU is the gate/up kernel's epilogue. (An expert of 48
+    here, so that 2f is not the hidden width, which the layer's float32
+    output [R·L·k, d] has.)"""
+    emb = _build(model=dict(MODEL, moe_intermediate_size=48))[0]
+    cfg = emb.cfg
+    assert 2 * cfg.moe_intermediate != cfg.dim
+    rows, length = emb.shapes[-1]
+    ids = jnp.zeros((3, rows, length), jnp.int32)
+    eqns = list(_equations(jax.make_jaxpr(emb._fwd)(emb._handed(looped=False), ids).jaxpr))
+    kernels = [e.params["name"] for e in eqns if e.primitive.name == "pallas_call"]
+    assert kernels == ["moe_gate_up", "moe_down"] * cfg.moe_layers
+    assert not [e for e in eqns if "ragged_dot" in e.primitive.name]
+    assignments = rows * length * cfg.experts_per_token
+    wide = [v.aval for e in eqns for v in e.outvars
+            if v.aval.dtype == jnp.float32 and v.aval.ndim == 2
+            and v.aval.shape[0] >= assignments and v.aval.shape[1] == 2 * cfg.moe_intermediate]
+    assert not wide
+
+
 def test_the_references_routed_experts_equal_the_dense_form():
     """The family's reference computes each expert over the tokens routed to
     it (gathered in blocks, added back): the same as every expert over every
