@@ -10,10 +10,11 @@ them, normalised to sum to one (``norm_topk_prob``) and scaled by
 
 The experts run as two grouped products, Pallas kernels over the ``tokens x
 k`` assignments sorted by expert: ``moe_gate_up`` applies each expert's SwiGLU
-to its own contiguous group of rows, ``moe_down`` its output matrix, and the
-weighted results are added back to their tokens. A token that is not real
-(padding of a packed row) is given no assignment: its rows are sorted past the
-last group, no kernel visits them, and they come out zero.
+to its own contiguous group of rows, ``moe_down`` its output matrix, and each
+token gathers its ``k`` rows back through the inverse of the sort, weighs them
+and sums them in float32. A token that is not real (padding of a packed row)
+is given no assignment: its rows are sorted past the last group, no kernel
+visits them, and it comes out zero.
 """
 
 from __future__ import annotations
@@ -129,6 +130,25 @@ def _swiglu(g, u):
     return jax.nn.silu(g) * u
 
 
+def _combine(y: jax.Array, inv: jax.Array, weights: jax.Array, valid: jax.Array) -> jax.Array:
+    """Each token's ``k`` rows of ``y`` [M, d], sorted by expert, gathered at
+    ``inv`` [T, k] (their sorted positions), weighed by ``weights`` [T, k] and
+    summed: float32 [T, d]. The terms are added in ascending sorted position,
+    from the first, the order in which a sequential scatter-add into zeros
+    applies them. A padding token's positions lie past the last group, in rows
+    no kernel writes, so it is masked to an exact zero by a ``where`` (a
+    product with zero keeps a NaN)."""
+    inv, weights = jax.lax.sort((inv, weights), dimension=1, num_keys=1)
+    # one [T, d] gather a slot: gathered as one [T, k, d], the rows are written
+    # out whole and laid out again slot by slot (the combine alone at [8, 512]
+    # on a TPU v5e: 0.64 ms a slot at a time, 1.96 as one gather, 1.98 as the
+    # weighed scatter-add into the tokens)
+    out = y[inv[:, 0]] * weights[:, :1]
+    for j in range(1, inv.shape[1]):
+        out = out + y[inv[:, j]] * weights[:, j:j + 1]
+    return jnp.where(valid[:, None], out, 0.0)
+
+
 # jitted, so that the expert layers of a program share one lowering of the
 # kernels: in interpret mode each takes a third of a second to lower
 @functools.partial(jax.jit, static_argnums=6)
@@ -148,8 +168,8 @@ def experts_swiglu(h: jax.Array, experts: jax.Array, weights: jax.Array,
         # the rows are padded to whole m-tiles the same way
         key = jnp.where(valid[:, None], experts, n).reshape(-1)
         order = jnp.argsort(key, stable=True)
+        inv = jnp.argsort(order).reshape(t, k)
         order = jnp.pad(order, (0, -order.size % _TM), constant_values=t * k)
-        kept = jnp.pad(key, (0, 1), constant_values=n)[order] < n
         token = jnp.minimum(order // k, t - 1)
         sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
         schedule = _schedule(sizes, order.size)
@@ -157,7 +177,4 @@ def experts_swiglu(h: jax.Array, experts: jax.Array, weights: jax.Array,
                        halves=2, epilogue=_swiglu, out_dtype=dtype)
         y = _grouped("moe_down", act, w2.astype(dtype), schedule,
                      halves=1, epilogue=lambda y: y, out_dtype=_F32)
-        # rows past the last group are never visited and may hold anything
-        scale = jnp.pad(weights.reshape(-1), (0, 1))[order]
-        y = jnp.where(kept[:, None], y * scale[:, None], 0.0)
-        return jnp.zeros((t, y.shape[-1]), _F32).at[token].add(y)
+        return _combine(y, inv, weights, valid)

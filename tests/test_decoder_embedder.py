@@ -242,6 +242,25 @@ def test_a_served_program_runs_each_expert_layer_as_two_kernels():
     assert not wide
 
 
+def test_a_served_programs_expert_layer_returns_its_rows_by_a_gather():
+    """Under ``moe.experts`` no scatter of a served program writes rows: the
+    tokens gather their rows back through the inverse of the sort, one
+    [T, d] gather a slot, where a float32 scatter-add into the tokens was
+    serialised over a token's k rows. (The scatters left count the rows and
+    tiles of each group for the schedule, a scalar an update.)"""
+    emb = _build()[0]
+    cfg = emb.cfg
+    rows, length = emb.shapes[-1]
+    ids = jnp.zeros((3, rows, length), jnp.int32)
+    eqns = list(_equations(jax.make_jaxpr(emb._fwd)(emb._handed(looped=False), ids).jaxpr))
+    experts = [e for e in eqns if "moe.experts" in str(e.source_info.name_stack)]
+    back = [e for e in experts if e.primitive.name == "gather"
+            and e.outvars[0].aval.shape == (rows * length, cfg.dim)]
+    assert len(back) == cfg.experts_per_token * cfg.moe_layers
+    assert not [e for e in experts if "scatter" in e.primitive.name
+                and e.outvars[0].aval.ndim > 1]
+
+
 def test_the_references_routed_experts_equal_the_dense_form():
     """The family's reference computes each expert over the tokens routed to
     it (gathered in blocks, added back): the same as every expert over every

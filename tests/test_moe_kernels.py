@@ -4,7 +4,9 @@ the SwiGLU in its epilogue, ``moe_down``), in interpret mode, held to
 grid that visits the wrong tiles: an expert with no rows, every assignment on
 one expert, groups that start inside a tile, every token padding, a row count
 that is no multiple of the tile, widths under one tile, and widths of several
-output tiles."""
+output tiles. Over the same layouts, the combine (``moe._combine``: each
+token's rows gathered back through the inverse of the sort) is held to the
+weighed scatter-add it replaced."""
 
 import jax
 import jax.numpy as jnp
@@ -118,3 +120,52 @@ def test_the_kernels_equal_ragged_dot_and_the_float32_swiglu(layout, dtype, monk
     assert np.isfinite(got).all()
     _close(got[keep], want[keep], dtype)
     assert (got[~keep] == 0).all()
+
+
+def _scatter_combine(y, order, key, weights, n):
+    """The combine as a weighed scatter-add: each sorted row ``y[p]`` times its
+    assignment's weight, added into its token, rows past the last group
+    masked off."""
+    t, k = weights.shape
+    kept = jnp.pad(key, (0, 1), constant_values=n)[order] < n
+    token = jnp.minimum(order // k, t - 1)
+    scale = jnp.pad(weights.reshape(-1), (0, 1))[order]
+    y = jnp.where(kept[:, None], y * scale[:, None], 0.0)
+    return jnp.zeros((t, y.shape[-1]), jnp.float32).at[token].add(y)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_the_combine_gathers_what_the_scatter_add_summed(layout, dtype, monkeypatch):
+    """The down kernel's rows, NaN planted in every row past the last group,
+    gathered back to their tokens: a real token's sum is the scatter-add's,
+    to one rounding of each term (a CPU fuses a product into its sum, which
+    rounds once where the scatter rounds twice), and a padding token is an
+    exact zero."""
+    sizes, padding, d, f, tile = LAYOUTS[layout]
+    if tile is not None:
+        monkeypatch.setattr(moe, "_TN", tile)
+    rng = np.random.default_rng(100 + sorted(LAYOUTS).index(layout))
+    experts, valid = _assignments(sizes, padding, rng)
+    t = experts.shape[0]
+    key = jnp.where(valid[:, None], experts, E).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    inv = jnp.argsort(order).reshape(t, K)
+    order = jnp.pad(order, (0, -order.size % moe._TM), constant_values=t * K)
+    m, real = order.size, sum(sizes)
+    act = jnp.asarray(rng.standard_normal((m, f), np.float32), dtype)
+    w2 = jnp.asarray(rng.standard_normal((E, f, d), np.float32) / np.sqrt(f), dtype)
+    y = jax.jit(lambda a, w, g: moe._grouped(
+        "moe_down", a, w, moe._schedule(g, m), halves=1, epilogue=lambda y: y,
+        out_dtype=jnp.float32))(act, w2, jnp.asarray(sizes, jnp.int32))
+    y = y.at[real:].set(jnp.nan)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, (t, K)), jnp.float32)
+
+    got = np.asarray(jax.jit(moe._combine)(y, inv, weights, valid))
+    want = np.asarray(jax.jit(_scatter_combine, static_argnums=4)(y, order, key, weights, E))
+    keep = np.asarray(valid)
+    assert got.shape == (t, d) and got.dtype == np.float32
+    assert np.isfinite(got).all()
+    assert (got[~keep] == 0).all()
+    terms = np.abs(np.asarray(y)[np.asarray(inv)[keep]] * np.asarray(weights)[keep][..., None])
+    assert (np.abs(got[keep] - want[keep]) <= K * 2.0 ** -24 * terms.sum(1)).all()
